@@ -25,15 +25,24 @@ _LIB: Optional[ctypes.CDLL] = None
 
 
 def _build_lib() -> str:
-    d = os.path.join(os.path.dirname(__file__), "..", "native")
-    d = os.path.abspath(d)
-    so = os.path.join(d, "libddstore.so")
+    """Path of the compiled library for THIS source, built on first use.
+    The file name carries a content hash of ddstore.cpp, so an existing
+    file is by construction built from the current source — a checkout,
+    a copy or an export cannot make a stale or missing library look
+    fresh the way an mtime comparison does. (*.so is in .gitignore.)"""
+    import hashlib
+    d = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                     "native"))
     src = os.path.join(d, "ddstore.cpp")
-    if (not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(d, f"libddstore-{digest}.so")
+    if not os.path.exists(so):
+        tmp = f"{so[:-3]}.{os.getpid()}.tmp.so"  # still matches *.so
         subprocess.check_call(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", so, src,
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src,
              "-lpthread"])
+        os.replace(tmp, so)  # atomic: a concurrent builder wrote the same
     return so
 
 

@@ -50,7 +50,8 @@ microbench win — ops/segment.py decision record), so the kernels are
     utils/envflags.env_strict_flag — a typo warns and stays off, the
     HYDRAGNN_PALLAS_NBR lesson), resolved ONCE at step construction
     (resolve_fused_mp_flag(refresh=True) in train_step factories),
-  * interpret-mode on CPU so tier-1 exercises them end to end,
+  * interpret-mode off the TPU (kernels.interpret_mode) so tier-1
+    exercises them end to end,
   * bounded by the whole node array fitting VMEM (the one-hot gather
     reads all of h/proj_j per tile): larger inputs fall back to the
     XLA path via ``fused_mp_enabled``.
@@ -74,10 +75,6 @@ from jax.experimental.pallas import tpu as pltpu
 # ~16 MB/core budget.
 TILE_E = 256
 TILE_N = 128
-# min/max sub-chunk: the masked-broadcast intermediate is
-# [MM_CHUNK, TILE_N, F]; 32 keeps it ~2 MB at F=128 f32
-MM_CHUNK = 32
-
 # node arrays bigger than this stay on the XLA path: the kernels hold
 # the whole h / proj_j in VMEM for the one-hot gather (same bound and
 # rationale as kernels/nbr_pallas.py)
@@ -110,9 +107,18 @@ def _gather_rows(ids, table32, dtype):
     n_all = table32.shape[0]
     iota = lax.broadcasted_iota(jnp.int32, (ids.shape[0], n_all), 1)
     onehot = (ids[:, None] == iota).astype(jnp.float32)
-    out = lax.dot_general(onehot, table32, (((1,), (0,)), ((), ())),
-                          preferred_element_type=jnp.float32)
+    out = _dot(onehot, table32, (((1,), (0,)), ((), ())))
     return out.astype(dtype)
+
+
+def _dot(a, b, dims):
+    """f32 MXU matmul at HIGHEST precision. Every matmul in this module
+    has a one-hot operand and exists to MOVE f32 values (gather) or ADD
+    them (scatter); at the default precision the MXU rounds the values
+    to bf16 first and the result is no longer the unfused path's
+    (kernels/segment_pallas.py has the on-chip numbers)."""
+    return lax.dot_general(a, b, dims, precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
 
 
 # --------------------------------------------------------------------------
@@ -138,8 +144,7 @@ def _filter_kernel(send_ref, recv_ref, h_ref, w_ref, out_ref, acc_ref):
     local = recv - n_blk * TILE_N
     cols = lax.broadcasted_iota(jnp.int32, (TILE_E, TILE_N), 1)
     onehot = (local[:, None] == cols).astype(jnp.float32)
-    acc_ref[:] += lax.dot_general(onehot, msgs, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
+    acc_ref[:] += _dot(onehot, msgs, (((0,), (0,)), ((), ())))
 
     @pl.when(e_idx == e_last)
     def _():
@@ -234,41 +239,42 @@ def _pna_kernel(send_ref, recv_ref, pi_ref, pj_ref,
         amn_ref[:] = jnp.full_like(amn_ref, big)
         amx_ref[:] = jnp.full_like(amx_ref, -big)
 
-    send = send_ref[0, :]
-    recv = recv_ref[0, :]
-    local = recv - n_blk * TILE_N
-    cols = lax.broadcasted_iota(jnp.int32, (TILE_E, TILE_N), 1)
-    onblk = local[:, None] == cols                      # [TILE_E, TILE_N]
-    oh = onblk.astype(jnp.float32)
+    # ONE membership mask, TRANSPOSED — nodes on sublanes, edges on lanes
+    # — so that every op below is a 2-D op in its natural layout: plain
+    # and transposed-LHS MXU matmuls for the sums and the proj_i gather,
+    # a lane reduction for the count, and for min/max, per edge, a
+    # [TILE_N, 1] column broadcast against a [1, F] row (the pattern
+    # kernels/nbr_pallas.py uses per neighbour slot). The 3-D
+    # [chunk, TILE_N, F] select this replaces needed a bool
+    # [C, TILE_N] -> [C, TILE_N, 1] shape cast that Mosaic refuses
+    # (v5e, PR 21: "infer-vector-layout: unsupported shape cast").
+    rows = lax.broadcasted_iota(jnp.int32, (TILE_N, TILE_E), 0)
+    onblk_t = rows == (recv_ref[...] - n_blk * TILE_N)  # [TILE_N, TILE_E]
+    oh_t = onblk_t.astype(jnp.float32)
 
     # both gathers are rounding-free one-hot matmuls; the edge message is
     # formed in the data dtype exactly like the unfused
     # proj_i[recv] + proj_j[send]
-    pj_g = _gather_rows(send, pj_ref[...].astype(jnp.float32), dtype)
-    pi_g = lax.dot_general(oh, pi_ref[...].astype(jnp.float32),
-                           (((1,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32).astype(dtype)
+    pj_g = _gather_rows(send_ref[0, :], pj_ref[...].astype(jnp.float32),
+                        dtype)
+    pi_g = _dot(oh_t, pi_ref[...].astype(jnp.float32),
+                (((0,), (0,)), ((), ()))).astype(dtype)
     h_e = pi_g + pj_g                                   # [TILE_E, F]
 
     h32 = h_e.astype(jnp.float32)
     sq32 = (h_e * h_e).astype(jnp.float32)  # square in dtype (mirrors
     # pna_aggregate's packed data*data), accumulate f32
-    s_ref[:] += lax.dot_general(oh, h32, (((0,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    sq_ref[:] += lax.dot_general(oh, sq32, (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    cnt_ref[:] += jnp.sum(oh, axis=0)[:, None]          # exact integers
-
-    # min/max: VPU masked reductions over edge sub-chunks (no matmul
-    # formulation exists; the [MM_CHUNK, TILE_N, F] intermediate stays
-    # in-register/VMEM)
-    for c0 in range(0, TILE_E, MM_CHUNK):
-        sel = onblk[c0:c0 + MM_CHUNK][:, :, None]       # [C, TILE_N, 1]
-        hc = h_e[c0:c0 + MM_CHUNK][:, None, :]          # [C, 1, F]
-        amn_ref[:] = jnp.minimum(amn_ref[:],
-                                 jnp.min(jnp.where(sel, hc, big), axis=0))
-        amx_ref[:] = jnp.maximum(amx_ref[:],
-                                 jnp.max(jnp.where(sel, hc, -big), axis=0))
+    s_ref[:] += _dot(oh_t, h32, (((1,), (0,)), ((), ())))
+    sq_ref[:] += _dot(oh_t, sq32, (((1,), (0,)), ((), ())))
+    cnt_ref[:] += jnp.sum(oh_t, axis=1, keepdims=True)  # exact integers
+    amn, amx = amn_ref[:], amx_ref[:]
+    for c in range(TILE_E):                 # static: unrolled
+        sel = onblk_t[:, c:c + 1]                       # [TILE_N, 1]
+        hc = h_e[c:c + 1, :]                            # [1, F]
+        amn = jnp.minimum(amn, jnp.where(sel, hc, big))
+        amx = jnp.maximum(amx, jnp.where(sel, hc, -big))
+    amn_ref[:] = amn
+    amx_ref[:] = amx
 
     # the mean/std epilogue stays OUTSIDE the kernel (in _pna_call): the
     # kernel's one XLA computation would let the backend contract
@@ -422,24 +428,37 @@ def resolve_fused_mp_flag(refresh: bool = False) -> bool:
     return _RESOLVED_FLAG
 
 
-def fused_mp_enabled(node_array_shape, dtype) -> bool:
-    """Flag on AND the per-tile VMEM residents fit the budget: the whole
-    node array (h / proj_j, read per tile by the one-hot gather) AND the
-    [TILE_E, N] f32 one-hot itself — the one-hot's footprint is
-    TILE_E * N * 4 bytes regardless of F, so a narrow-F/bf16 shape can
-    pass the node-array bound alone while the gather operand blows VMEM
-    on real TPU (interpret mode would never catch it)."""
+def fused_mp_enabled(node_array_shape, dtype, edge_terms: bool = False,
+                     has_edge_mask: bool = True) -> bool:
+    """Flag on AND the kernels apply: no per-edge encoder terms
+    (`edge_terms`), a masked edge list, and the per-tile VMEM residents
+    fit the budget — the whole node array (h / proj_j, read per tile by
+    the one-hot gather) AND the [TILE_E, N] f32 one-hot itself: the
+    one-hot's footprint is TILE_E * N * 4 bytes regardless of F, so a
+    narrow-F/bf16 shape can pass the node-array bound alone while the
+    gather operand blows VMEM on real TPU (interpret mode would never
+    catch it). A flag that is on while the kernel is not taken is logged
+    (kernels.kernel_not_taken), never silent."""
     if not resolve_fused_mp_flag():
         return False
+    from . import kernel_not_taken
     n = node_array_shape[0]
     node_bytes = n * node_array_shape[1] * jnp.dtype(dtype).itemsize
     n_pad = pl.cdiv(n, TILE_N) * TILE_N
     onehot_bytes = TILE_E * n_pad * 4
-    return (node_bytes <= VMEM_BYTES_LIMIT
-            and onehot_bytes <= VMEM_BYTES_LIMIT)
-
-
-def interpret_mode() -> bool:
-    """Pallas interpret mode everywhere but real TPU — how tier-1
-    exercises the kernels on CPU."""
-    return jax.default_backend() != "tpu"
+    why = None
+    if edge_terms:
+        why = "the conv adds per-edge encoder terms (edge_dim/rbf)"
+    elif not has_edge_mask:
+        why = "the batch carries no edge mask"
+    elif node_bytes > VMEM_BYTES_LIMIT:
+        why = (f"the node array is {node_bytes} bytes, over the "
+               f"{VMEM_BYTES_LIMIT}-byte whole-array VMEM bound")
+    elif onehot_bytes > VMEM_BYTES_LIMIT:
+        why = (f"the [TILE_E, N] one-hot is {onehot_bytes} bytes, over the "
+               f"{VMEM_BYTES_LIMIT}-byte VMEM bound")
+    if why is not None:
+        kernel_not_taken("HYDRAGNN_FUSED_MP", "fused_mp_pallas",
+                         tuple(node_array_shape), why)
+        return False
+    return True

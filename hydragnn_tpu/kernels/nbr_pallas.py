@@ -64,8 +64,12 @@ def _kernel(pi_ref, pj_ref, nbr_ref, mask_ref,
     acc_mx = jnp.full((tn, f), -big, dtype)
     for kk in range(k):                    # K is small and static: unroll
         onehot = (idx[:, kk:kk + 1] == iota_n).astype(dtype)   # [TN, N]
+        # HIGHEST: the default MXU precision rounds f32 proj_j to bf16,
+        # and the "gather" would return other values than proj_j[nbr]
+        # (kernels/segment_pallas.py has the on-chip numbers)
         gath = jax.lax.dot_general(
             onehot, pj, (((1,), (0,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32).astype(dtype)
         hk = gath + pi                                          # [TN, F]
         mk = msk[:, kk:kk + 1].astype(dtype)                    # [TN, 1]
@@ -180,9 +184,24 @@ def resolve_nbr_pallas_flag(refresh: bool = False) -> bool:
     return _RESOLVED_FLAG
 
 
-def nbr_pallas_enabled(proj_j_shape, dtype) -> bool:
+def nbr_pallas_enabled(proj_j_shape, dtype, edge_terms: bool = False) -> bool:
+    """Flag on AND the kernel applies: no per-edge encoder terms in the
+    message (`edge_terms` — the kernel only forms proj_i + proj_j[nbr])
+    and proj_j fits the VMEM bound. A flag that is on while the kernel is
+    not taken is logged (kernels.kernel_not_taken), never silent."""
     if not resolve_nbr_pallas_flag():
         return False
+    from . import kernel_not_taken
+    why = None
     nbytes = (proj_j_shape[0] * proj_j_shape[1]
               * jnp.dtype(dtype).itemsize)
-    return nbytes <= VMEM_BYTES_LIMIT
+    if edge_terms:
+        why = "the conv adds per-edge encoder terms (edge_dim/rbf)"
+    elif nbytes > VMEM_BYTES_LIMIT:
+        why = (f"proj_j is {nbytes} bytes, over the {VMEM_BYTES_LIMIT}-byte "
+               "whole-array VMEM bound")
+    if why is not None:
+        kernel_not_taken("HYDRAGNN_PALLAS_NBR", "nbr_pallas",
+                         tuple(proj_j_shape), why)
+        return False
+    return True

@@ -58,7 +58,7 @@ itself is immune: a sum's backward is a cotangent broadcast, so the
 forces that drive the integrator carry no reduction at all. BENCH_MD_FARM
 adjudicates positions/velocities bitwise and energies to 1e-9 relative.
 
-Everything jax-side runs under ``jax.experimental.enable_x64`` (the
+Everything jax-side runs under ``jax.enable_x64`` (the
 integrator state is f64); for the farm-vs-session adjudication the
 reference engine must be compiled under x64 too (BENCH_MD_FARM and the
 tests do), since the trace-time constant dtypes of the model change
@@ -502,7 +502,6 @@ class TrajectoryFarm:
         farm statistics BENCH_MD_FARM reports."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from ..graphs.batch import collate
         from ..preprocess.transforms import build_graph_sample
@@ -575,7 +574,7 @@ class TrajectoryFarm:
         scored = self.scorer is not None
         fresh_compiles_before = self.fresh_compiles
         traces: List[Dict[str, np.ndarray]] = []
-        with enable_x64():
+        with jax.enable_x64(True):
             b_template = jax.tree_util.tree_map(jnp.asarray, b0)
             packed = [self._pack_traj(nls[t], c_cap, w_cap, n)
                       for t in range(T)]

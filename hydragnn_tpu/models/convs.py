@@ -211,18 +211,19 @@ class PNAConv(nn.Module):
                                         name="rbf_proj")(enc))
             return h
 
+        from ..kernels import interpret_mode
+        has_edge_terms = bool(self.edge_dim or self.rbf)
         if batch.nbr is not None:
             from ..kernels.nbr_pallas import (fused_neighbor_aggregate,
                                               nbr_pallas_enabled)
-            if (not self.edge_dim and not self.rbf
-                    and nbr_pallas_enabled(proj_j.shape, proj_j.dtype)):
+            if nbr_pallas_enabled(proj_j.shape, proj_j.dtype,
+                                  edge_terms=has_edge_terms):
                 # fused gather->stats Pallas kernel: no [N, K, F] in HBM
                 # (HYDRAGNN_PALLAS_NBR=1, resolved once at step
-                # construction — kernels/nbr_pallas.py decision record;
-                # on-chip A/B via bench BENCH_NBR_PALLAS)
+                # construction — kernels/nbr_pallas.py decision record)
                 mean, mn, mx, sd, deg = fused_neighbor_aggregate(
                     proj_i, proj_j, batch.nbr, batch.nbr_mask, 128,
-                    jax.default_backend() == "cpu")
+                    interpret_mode())
             else:
                 # dense neighbor-list layout: [N, K, F] messages, axis-1
                 # reductions, zero scatters (with_neighbor_format)
@@ -232,11 +233,10 @@ class PNAConv(nn.Module):
                     h, batch.nbr_mask)
         else:
             from ..kernels.fused_mp_pallas import (fused_mp_enabled,
-                                                   fused_pna_edge_aggregate,
-                                                   interpret_mode)
-            if (not self.edge_dim and not self.rbf
-                    and batch.edge_mask is not None
-                    and fused_mp_enabled(proj_j.shape, proj_j.dtype)):
+                                                   fused_pna_edge_aggregate)
+            if fused_mp_enabled(proj_j.shape, proj_j.dtype,
+                                edge_terms=has_edge_terms,
+                                has_edge_mask=batch.edge_mask is not None):
                 # fused gather->edge-add->stats Pallas kernel: no [E, F]
                 # edge tensor in HBM (HYDRAGNN_FUSED_MP=1, resolved once
                 # at step construction — kernels/fused_mp_pallas.py

@@ -13,7 +13,8 @@
 // Python layer passes the full peer list; on TPU pods that comes from
 // jax.distributed). Local-shard gets short-circuit to memcpy.
 //
-// Build: g++ -O2 -shared -fPIC -o libddstore.so ddstore.cpp -lpthread
+// Build: datasets/ddstore.py compiles this on first use into
+// libddstore-<source hash>.so (g++ -O2 -std=c++17 -shared -fPIC -lpthread)
 //
 // C ABI (ctypes-friendly):
 //   dds_init(rank, world) -> handle

@@ -38,8 +38,9 @@ def _use_pallas() -> bool:
     """
     if not _PALLAS_STATE["checked"]:
         from ..utils.envflags import env_strict_flag
+        from ..kernels import interpret_mode
         _PALLAS_STATE["on"] = env_strict_flag("HYDRAGNN_USE_PALLAS", False)
-        _PALLAS_STATE["interpret"] = jax.default_backend() == "cpu"
+        _PALLAS_STATE["interpret"] = interpret_mode()
         _PALLAS_STATE["checked"] = True
     return _PALLAS_STATE["on"]
 
@@ -223,17 +224,17 @@ def filter_weighted_aggregate(h, w, batch):
     if batch.nbr_edge is not None:
         return neighbor_sum((h[batch.senders] * w)[batch.nbr_edge],
                             batch.nbr_mask)
-    if batch.edge_mask is not None:
-        from ..kernels.fused_mp_pallas import (fused_filter_scatter,
-                                               fused_mp_enabled,
-                                               interpret_mode)
-        # VMEM bound against the PROMOTED dtype: a bf16 h multiplied by
-        # an f32 filter runs the kernel in f32 (fused_mp_pallas mirrors
-        # the unfused promotion)
-        if fused_mp_enabled(h.shape, jnp.promote_types(h.dtype, w.dtype)):
-            return fused_filter_scatter(h, w, batch.senders,
-                                        batch.receivers, batch.edge_mask,
-                                        batch.num_nodes, interpret_mode())
+    from ..kernels import interpret_mode
+    from ..kernels.fused_mp_pallas import (fused_filter_scatter,
+                                           fused_mp_enabled)
+    # VMEM bound against the PROMOTED dtype: a bf16 h multiplied by
+    # an f32 filter runs the kernel in f32 (fused_mp_pallas mirrors
+    # the unfused promotion)
+    if fused_mp_enabled(h.shape, jnp.promote_types(h.dtype, w.dtype),
+                        has_edge_mask=batch.edge_mask is not None):
+        return fused_filter_scatter(h, w, batch.senders,
+                                    batch.receivers, batch.edge_mask,
+                                    batch.num_nodes, interpret_mode())
     return segment_sum(h[batch.senders] * w, batch.receivers,
                        batch.num_nodes, batch.edge_mask)
 

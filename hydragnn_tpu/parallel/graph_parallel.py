@@ -34,12 +34,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -161,8 +156,8 @@ def ring_aggregate(message_fn: Callable, x_block: jnp.ndarray,
     aggregation (receiver-partitioned — no final collective needed).
     """
     # ring length == mesh axis size == leading dim of the per-sender-block
-    # bucket stack; read it from the static shape (jax.lax.axis_size is not
-    # available on jax 0.4.x, and ppermute needs a static permutation anyway)
+    # bucket stack; read it from the static shape (ppermute needs a static
+    # permutation)
     d = buckets.send_local.shape[0]
     perm = [(i, (i + 1) % d) for i in range(d)]
     block = x_block.shape[0]

@@ -45,12 +45,8 @@ def init_distributed(coordinator: Optional[str] = None,
     # must not touch the XLA backend before jax.distributed.initialize
     # (jax.process_count() would initialise it), so probe the distributed
     # client state instead
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # jax < 0.5 has no is_initialized()
-        from jax._src import distributed as _dist
-        already = _dist.global_state.client is not None
-    if not already and (coordinator or os.getenv("HYDRAGNN_MASTER_ADDR")):
+    if not jax.distributed.is_initialized() and (
+            coordinator or os.getenv("HYDRAGNN_MASTER_ADDR")):
         coord = coordinator or (
             os.environ["HYDRAGNN_MASTER_ADDR"] + ":" +
             os.environ.get("HYDRAGNN_MASTER_PORT", "12355"))
@@ -68,25 +64,9 @@ def init_distributed(coordinator: Optional[str] = None,
         # contract: never wedge an allocation on a missing peer), it
         # just skips the prettier message below
         try:
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coord, num_processes=nproc,
-                    process_id=pid, **kwargs)
-            except TypeError:
-                if not kwargs:
-                    raise
-                # this jax predates initialization_timeout: fall back to
-                # the unbounded rendezvous rather than failing a run
-                # whose peers may be perfectly healthy
-                import logging
-                logging.getLogger("hydragnn_tpu").warning(
-                    "this jax does not support a rendezvous "
-                    "initialization timeout; HYDRAGNN_RENDEZVOUS_"
-                    "TIMEOUT_S=%g is ignored for initialize()",
-                    timeout_s)
-                jax.distributed.initialize(
-                    coordinator_address=coord, num_processes=nproc,
-                    process_id=pid)
+            jax.distributed.initialize(
+                coordinator_address=coord, num_processes=nproc,
+                process_id=pid, **kwargs)
         except Exception as exc:  # noqa: BLE001 — re-raise actionable
             msg = str(exc).lower()
             if timeout_s and ("deadline" in msg or "timed out" in msg):
@@ -131,7 +111,12 @@ def resolve_num_shards(num_shards: Optional[int], batch_size: int,
     to all devices when more than one, fall back to single-program when the
     batch doesn't divide or the request exceeds the device count.
     `device_budget` caps the devices available to the data axis (a composed
-    mesh reserves device_count/graph_shards for the graph axis)."""
+    mesh reserves device_count/graph_shards for the graph axis).
+
+    A fallback is never silent: an explicit request that cannot be met
+    warns, and the implicit all-devices default that cannot be met says so
+    in the startup log (devices seen, shards used, why) — otherwise a
+    multi-chip host quietly trains on device 0 only."""
     ndev = device_budget if device_budget is not None else jax.device_count()
     explicit = num_shards is not None
     if num_shards is None:
@@ -139,14 +124,21 @@ def resolve_num_shards(num_shards: Optional[int], batch_size: int,
             else 1
     num_shards = max(int(num_shards), 1)
     if num_shards > ndev or batch_size % num_shards != 0:
+        reason = (f"exceeds device count {ndev}"
+                  if num_shards > ndev else
+                  f"does not divide batch_size {batch_size}")
         if explicit and num_shards > 1:
             import warnings
-            reason = (f"exceeds device count {ndev}"
-                      if num_shards > ndev else
-                      f"does not divide batch_size {batch_size}")
             warnings.warn(
                 f"requested num_shards={num_shards} {reason}; "
                 f"falling back to a single-device run", stacklevel=2)
+        elif not explicit:
+            import logging
+            logging.getLogger("hydragnn_tpu").warning(
+                "data parallelism off: %d devices seen, 1 shard used — "
+                "the default num_shards=%d %s, so this run computes on "
+                "device 0 only. Pass num_shards or pick a batch_size the "
+                "device count divides.", ndev, num_shards, reason)
         return 1
     return num_shards
 

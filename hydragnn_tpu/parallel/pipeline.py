@@ -61,12 +61,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 # pipeline schedules (docs/pipeline.md): the forward tick pattern is
@@ -267,12 +262,8 @@ def make_pipeline_apply(mesh: Mesh, layer_fn: Callable, num_layers: int,
     else:
         in_specs = (P(axis), P(data_axis), P(data_axis))
         out_specs = P(axis, data_axis)
-    try:
-        mapped = shard_map(pipelined, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    except TypeError:  # jax < 0.6 names the replication check check_rep
-        mapped = shard_map(pipelined, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+    mapped = shard_map(pipelined, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def apply(stage_params, x_micro, structure):
         return mapped(stage_params, x_micro, structure)[S - 1]

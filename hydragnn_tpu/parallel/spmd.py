@@ -23,10 +23,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import optax
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config.config import ModelConfig

@@ -41,8 +41,8 @@ def run_prediction(config_or_path, datasets: Optional[Tuple] = None,
     loop. Outputs are bitwise-identical between the two paths on the same
     bucket shapes (tests/test_serving.py)."""
     config = load_config(config_or_path)
-    from .utils.devices import enable_compile_cache, resolve_compile_cache_dir
-    enable_compile_cache(resolve_compile_cache_dir())
+    from .utils.devices import enable_compile_cache
+    enable_compile_cache()
     if datasets is None:
         from .run_training import _load_datasets_from_config
         datasets = _load_datasets_from_config(config)
@@ -296,7 +296,13 @@ def _predict_with_engine(model, state, mcfg, testset, serving, num_shards,
             import logging
             logging.getLogger("hydragnn_tpu").info(
                 "serving metrics endpoint at %s/metrics", http.url)
-        server.warmup()
+        warm = server.warmup()
+        if fleet.replicas > 1:
+            # per replica: programs compiled fresh vs loaded from the
+            # compile store, and the devices they execute on
+            import logging
+            logging.getLogger("hydragnn_tpu").info(
+                "serving warm-up: %s", warm)
         results = server.predict(testset)
     finally:
         server.shutdown()

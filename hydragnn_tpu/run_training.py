@@ -91,13 +91,6 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
 
     from .utils.envflags import (env_flag, env_int, resolve_pack_lookahead,
                                  resolve_packing, resolve_steps_per_call)
-    # HYDRAGNN_COMPILE_CACHE_DIR (or legacy HYDRAGNN_COMPILE_CACHE):
-    # persistent XLA compilation cache wired at startup so the handful of
-    # bucket/pack shapes compile once per machine, not per run (opt-in;
-    # bench.py defaults it on for TPU)
-    from .utils.devices import (enable_compile_cache,
-                                resolve_compile_cache_dir)
-    enable_compile_cache(resolve_compile_cache_dir())
     # deterministic fault injection (docs/fault_tolerance.md): the plan —
     # HYDRAGNN_FAULT_PLAN env over Training.fault_plan, strict parsing —
     # is installed per run so site counters start fresh; a stale
@@ -108,6 +101,11 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
         config.get("NeuralNetwork", {}).get("Training", {})))
     clear_preemption()
     init_distributed()
+    # persistent XLA compilation cache (utils/devices.enable_compile_cache
+    # holds the one placement rule); after the rendezvous because the rule
+    # reads the backend
+    from .utils.devices import enable_compile_cache
+    enable_compile_cache()
     # TRACE_LEVEL>0 also turns on synchronous region timing (the cudasync
     # analogue: block_until_ready before closing a span — reference:
     # tracer.py:106-127)

@@ -434,10 +434,16 @@ class InferenceEngine:
                     batch_transform=self.batch_transform)
             self.quant_calibration = quant_calibration
             self._quant_digest = quant_calibration.digest
+        # the devices this engine's programs execute on — what a program
+        # reloaded from the compile store must be loaded onto
+        # (utils/devices.CompileStore.load). Nothing places an engine
+        # yet: a single-shard engine runs on the default device.
+        self.devices = jax.devices()[:1]
         if self.num_shards > 1:
             from ..parallel.mesh import make_mesh
             from ..parallel.spmd import make_spmd_forward
             mesh = make_mesh((("data", self.num_shards),))
+            self.devices = list(mesh.devices.flat)
             self._jit_forward = make_spmd_forward(model, mesh, mcfg,
                                                   compute_dtype)
         else:
@@ -1132,7 +1138,8 @@ class InferenceEngine:
         compiled = None
         from_store = False
         if self._compile_store is not None:
-            compiled = self._compile_store.load(self._store_key(bucket))
+            compiled = self._compile_store.load(
+                self._store_key(bucket), self.devices)
             from_store = compiled is not None
         if compiled is None:
             compiled = self._jit_forward.lower(variables,

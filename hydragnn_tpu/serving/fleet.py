@@ -295,8 +295,9 @@ class ReplicaRouter:
 
     def warmup(self) -> List[dict]:
         """Warm every live replica's bucket ladder; per-replica report of
-        {replica, compiled, store_hits, fresh} — on a populated compile
-        store, `fresh` is 0 (the BENCH_SERVE_FLEET adjudication)."""
+        {replica, compiled, store_hits, fresh, devices} — on a populated
+        compile store, `fresh` is 0 (the BENCH_SERVE_FLEET adjudication);
+        `devices` names where the replica's programs execute."""
         reports = []
         for rep in self._replicas:
             with self._lock:
@@ -308,7 +309,9 @@ class ReplicaRouter:
             reports.append({"replica": rep.idx,
                             "compiled": st["compile_count"],
                             "store_hits": st["compile_store_hits"],
-                            "fresh": st["compile_fresh"]})
+                            "fresh": st["compile_fresh"],
+                            "devices": ",".join(
+                                str(d) for d in rep.engine.devices)})
         return reports
 
     def health(self) -> dict:

@@ -12,12 +12,13 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
-# bf16-MXU peak FLOP/s by device kind (public spec sheets); MFU is
-# measured achieved FLOP/s over this peak. f32 compute gets half the
-# bf16 peak (the MXU multiplies in bf16; f32 matmuls take 2+ passes) so
-# cross-dtype MFU comparisons rank utilization, not throughput rescaled
-# by one constant. Unknown kinds fall back to the v5e figure; override
-# with BENCH_PEAK_FLOPS (bench) / the `peak_override` argument.
+# bf16-MXU peak FLOP/s by `jax.devices()[0].device_kind` (public spec
+# sheets; the v5e reports itself as "TPU v5 lite"); MFU is measured
+# achieved FLOP/s over this peak. f32 compute gets half the bf16 peak
+# (the MXU multiplies in bf16; f32 matmuls take 2+ passes) so cross-dtype
+# MFU comparisons rank utilization, not throughput rescaled by one
+# constant. A kind that is not in the table is an error, not a default;
+# name its peak with BENCH_PEAK_FLOPS (bench) / `peak_override`.
 PEAK_FLOPS: Dict[str, float] = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -33,10 +34,17 @@ def peak_flops(device_kind: str, compute_dtype: str = "float32",
                peak_override: float = 0.0) -> float:
     """Per-dtype peak FLOP/s for `device_kind`. An explicit override is
     taken as-is (it names the dtype's own peak); otherwise the bf16 table
-    entry, halved for f32 compute."""
+    entry, halved for f32 compute. Raises for a kind the table does not
+    hold — a utilization against a guessed peak is not a measurement."""
     if peak_override:
         return float(peak_override)
-    peak = PEAK_FLOPS.get(device_kind, PEAK_FLOPS["TPU v5e"])
+    if device_kind not in PEAK_FLOPS:
+        raise ValueError(
+            f"no peak FLOP/s on record for device kind {device_kind!r} "
+            f"(known: {sorted(PEAK_FLOPS)}); add it to "
+            "telemetry/mfu.PEAK_FLOPS with its source or pass an "
+            "explicit peak override")
+    peak = PEAK_FLOPS[device_kind]
     if compute_dtype in ("float32", "f32", None):
         peak /= 2.0
     return peak

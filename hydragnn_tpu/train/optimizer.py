@@ -76,11 +76,20 @@ def get_learning_rate(opt_state) -> float:
 
 
 def set_learning_rate(opt_state, lr: float):
+    """Overwrite the injected learning-rate hyperparameter in place. The
+    new leaf keeps the old one's placement when that spans several
+    devices: an SPMD step hands back its optimizer state replicated over
+    the mesh, and a fresh single-device scalar in its place changes the
+    step's input shardings — one full recompile of the train step at the
+    first LR reduction (seen on the four-chip host, PR 21)."""
+    import jax
     import jax.numpy as jnp
     target = _lr_state(opt_state)
     old = target.hyperparams["learning_rate"]
-    target.hyperparams["learning_rate"] = jnp.asarray(
-        lr, dtype=getattr(old, "dtype", jnp.float32))
+    new = jnp.asarray(lr, dtype=getattr(old, "dtype", jnp.float32))
+    if isinstance(old, jax.Array) and len(old.sharding.device_set) > 1:
+        new = jax.device_put(new, old.sharding)
+    target.hyperparams["learning_rate"] = new
     return opt_state
 
 

@@ -198,10 +198,57 @@ def latency_percentiles(latencies_s, percentiles=(50, 95, 99)) -> Dict[str, floa
     return out
 
 
+class CompileWatch:
+    """Process-wide count of XLA backend compiles and the seconds spent
+    in them, read off jax's own monitoring events — every program any
+    jitted callable or AOT ``.lower().compile()`` builds while the
+    watch is open, whoever owns the callable (``jit_cache_size`` below
+    needs the callable in hand). ``count`` includes programs served
+    from the persistent compilation cache (their ``seconds`` are the
+    retrieval time); ``cache_hits`` says how many those were. The
+    accelerator smoke (chip_smoke.py) reports cold and warm compile
+    seconds from it and asserts no compile lands after warm-up.
+
+        with CompileWatch() as watch:
+            ...
+            before = watch.count
+            serve()
+            assert watch.count == before
+    """
+
+    _COMPILE = "/jax/core/compile/backend_compile_duration"
+    _CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+        self.cache_hits = 0
+
+    def _on_duration(self, event: str, duration: float, **_):
+        if event == self._COMPILE:
+            self.count += 1
+            self.seconds += duration
+
+    def _on_event(self, event: str, **_):
+        if event == self._CACHE_HIT:
+            self.cache_hits += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
+        return False
+
+
 def jit_cache_size(fn) -> Optional[int]:
     """Number of compiled programs a jitted callable currently holds
-    (jax 0.4.x PjitFunction `_cache_size`); None when `fn` is not a
-    jitted function (or the introspection API moved). The trainer/bench
+    (PjitFunction `_cache_size`, confirmed to count on jax 0.9.0); None
+    when `fn` is not a jitted function. The trainer/bench
     report this as the recompile counter — budget-packed batching must
     keep it at ONE program per step function (docs/packing.md).
 

@@ -40,6 +40,8 @@ def test_graph_shards_config_trains():
     assert history["train_loss"][-1] < history["train_loss"][0] * 5
 
 
+# slow lane since PR 21 (tier-1 budget): 27 s; test_graph_shards_with_data_parallel keeps the composed mesh in tier-1
+@pytest.mark.slow
 def test_graph_shards_matches_single_device():
     """Same seeds, same data: losses with graph_shards=4 must track the
     plain single-device run (GSPMD partitions, math unchanged)."""

@@ -100,10 +100,7 @@ def test_ring_bucket_invariants():
 def test_edge_sharded_inside_shard_map_composes(mesh):
     """edge_sharded_aggregate is usable as a building block inside a larger
     shard_map (e.g. a full conv layer with pre/post MLPs)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     x, send, recv = random_graph(n_nodes=100, n_edges=1000, seed=3)

@@ -108,7 +108,12 @@ def test_gaussian_nll_var_output(samples):
     assert np.all(np.asarray(outputs_var[0]) >= 0)
 
 
-@pytest.mark.parametrize("model_type", ["GIN", "PAINN", "PNAEq"])
+# the two vector-channel stacks compile for ~25 s each on CPU: slow lane
+# since PR 21 (tier-1 budget); GIN keeps the conv-head path in tier-1
+@pytest.mark.parametrize("model_type", [
+    "GIN",
+    pytest.param("PAINN", marks=pytest.mark.slow),
+    pytest.param("PNAEq", marks=pytest.mark.slow)])
 def test_conv_node_head(model_type, samples):
     """Node head of type 'conv' (reference: Base.py:262-290; for the
     vector-channel stacks the head convs thread the encoder's final v,

@@ -304,8 +304,22 @@ def test_loss_trajectory_equivalence_packed_vs_fixed():
     """Packed batching must train equivalently to fixed-shape batching on
     a tiny fixture: both see every sample once per epoch (num_shards=1
     packs drop nothing), so the loss trajectories should land in the
-    same place (different batch compositions => not bitwise, but close
-    after a few epochs)."""
+    same place (different batch compositions => not bitwise, but close).
+
+    Re-conditioned in PR 21, when the jax 0.9.0 compiler moved this test
+    out of its band. Two causes, both the TEST's:
+    * it scored each run on its OWN loader's batches with an unweighted
+      mean of per-batch means. Packed batches hold unequal graph counts,
+      so that number differs for identical weights — after one epoch 0.166
+      (packed) vs 0.097 (fixed) where a common graph-weighted evaluation
+      reads 0.0969 vs 0.0973. Both runs are now scored on ONE fixed-shape,
+      unshuffled loader (six full batches of eight).
+    * at the fixture's lr 5e-3 with six steps per epoch a single run's
+      loss swings 2-5x from epoch to epoch, wider than any band between
+      two runs; which side of it epoch 6 lands on is decided by last-bit
+      rounding. At lr 1e-3 both trajectories are smooth and fall 2x in
+      six epochs; measured packed-vs-fixed difference there: 7-13% over
+      three shuffle seeds. The band is 25%."""
     import jax
     from hydragnn_tpu.config import build_model_config, update_config
     from hydragnn_tpu.models.create import create_model, init_params
@@ -318,10 +332,13 @@ def test_loss_trajectory_equivalence_packed_vs_fixed():
     samples = deterministic_graph_dataset(num_configs=48, heads=("graph",))
     cfg = make_config("PNA", heads=("graph",), hidden_dim=8,
                       num_conv_layers=1, radius=1.0)
+    cfg["NeuralNetwork"]["Training"]["Optimizer"]["learning_rate"] = 1e-3
     cfg = update_config(cfg, samples)
     mcfg = build_model_config(cfg)
     model = create_model(mcfg)
     tx = select_optimizer(cfg["NeuralNetwork"]["Training"])
+    score_on = GraphDataLoader(samples, batch_size=8, shuffle=False,
+                               async_workers=0)
 
     def train(packing, epochs=6):
         ld = GraphDataLoader(samples, batch_size=8, shuffle=True, seed=0,
@@ -336,23 +353,17 @@ def test_loss_trajectory_equivalence_packed_vs_fixed():
             ld.set_epoch(e)
             for b in ld:
                 state, _ = step(state, b)
-            tot = n = 0
-            for b in ld:  # eval over the same (epoch e) stream
-                out = evl(state, b)
-                m = out[0] if isinstance(out, tuple) else out
-                tot += float(np.asarray(m["loss"]))
-                n += 1
-            losses.append(tot / max(n, 1))
+            per_batch = [float(np.asarray(evl(state, b)[0]["loss"]))
+                         for b in score_on]
+            losses.append(sum(per_batch) / len(per_batch))
         return losses
 
     fixed = train(False)
     packed = train(True)
-    assert packed[-1] < packed[0], f"packed did not learn: {packed}"
-    assert fixed[-1] < fixed[0], f"fixed did not learn: {fixed}"
-    # same converged neighborhood: within 50% relative (tiny-run noise
-    # from differing batch compositions), and both clearly below start
-    ref = max(abs(fixed[-1]), 1e-8)
-    assert abs(packed[-1] - fixed[-1]) / ref < 0.5, (fixed, packed)
+    assert packed[-1] < 0.6 * packed[0], f"packed did not learn: {packed}"
+    assert fixed[-1] < 0.6 * fixed[0], f"fixed did not learn: {fixed}"
+    assert abs(packed[0] - fixed[0]) / fixed[0] < 0.02, (fixed, packed)
+    assert abs(packed[-1] - fixed[-1]) / fixed[-1] < 0.25, (fixed, packed)
 
 
 # ------------------------------------------------- CI smoke perf guard

@@ -75,6 +75,8 @@ def test_pipeline_matches_sequential():
                                rtol=2e-5, atol=2e-5)
 
 
+# slow lane since PR 21 (tier-1 budget): 19 s; gradient equivalence stays pinned by the exact-data 1f1b/gpipe/sequential test
+@pytest.mark.slow
 def test_pipeline_differentiable():
     x, structure, params = _random_problem(1)
     mesh = make_mesh((("pipe", S),), devices=jax.devices()[:S])
@@ -138,9 +140,18 @@ def test_pipeline_forward_bitwise_and_remat():
     random floats (identical per-microbatch op sequence — the banked
     last-stage slice replaces the seed's psum broadcast, which was also
     value-exact but shipped a full zero tensor per stage); remat on is
-    bitwise vs remat off (jax.checkpoint recomputes the same ops)."""
+    bitwise vs remat off (jax.checkpoint recomputes the same ops).
+
+    The contract stays BITWISE, between COMPILED programs: the reference
+    is the jitted sequential stack. Decided in PR 21: under jax 0.9.0 the
+    op-by-op (eager) stack and its own jitted form differ by one ulp
+    (7.6e-06 at |h| ~ 100, 32% of elements) because XLA:CPU fuses
+    (x + agg) @ w + b inside one program and cannot across eager
+    dispatches — a property of the compiler, not of the schedule. The
+    pipeline matches the jitted stack exactly (measured 0.0 max diff), and
+    that is what a user runs: every training path is jitted."""
     x, structure, params = _random_problem(3)
-    expect = _sequential(params, x, structure)
+    expect = jax.jit(_sequential)(params, x, structure)
     mesh = make_mesh((("pipe", S),), devices=jax.devices()[:S])
     stacked = stack_stage_params(params, S)
     got = make_pipeline_apply(mesh, _layer_fn, L)(stacked, x, structure)

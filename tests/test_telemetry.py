@@ -233,8 +233,17 @@ def test_peak_flops_halves_f32():
     bf16 = peak_flops("TPU v5e", "bfloat16")
     f32 = peak_flops("TPU v5e", "float32")
     assert f32 == pytest.approx(bf16 / 2)
-    assert peak_flops("unknown kind", "bfloat16") == bf16
+    # the kind string the v5e reports about itself
+    assert peak_flops("TPU v5 lite", "bfloat16") == bf16
     assert peak_flops("TPU v5e", "bfloat16", peak_override=1e12) == 1e12
+
+
+def test_peak_flops_unknown_kind_raises():
+    # an unknown device is an error that names the kind, never a silent
+    # v5e default; an explicit override still names the peak itself
+    with pytest.raises(ValueError, match="no such chip"):
+        peak_flops("no such chip")
+    assert peak_flops("no such chip", peak_override=2e12) == 2e12
 
 
 def test_achieved_and_mfu_gates():

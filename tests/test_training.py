@@ -309,6 +309,8 @@ def test_conv_checkpointing_equivalent():
                                    rtol=1e-5, atol=1e-6)
 
 
+# slow lane since PR 21 (tier-1 budget): 15 s; test_steps_per_call_through_run_training keeps the path in tier-1
+@pytest.mark.slow
 def test_steps_per_call_multi_step_equivalence():
     """make_multi_train_step: one scanned dispatch over S stacked batches is
     bit-identical to S sequential single-step calls (dispatch-latency
@@ -395,6 +397,8 @@ def test_steps_per_call_through_run_training(monkeypatch):
     assert int(state.step) == 3
 
 
+# slow lane since PR 21 (tier-1 budget): 13 s
+@pytest.mark.slow
 def test_spmd_steps_per_call_equivalence():
     """SPMD multi-step: one scanned dispatch over [S, D, ...] stacks matches
     S sequential SPMD steps, and Training.steps_per_call works end-to-end
@@ -462,6 +466,48 @@ def test_spmd_steps_per_call_equivalence():
     assert all(np.isfinite(v) for v in history["train_loss"])
 
 
+def test_lr_reduction_does_not_recompile_the_spmd_step():
+    """ReduceLROnPlateau rewrites the injected LR leaf between steps. On
+    the SPMD path that leaf comes back from the step replicated over the
+    mesh; a fresh single-device scalar in its place changed the step's
+    input shardings and cost one full train-step recompile at the first
+    reduction (seen on the four-chip TPU host, PR 21)."""
+    import jax
+    from hydragnn_tpu.config import build_model_config, update_config
+    from hydragnn_tpu.datasets.loader import GraphDataLoader
+    from hydragnn_tpu.models.create import create_model, init_params
+    from hydragnn_tpu.parallel.mesh import make_mesh, shard_batch
+    from hydragnn_tpu.parallel.spmd import make_spmd_train_step
+    from hydragnn_tpu.train.optimizer import (get_learning_rate,
+                                              select_optimizer,
+                                              set_learning_rate)
+    from hydragnn_tpu.train.train_step import TrainState
+    from hydragnn_tpu.utils.profiling import jit_cache_size
+
+    ndev = 8
+    samples = deterministic_graph_dataset(num_configs=16)
+    cfg = update_config(make_config("SAGE", heads=("graph",)), samples)
+    mcfg = build_model_config(cfg)
+    model = create_model(mcfg)
+    loader = GraphDataLoader(samples, batch_size=2 * ndev, num_shards=ndev,
+                             shuffle=False)
+    batch = next(iter(loader))
+    init_b = jax.tree_util.tree_map(
+        lambda a: None if a is None else a[0], batch)
+    tx = select_optimizer(cfg["NeuralNetwork"]["Training"])
+    state = TrainState.create(init_params(model, init_b), tx)
+    mesh = make_mesh((("data", ndev),))
+    step = make_spmd_train_step(model, mcfg, tx, mesh)
+    for _ in range(2):  # host-resident init state, then its own output
+        state, _ = step(state, shard_batch(batch, mesh))
+    warm = jit_cache_size(step)
+    for lr in (2.5e-3, 1.25e-3):
+        set_learning_rate(state.opt_state, lr)
+        state, _ = step(state, shard_batch(batch, mesh))
+        assert get_learning_rate(state.opt_state) == pytest.approx(lr)
+    assert jit_cache_size(step) == warm
+
+
 def test_per_task_val_test_history():
     """val/test per-task losses recorded every epoch (reference:
     task_loss_val/test, train_validate_test.py:93-96)."""
@@ -480,6 +526,8 @@ def test_per_task_val_test_history():
     assert history["nonfinite_steps"] == [0.0, 0.0]
 
 
+# slow lane since PR 21 (tier-1 budget): 13 s
+@pytest.mark.slow
 def test_gradient_accumulation_matches_large_batch():
     """gradient_accumulation_steps=2 with batch B/2 must match one step at
     batch B (equal-size micro-batches -> mean of means == combined grad);
@@ -554,6 +602,8 @@ def test_spmd_bfloat16_training():
         assert leaf.dtype == np.float32, leaf.dtype
 
 
+# slow lane since PR 21 (tier-1 budget): 17 s
+@pytest.mark.slow
 def test_force_loss_weight_auto_matches_reference_balancing():
     """Training.force_loss_weight "auto" reproduces the reference's
     magnitude balancing (Base.energy_force_loss force_loss_weight,

@@ -61,7 +61,7 @@ case "$shard" in
       tests/test_serving.py tests/test_serving_faults.py \
       tests/test_serving_fleet.py \
       tests/test_faults.py tests/test_env_lint.py tests/test_lint.py \
-      tests/test_ref_shims.py tests/test_telemetry.py
+      tests/test_ref_shims.py tests/test_telemetry.py tests/test_devices.py
     # the HPO supervisor suite runs its fast lane here; its slow lane is
     # a multi-minute subprocess chaos e2e (real child training
     # processes) covered by the nightly hpo-chaos job

@@ -42,9 +42,12 @@ def build_worker_command(args, process_id=None, num_hosts=None):
     """The command every worker runs."""
     env = {
         "HYDRAGNN_NUM_WORKERS": str(args.prefetch_workers),
-        "HYDRAGNN_COMPILE_CACHE": args.compile_cache,
         "HYDRAGNN_STEPS_PER_CALL": str(args.steps_per_call),
     }
+    if args.compile_cache:
+        # jax's own variable; unset, every worker uses
+        # <checkout>/.jax_cache (utils/devices.enable_compile_cache)
+        env["JAX_COMPILATION_CACHE_DIR"] = args.compile_cache
     if args.graphstore_root:
         if process_id is None:
             # gcloud --worker=all runs one identical command everywhere;
@@ -117,7 +120,10 @@ def main(argv=None):
     p.add_argument("--steps-per-call", type=int,
                    default=DEFAULT_STEPS_PER_CALL)
     p.add_argument("--prefetch-workers", type=int, default=2)
-    p.add_argument("--compile-cache", default=".jax_cache")
+    p.add_argument("--compile-cache", default=None,
+                   help="persistent XLA cache dir for every worker "
+                        "(exported as JAX_COMPILATION_CACHE_DIR; default: "
+                        "the checkout's .jax_cache)")
     p.add_argument("--graphstore-root", default=None,
                    help="root dir of per-host GraphStore shards "
                         "(shard_<pid> per process)")
