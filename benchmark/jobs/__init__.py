@@ -1,0 +1,8 @@
+"""Jobs: what a traffic mix's `job` names. A job's ``run(ctx)`` sets the
+system up, calls ``ctx.open_window()``, drives the load for
+``ctx.window_seconds()``, calls ``ctx.close_window()``, checks the outputs
+and returns
+
+    {"end_to_end": {metric: value}, "attempted": n, "failed": n,
+     "checks": {name: bool}, "counters": {...}, "work": {...}, "arch": {...}}
+"""

@@ -1,0 +1,255 @@
+"""Energy+force training through the loop users run.
+
+The job composes what ``run_training`` composes on its default path
+(`system.Training`) and hands it to ``train/trainer.train_validate_test``
+— epoch loop, async loader, device prefetch, per-step loss fetch,
+validation and test passes, best-state copy — with the step callable
+wrapped by a counter. After the warm-up steps the counter syncs and opens
+the window; it counts the optimizer steps and their REAL graphs, atoms and
+edges (read on the host from each batch's masks as it is placed); when the
+window's time has passed it syncs on the last step's output, closes the
+window and asks the trainer to stop (``trainer.request_preemption``).
+
+Not as a user has it: no TensorBoard writer (HYDRAGNN_DISABLE_TB, saves
+importing torch in every run), no checkpoint, weights from ``--seed``.
+"""
+from __future__ import annotations
+
+import os
+import time
+from typing import Dict, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .. import say, system
+from ..reference import common
+from . import checks
+
+WARMUP_STEPS = 2   # the trainer's first steps: both compiled variants of a
+#                    data-parallel step (PERF.md, PR 21) and the prefetch
+
+
+class WindowedStep:
+    """`train_step` with the window round it. Not jitted itself: the
+    trainer's recompile counter skips it and reads the eval step."""
+
+    def __init__(self, step, ctx, max_steps=None):
+        self.step, self.ctx, self.max_steps = step, ctx, max_steps
+        self.calls = 0
+        self.t0 = self.t1 = None
+        self.losses: List = []
+        self.nonfinite: List = []
+        self.work = {"graphs": 0, "atoms": 0, "edges": 0, "steps": 0,
+                     "node_slots": 0}
+        self._placed: Dict[int, tuple] = {}
+
+    def placing(self, place):
+        """Wrap the placement: count a batch's real content on the host,
+        keyed by the placed batch the step will receive."""
+        def placed(batch):
+            counts = (int(np.sum(batch.graph_mask)),
+                      int(np.sum(batch.node_mask)),
+                      int(np.sum(batch.edge_mask)),
+                      int(np.size(batch.node_mask)))
+            out = place(batch)
+            self._placed[id(out)] = counts
+            return out
+        return placed
+
+    def __call__(self, state, batch):
+        from hydragnn_tpu.train import trainer
+        counts = self._placed.pop(id(batch), None)
+        if self.calls == WARMUP_STEPS and self.t0 is None:
+            jax.block_until_ready(state)
+            self.ctx.open_window()
+            self.t0 = time.perf_counter()
+        state, metrics = self.step(state, batch)
+        self.calls += 1
+        if self.t0 is not None and self.t1 is None:
+            self.losses.append(metrics["loss"])
+            self.nonfinite.append(metrics["nonfinite_steps"])
+            self.work["steps"] += 1
+            for key, n in zip(("graphs", "atoms", "edges", "node_slots"),
+                              counts):
+                self.work[key] += n
+            out_of_time = (time.perf_counter() - self.t0
+                           >= self.ctx.window_seconds())
+            if out_of_time or self.work["steps"] == self.max_steps:
+                jax.block_until_ready((state, metrics))
+                self.t1 = time.perf_counter()
+                self.ctx.close_window()
+                trainer.request_preemption()
+        return state, metrics
+
+
+@jax.jit
+def copy_state(state):
+    """A copy of the train state in one program. The step donates its
+    state, and both the check step and the trainer's first step must see
+    the same kind of array (the trainer's would otherwise compile the step
+    a third time)."""
+    return jax.tree_util.tree_map(jnp.copy, state)
+
+
+class Checks:
+    """The step programs against the plain reference, on the check
+    structures, with the same weights (`jobs/checks.py`).
+
+    `as_run()` comes before the window and is its warm-up: it compiles and
+    runs the train step and the eval step in the variants the trainer will
+    call, and keeps what they gave. `judge()` comes after the window: the
+    reference, and the same programs traced at highest matmul precision."""
+
+    def __init__(self, comp, state, doc, config):
+        self.comp, self.state, self.doc, self.config = comp, state, doc, config
+        self.chk = system.check_structures(
+            comp.loaders[2].dataset,
+            min(system.CHECK_STRUCTURES, comp.loaders[0].batch_size))
+        self.placed = comp.place(comp.collate(self.chk))
+        # the positions, in `chk`, of the structures each shard holds
+        self.shards = [list(range(len(self.chk)))[i::comp.num_shards]
+                       for i in range(comp.num_shards)]
+
+    def _first_step(self):
+        """(the state one train step on the check batch leaves, its loss),
+        under the matmul precision in force."""
+        stepped, metrics = self.comp.train_step(copy_state(self.state),
+                                                self.placed)
+        return stepped, float(metrics["loss"])
+
+    def _evaluate(self):
+        """The eval step on the check batch with the weights `as_run` left,
+        under the matmul precision in force."""
+        return jax.device_get(self.comp.eval_step(self.stepped, self.placed))
+
+    def as_run(self) -> None:
+        self.stepped, self.loss = self._first_step()
+        self.evaluated = self._evaluate()
+        # the step on its own output: a data-parallel step compiles once
+        # more for it (PERF.md, PR 21)
+        self.comp.train_step(copy_state(self.stepped), self.placed)
+
+    def judge(self) -> Dict[str, bool]:
+        comp, chk, doc, config = self.comp, self.chk, self.doc, self.config
+        out: Dict[str, bool] = {}
+        with jax.default_matmul_precision("highest"):
+            _, exact_loss = self._first_step()
+            exact_evaluated = self._evaluate()
+
+        # train mode: BatchNorm takes the statistics of the atoms one device
+        # sees, so a data-parallel step is held to the mean of its shards'
+        # losses, each with its own statistics (ROADMAP A8); the loss with
+        # whole-batch statistics is printed beside it
+        variables = {"params": self.state.params,
+                     "batch_stats": self.state.batch_stats}
+        want = float(np.mean([sum(common.mae_losses(
+            *system.reference_energy_forces(
+                doc, config, variables, [chk[i] for i in members],
+                train=True))) for members in self.shards]))
+        if comp.num_shards > 1:
+            whole = sum(common.mae_losses(*system.reference_energy_forces(
+                doc, config, variables, chk, train=True)))
+            say(f"the check batch with whole-batch BatchNorm statistics "
+                f"(one device): reference loss {whole:.6f} (recorded, not "
+                "judged)")
+        out["train_step_loss_at_highest"] = checks.close(
+            "train-step loss on the check batch at highest precision",
+            exact_loss, want, say, checks.HIGHEST_TOL["loss"])
+        out["train_step_loss_as_run"] = checks.close(
+            "train-step loss on the check batch as run", self.loss, want,
+            say, checks.AS_RUN_TOL["loss"])
+
+        variables = {"params": self.stepped.params,
+                     "batch_stats": self.stepped.batch_stats}
+        ref_e, ref_f, struct = system.reference_energy_forces(
+            doc, config, variables, chk, train=False)
+        for label, tol, got in (
+                ("eval_step_at_highest", checks.HIGHEST_TOL, exact_evaluated),
+                ("eval_step_as_run", checks.AS_RUN_TOL, self.evaluated)):
+            if comp.num_shards == 1:
+                _, (energy, forces) = got
+                out.update(checks.against_reference(
+                    label, *checks.unpad_ef(energy, forces, chk), ref_e,
+                    ref_f, say, tol))
+            else:
+                # the data-parallel eval step returns losses only: hold
+                # them to the reference's predictions composed the same way
+                want = checks.sharded_losses(ref_e, ref_f, struct,
+                                             self.shards)
+                for key in ("energy_loss", "force_loss"):
+                    out[f"{label}_{key}"] = checks.close(
+                        f"{label} {key}", float(got[key]), want[key], say,
+                        tol["loss"])
+        return out
+
+
+def run(ctx) -> Dict:
+    os.environ.setdefault("HYDRAGNN_DISABLE_TB", "1")
+    from hydragnn_tpu.config import get_log_name_config
+    from hydragnn_tpu.train import trainer
+    from hydragnn_tpu.utils import profiling as tr
+    cell = ctx.cell
+    doc = system.apply_tiny(cell.config_doc) if ctx.tiny else cell.config_doc
+    pool, valset, testset = system.load_pools(doc)
+    trainset = [pool[i] for i in system.seeded_order(len(pool), ctx.seed)]
+    pools = (trainset, valset, testset)
+    batch_size = int(ctx.param("graphs_per_chip")) * cell.chips
+    config = system.complete_config(doc, pools, batch_size,
+                                    training=ctx.param("training"))
+    say(f"pools loaded: {len(pool)} + {len(valset)} + {len(testset)} "
+        "structures")
+    comp = system.Training(config, pools, num_shards=cell.chips)
+    loader = comp.loaders[0]
+    say(f"layout: neighbor_format={comp.neighbor_format} K="
+        f"{loader.neighbor_k} batch {batch_size} graphs over {cell.chips} "
+        f"chip(s), padded to {loader.n_node} nodes x {loader.n_edge} edges "
+        f"per chip; {len(loader)} steps an epoch")
+    state = comp.initial_state(ctx.seed)
+    say("weights initialised")
+    against_reference = Checks(comp, state, doc, config)
+    against_reference.as_run()
+    say("step programs ready")
+
+    tr.initialize(sync=False)
+    trainer.clear_preemption()
+    step = WindowedStep(comp.train_step, ctx,
+                        max_steps=ctx.param("trace_steps")
+                        if ctx.trace is not None else None)
+    tcfg = comp.train_cfg
+    try:
+        trainer.train_validate_test(
+            step, comp.eval_step, copy_state(state), *comp.loaders,
+            num_epochs=int(tcfg["num_epoch"]),
+            log_name="bench-" + get_log_name_config(config),
+            patience=int(tcfg.get("patience", 10)),
+            use_early_stopping=bool(tcfg.get("EarlyStopping", False)),
+            verbosity=0, tracer=tr.get(), place_fn=step.placing(comp.place),
+            keep_best=bool(tcfg.get("keep_best", True)))
+    finally:
+        trainer.clear_preemption()
+    if step.t1 is None:
+        raise RuntimeError("training ended before the window closed: "
+                           "raise Training.num_epoch")
+
+    seconds = step.t1 - step.t0
+    work = step.work
+    losses = np.asarray(jax.device_get(step.losses), np.float64)
+    bad = int(np.sum(jax.device_get(step.nonfinite)))
+    tenth = max(1, len(losses) // 10)
+    first, last = losses[:tenth].mean(), losses[-tenth:].mean()
+    say(f"{work['steps']} steps in {seconds:.3f} s: {work['graphs']} "
+        f"graphs, {work['atoms'] / seconds:.1f} real atoms/s, "
+        f"{work['edges'] / seconds:.1f} real edges/s; loss {first:.5f} "
+        f"(first tenth) -> {last:.5f} (last tenth)")
+    results = against_reference.judge()
+    results["every_loss_finite"] = bool(np.isfinite(losses).all()
+                                        and bad == 0)
+    results["loss_fell"] = bool(last < first)
+    return {
+        "end_to_end": {"train_graphs_per_s": work["graphs"] / seconds},
+        "attempted": work["steps"], "failed": bad, "checks": results,
+        "counters": {
+            "pad_node_share": 1.0 - work["atoms"] / work["node_slots"]},
+        "work": work, "arch": config["NeuralNetwork"]["Architecture"]}
