@@ -5,7 +5,10 @@ runs in a check; a later `benchmark` PR repeats it the same way
 
     python3 -m benchmark.calibrate knee --workload schnet-s2ef.serve-open \\
         --rates 50,100,150,200,300 --seconds 10          (on the chip)
-    python3 -m benchmark.calibrate tolerance --workload <cell>  (on the chip)
+    python3 -m benchmark.calibrate tolerance --workload <cell> --seeds 64 \\
+        [--also 2071849904 --diagnose 2071849904]        (on the chip)
+    python3 -m benchmark.calibrate verdict benchmark/calibration.json
+                      (here: the kept sweeps against the present tolerances)
     python3 -m benchmark.calibrate memory --workload <cell> --sizes 16,32,64
                                            (here: compiles for a described v5e)
     python3 -m benchmark.calibrate spread runs.jsonl
@@ -97,42 +100,345 @@ def knee(args) -> Dict:
             "knee_rps": max(sustained) if sustained else None}
 
 
+def _seeds(args) -> List[int]:
+    """`--seeds` large seeds drawn from `--seed` (the driver's are large),
+    after the ones named with `--also`."""
+    drawn = np.random.RandomState(args.seed).randint(
+        1, 2 ** 31 - 1, size=args.seeds)
+    also = [int(s) for s in args.also.split(",") if s]
+    return also + [int(s) for s in drawn][:max(args.seeds - len(also), 0)]
+
+
+def _distribution(rows: List[Dict], keys: Dict[str, str] = None
+                  ) -> Dict[str, Dict]:
+    """min, median, p90, max and the seeds of both ends of every reading
+    of `rows` ({"seed": n, name: value, ...}); with `keys`, also the entry
+    of the tolerance table that judges each (`judged_by`; null: the number
+    is printed and not judged)."""
+    out = {}
+    for name in [k for k in rows[0] if k != "seed"]:
+        v = np.array([r[name] for r in rows], np.float64)
+        out[name] = {
+            "seeds": len(rows), "min": float(v.min()),
+            "median": float(np.median(v)),
+            "p90": float(np.percentile(v, 90)), "max": float(v.max()),
+            "seed_of_max": rows[int(v.argmax())]["seed"],
+            "seed_of_min": rows[int(v.argmin())]["seed"]}
+        if keys is not None:
+            out[name]["judged_by"] = keys.get(name)
+    return out
+
+
+SEPARATES = 3.0     # a control's narrowest reading over the sound program's
+#                     widest, from which a number is held against it
+
+
+def _verdict(sound: Dict, controls: Dict[str, Dict]) -> Dict:
+    """The rule of jobs/checks.py applied to the distributions of a sweep
+    and the PRESENT `HIGHEST_TOL`. Every judged number (`judged_by`): its
+    limit at least 3 x the sound program's widest reading, and below the
+    narrowest reading of every control that separates from the sound
+    program by `SEPARATES` or more. Every control: some judged number
+    whose narrowest reading over the control's seeds is at least 3 x its
+    limit (so at every seed that number fails by 3 x or more)."""
+    from .jobs import checks
+    limit = {name: checks.HIGHEST_TOL[dist["judged_by"]]
+             for name, dist in sound.items() if dist.get("judged_by")}
+    out = {"limits": {}, "controls": {}}
+    for name, lim in limit.items():
+        widest = max(sound[name]["max"], 1e-300)
+        held = {c: d[name]["min"] for c, d in controls.items()
+                if name in d and d[name]["min"] >= SEPARATES * widest}
+        out["limits"][name] = {
+            "limit": lim, "sound_max": sound[name]["max"],
+            "room_above_sound": lim / widest,
+            "held_against": held,
+            "room_below_controls": min(
+                [v / lim for v in held.values()], default=None)}
+    for control, dists in controls.items():
+        caught_by = max((n for n in limit if n in dists),
+                        key=lambda n: dists[n]["min"] / limit[n])
+        out["controls"][control] = {
+            "seeds": dists[caught_by]["seeds"], "caught_by": caught_by,
+            "narrowest_reading": dists[caught_by]["min"],
+            "seed_of_narrowest": dists[caught_by]["seed_of_min"],
+            "room_below_control": dists[caught_by]["min"]
+            / limit[caught_by]}
+    out["holds"] = bool(
+        all(v["room_above_sound"] >= 3
+            and (v["room_below_controls"] or 2) > 1
+            for v in out["limits"].values())
+        and all(v["room_below_control"] >= 3
+                for v in out["controls"].values()))
+    return out
+
+
+def verdict(args) -> Dict:
+    """The sweeps kept in calibration.json (or one sweep's `--out` file)
+    judged again by the present `HIGHEST_TOL`: what a `benchmark` PR runs,
+    here, before and after it moves a tolerance."""
+    with open(args.path) as f:
+        doc = json.load(f)
+    sweeps = doc.get("tolerance", {}).get("cells") or {
+        doc["workload"]: doc}
+
+    def measured(dists: Dict) -> Dict:
+        # a reading reconstructed from a sweep's log is kept for the
+        # reader and sets no limit
+        return {name: dist for name, dist in dists.items()
+                if not dist.get("derived_from_log")}
+    out = {}
+    for cell, sweep in sweeps.items():
+        # the widest sound reading and the narrowest of each control are
+        # those of every sweep kept for the cell
+        sound: Dict[str, Dict] = {}
+        controls: Dict[str, Dict] = {}
+        for one in [sweep] + sweep.get("more_sweeps", []):
+            for name, dist in measured(one["at_highest"]).items():
+                kept = sound.setdefault(name, dict(dist))
+                kept["max"] = max(kept["max"], dist["max"])
+            for control, dists in one.get("controls_at_highest",
+                                          {}).items():
+                for name, dist in measured(dists).items():
+                    kept = controls.setdefault(control, {}).setdefault(
+                        name, dict(dist))
+                    if dist["min"] < kept["min"]:
+                        kept.update(min=dist["min"],
+                                    seed_of_min=dist["seed_of_min"])
+                    kept["seeds"] = max(kept["seeds"], dist["seeds"])
+        out[cell] = _verdict(sound, controls)
+    return out
+
+
+def _readings(compared, highest_only: bool = False) -> Dict[str, float]:
+    """Every reading of one judged run, judged or recorded; `highest_only`
+    leaves the as-run ones out."""
+    out = {k: v[0] for k, v in compared.numbers.items()}
+    out.update(compared.recorded)
+    return {k: v for k, v in out.items()
+            if "at_highest" in k or not highest_only}
+
+
+def _out_of_time(args) -> bool:
+    """`--max-seconds` of the process have passed: a chip call's time limit
+    is near. A train cell's sweep then judges no further seed beyond those
+    that run the controls, starts no control that has to compile, and its
+    distributions say how many seeds they hold (a four-chip call that is
+    cut leaves nothing and costs the same)."""
+    from . import START
+    return bool(args.max_seconds
+                and time.perf_counter() - START > args.max_seconds)
+
+
+def _with_dtype(doc: Dict, dtype: str) -> Dict:
+    doc = copy.deepcopy(doc)
+    doc["hydragnn"]["NeuralNetwork"]["Architecture"]["dtype"] = dtype
+    return doc
+
+
 def tolerance(args) -> Dict:
-    """How far the system is from its plain reference on the check
-    structures: as it runs (float32, default matmul precision), at highest
-    matmul precision, and with the whole computation in bfloat16
-    (Architecture.dtype) at highest. `jobs/checks.py` sets HIGHEST_TOL
-    between the second and the third reading, AS_RUN_TOL above the first."""
+    """The distribution over seeds of every number `correct` judges, from
+    the jobs' own `judge` (a train cell: the train-mode step loss and the
+    eval step, on as many shards as the cell runs; a serving cell: the
+    engine's answers), as run and at highest matmul precision; beside it
+    the negative controls of `jobs/checks.CONTROLS` at the first
+    `--control-seeds` seeds. One process: weights are arguments of the
+    compiled programs, so each seed costs an initialisation and no
+    compile. `jobs/checks.HIGHEST_TOL` is set from what this prints
+    (README.md, "Tolerance of `correct`")."""
+    import jax
+    ctx = _context(args.workload, args.seed, 1.0, True)
+    seeds = _seeds(args)
+    job = ctx.cell.traffic["job"]
+    sweep = _tolerance_train if job == "train" else _tolerance_serving
+    sound, controls, keys, extra = sweep(ctx, args, seeds)
+    dist = _distribution([{k: v for k, v in r.items()
+                           if k == "seed" or "at_highest" in k}
+                          for r in sound], keys)
+    controls = {k: _distribution(v) for k, v in controls.items()}
+    return {"workload": args.workload, "device": jax.devices()[0].device_kind,
+            "devices": ctx.cell.chips, **extra,
+            "at_highest": dist,
+            "as_run": _distribution(
+                [{k: v for k, v in r.items() if "at_highest" not in k}
+                 for r in sound]),
+            "controls_at_highest": controls,
+            "verdict": _verdict(dist, controls)}
+
+
+def _tolerance_train(ctx, args, seeds):
     import jax
     from . import system
-    from .jobs import checks
-    ctx = _context(args.workload, args.seed, 1.0, True)
+    from .jobs import train
     doc = (system.apply_tiny(ctx.cell.config_doc) if ctx.tiny
            else ctx.cell.config_doc)
     pools = system.load_pools(doc)
-    chk = system.check_structures(pools[2])
-    out = {"workload": args.workload, "structures": len(chk),
-           "device": jax.devices()[0].device_kind}
-    for dtype, precision in (("float32", None), ("float32", "highest"),
-                             ("bfloat16", "highest")):
-        d = copy.deepcopy(doc)
-        d["hydragnn"]["NeuralNetwork"]["Architecture"]["dtype"] = dtype
-        config = system.complete_config(d, pools, 32)
-        comp = system.Training(config, pools, 1)
-        state = comp.initial_state(args.seed)
-        variables = {"params": state.params,
-                     "batch_stats": state.batch_stats}
-        ref_e, ref_f, _ = system.reference_energy_forces(
-            doc, config, variables, chk, train=False)
-        with jax.default_matmul_precision(precision):
-            _, (energy, forces) = comp.eval_step(
-                state, comp.place(comp.collate(chk)))
-        e, f = checks.unpad_ef(energy, forces, chk)
-        key = f"{dtype} at {precision or 'default'} precision"
-        out[key] = {"energy": system.relative_error(e, ref_e),
-                    "forces": system.relative_error(f, ref_f)}
-        say(f"{key}: {out[key]}")
-    return out
+    chips = ctx.cell.chips
+    per_chip = int(ctx.param("graphs_per_chip"))
+    config = system.complete_config(doc, pools, per_chip * chips,
+                                    training=ctx.param("training"))
+    comp = system.Training(config, pools, chips)
+    chk = train.Checks(comp, doc, config)
+    extra = {"structures": len(chk.chk), "per_shard": chk.per_shard}
+    diagnosed = [int(s) for s in args.diagnose.split(",") if s]
+    if diagnosed:
+        one = comp if chips == 1 else system.Training(
+            system.complete_config(doc, pools, per_chip,
+                                   training=ctx.param("training")),
+            pools, 1)
+        extra["diagnosis"] = [
+            _diagnose(train.Checks(comp, doc, config, per_shard=n), one,
+                      comp.initial_state(seed), seed)
+            for seed in diagnosed
+            for n in sorted({2, chk.per_shard} if chips > 1
+                            else {chk.per_shard})]
+    sound = []
+    controls = {"edge_mask": [], "bfloat16": []}
+    for i, seed in enumerate(seeds):
+        if i >= max(args.control_seeds, 1) and _out_of_time(args):
+            break
+        say(f"--- seed {seed} ({i + 1} of {len(seeds)})")
+        chk.as_run(comp.initial_state(seed), warm=False)
+        judged = chk.judge()
+        sound.append({"seed": seed, **_readings(judged)})
+        if i < args.control_seeds:
+            controls["edge_mask"].append(
+                {"seed": seed,
+                 **_readings(chk.control("edge_mask"), True)})
+    if args.control_seeds and _out_of_time(args):
+        say("out of time: the bfloat16 control is not read")
+    elif args.control_seeds:
+        low = _with_dtype(doc, "bfloat16")
+        comp16 = system.Training(
+            system.complete_config(low, pools, per_chip * chips,
+                                   training=ctx.param("training")),
+            pools, chips)
+        # the reference stays the float32 one: `doc`, `config`
+        chk16 = train.Checks(comp16, doc, config)
+        for seed in seeds[:args.control_seeds]:
+            say(f"--- bfloat16 control, seed {seed}")
+            # its first step too at highest: only the at-highest numbers
+            # are read, and two large programs fewer are compiled
+            with jax.default_matmul_precision("highest"):
+                chk16.as_run(comp16.initial_state(seed), warm=False)
+            controls["bfloat16"].append(
+                {"seed": seed, **_readings(chk16.judge(), True)})
+    return (sound, {k: v for k, v in controls.items() if v}, judged.keys,
+            extra)
+
+
+def _diagnose(chk, one, state, seed: int) -> Dict:
+    """Who is off, shard by shard (ISSUE 25, step 1): the data-parallel
+    step's composed losses at highest precision; each shard's own losses
+    from the ONE-device step of the program on that shard's structures (a
+    second path of the program); the plain reference in float32 on the
+    chip and in float64 on the CPU backend."""
+    import jax
+    from .jobs import checks, train
+    chk.as_run(state, warm=False)
+    first, evaluated = chk.at_highest()
+    ref32, ref64 = chk.reference(), chk.reference(float64=True)
+    state, stepped = jax.device_get((state, chk.stepped))
+    rows = []
+    for i, members in enumerate(chk.shards):
+        batch = one.place(one.collate([chk.chk[g] for g in members]))
+        with jax.default_matmul_precision("highest"):
+            _, m = one.train_step(train.copy_state(state), batch)
+            ev = one.eval_step(stepped, batch)
+        ev = ev[0] if isinstance(ev, tuple) else ev
+        row = {"shard": i, "atoms": sum(chk.chk[g].num_nodes
+                                        for g in members)}
+        for mode, got, key32 in (("train", m, "terms"),
+                                 ("eval", ev, "eval_terms")):
+            r32, r64 = ref32[key32][i], ref64[key32][i]
+            for key in ("energy_loss", "force_loss"):
+                row[f"{mode}_{key}"] = {
+                    "system": float(got[key]), "ref32": r32[key],
+                    "ref64": r64[key],
+                    "system_minus_ref64": float(got[key]) - r64[key],
+                    "ref32_minus_ref64": r32[key] - r64[key]}
+        say(f"diagnosis seed {seed}, {chk.per_shard} a shard: "
+            + json.dumps(row))
+        rows.append(row)
+    composed = {
+        "train_loss": {"system_spmd": first["loss"],
+                       "ref32": ref32["train"]["loss"],
+                       "ref64": ref64["train"]["loss"],
+                       "one_device_steps_composed": float(np.mean(
+                           [r["train_energy_loss"]["system"]
+                            + r["train_force_loss"]["system"]
+                            for r in rows]))}}
+    if chk.comp.num_shards > 1:
+        for key in ("energy_loss", "force_loss"):
+            composed[f"eval_{key}"] = {
+                "system_spmd": float(evaluated[key]),
+                "ref32": ref32["eval"][key], "ref64": ref64["eval"][key]}
+    for name, v in composed.items():
+        v["system_vs_ref64"] = abs(v["system_spmd"] - v["ref64"]) / abs(
+            v["ref64"])
+        v["ref32_vs_ref64"] = abs(v["ref32"] - v["ref64"]) / abs(v["ref64"])
+        v["system_vs_ref32"] = abs(v["system_spmd"] - v["ref32"]) / abs(
+            v["ref32"])
+        say(f"diagnosis seed {seed}, {chk.per_shard} a shard, composed "
+            f"{name}: " + json.dumps(v))
+    return {"seed": seed, "per_shard": chk.per_shard, "shards": rows,
+            "composed": composed}
+
+
+def _tolerance_serving(ctx, args, seeds):
+    import jax
+    from hydragnn_tpu.config import build_model_config
+    from hydragnn_tpu.models.create import create_model
+    from . import system
+    from .jobs import checks
+    from .jobs.serving import Served
+    served = Served(ctx)
+    check = served.check
+    sound = []
+    controls = {"edge_mask": [], "bfloat16": []}
+    starts = np.concatenate([[0], np.cumsum([s.num_nodes for s in check])])
+    exact = served.highest_engine()
+    init = system.initialiser(served.model, check)
+    config16 = system.complete_config(
+        _with_dtype(served.doc, "bfloat16"), served.pools,
+        served.config["NeuralNetwork"]["Training"]["batch_size"],
+        serving=ctx.param("serving"))
+    mcfg16 = build_model_config(config16)
+    exact16 = (served.highest_engine(config16, create_model(mcfg16), mcfg16)
+               if args.control_seeds else None)
+    try:
+        for i, seed in enumerate(seeds):
+            say(f"--- seed {seed} ({i + 1} of {len(seeds)})")
+            variables = init(jax.random.PRNGKey(seed))
+            served.variables, served._reference = variables, None
+            judged = checks.Compared(say)
+            for label, engine, tol in (
+                    ("engine_at_highest", exact, checks.HIGHEST_TOL),
+                    ("engine_as_run", served.engine, checks.AS_RUN_TOL)):
+                engine.swap_variables(variables, str(seed))
+                served.compare(judged, label, tol,
+                               engine.predict(check, timeout=600))
+            sound.append({"seed": seed, **_readings(judged)})
+            if i >= args.control_seeds:
+                continue
+            e, f, _ = system.reference_energy_forces(
+                served.doc, served.config, variables,
+                [system.drop_edges(s) for s in check], train=False)
+            exact16.swap_variables(variables, str(seed))
+            for fault, got in (
+                    ("edge_mask", [((e[g],), f[starts[g]:starts[g + 1]])
+                                   for g in range(len(check))]),
+                    ("bfloat16", exact16.predict(check, timeout=600))):
+                out = checks.Compared(say)
+                served.compare(out, "engine_at_highest", checks.HIGHEST_TOL,
+                               got)
+                controls[fault].append({"seed": seed, **_readings(out)})
+    finally:
+        for engine in (exact, exact16, served.engine):
+            if engine is not None:
+                engine.shutdown()
+    return (sound, {k: v for k, v in controls.items() if v}, judged.keys,
+            {"structures": len(check)})
 
 
 def memory(args) -> Dict:
@@ -266,16 +572,33 @@ def main(argv: List[str] = None) -> int:
         p.add_argument("--workload", required=True)
         p.add_argument("--seed", type=int, default=0)
         p.add_argument("--out")
+    tol = sub.choices["tolerance"]
+    tol.add_argument("--seeds", type=int, default=64,
+                     help="how many seeds to judge (drawn from --seed)")
+    tol.add_argument("--also", default="",
+                     help="seeds to judge first, by name, comma-separated")
+    tol.add_argument("--control-seeds", type=int, default=8,
+                     help="how many of the seeds also run the controls")
+    tol.add_argument("--max-seconds", type=float, default=0.0,
+                     help="seconds of the process after which no further "
+                          "seed is judged (0: all of them)")
+    tol.add_argument("--diagnose", default="",
+                     help="seeds to take apart shard by shard, with the "
+                          "reference in float64 on the CPU backend")
     sub.choices["knee"].add_argument("--rates", required=True)
     sub.choices["knee"].add_argument("--seconds", type=float, default=10.0)
     sub.choices["memory"].add_argument("--sizes", required=True)
     sub.choices["memory"].add_argument("--cache")
-    for name in ("spread", "xplane", "record"):
+    for name in ("spread", "xplane", "record", "verdict"):
         p = sub.add_parser(name)
         p.add_argument("path")
         p.add_argument("--out")
     sub.choices["xplane"].add_argument("--events", type=int, default=3)
     args = parser.parse_args(argv)
+    if getattr(args, "diagnose", "") and os.environ.get(
+            "JAX_PLATFORMS") == "tpu":
+        # the float64 reference runs on the CPU backend beside the chip
+        os.environ["JAX_PLATFORMS"] = "tpu,cpu"
     result = globals()[args.command](args)
     print(json.dumps(result, indent=1))
     if args.out:

@@ -4,5 +4,9 @@ system up, calls ``ctx.open_window()``, drives the load for
 and returns
 
     {"end_to_end": {metric: value}, "attempted": n, "failed": n,
-     "checks": {name: bool}, "counters": {...}, "work": {...}, "arch": {...}}
+     "checks": checks.Compared, "counters": {...}, "work": {...},
+     "arch": {...}}
+
+`checks` holds every number the run was compared on beside its limit
+(`jobs/checks.py`); `correct` is true when each is within it.
 """

@@ -60,8 +60,8 @@ def run(ctx) -> Dict:
                         batch[i], bucket=futures[i].bucket)))
             for i in range(0, len(batch), stride))
         results = served.judge()
-        results["batched_equals_single_bitwise"] = bool(same)
-        results["every_structure_answered"] = failed == 0
+        results.flag("batched_equals_single_bitwise", bool(same))
+        results.flag("every_structure_answered", failed == 0)
     finally:
         engine.shutdown()
     return {

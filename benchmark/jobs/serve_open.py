@@ -87,7 +87,8 @@ def drive(served: Served, rate_rps: float, seconds: float, seed: int,
         "edges": sum(p.num_edges for p in answered)}
 
 
-def served_against_reference(served: Served, got: Dict) -> Dict[str, bool]:
+def served_against_reference(served: Served, got: Dict,
+                             out: checks.Compared) -> None:
     loop, places = got["loop"], got["places"]
     ok = [k for k in places if not loop.failed[k]]
     ref_e, ref_f, struct = served.reference()
@@ -95,13 +96,12 @@ def served_against_reference(served: Served, got: Dict) -> Dict[str, bool]:
         struct["node_graph"]))])
     which = [int(np.nonzero(places == k)[0][0]) % len(served.check)
              for k in ok]
-    out = checks.against_reference(
+    out.arrays(
         "served_as_run", np.array([loop.results[k][0][0] for k in ok]),
         np.concatenate([loop.results[k][1] for k in ok]), ref_e[which],
-        np.concatenate([ref_f[starts[g]:starts[g + 1]] for g in which]), say,
-        checks.AS_RUN_TOL)
-    out["every_check_request_answered"] = len(ok) == len(places)
-    return out
+        np.concatenate([ref_f[starts[g]:starts[g + 1]] for g in which]),
+        checks.AS_RUN_TOL, f"{len(ok)} requests served inside the window")
+    out.flag("every_check_request_answered", len(ok) == len(places))
 
 
 def run(ctx) -> Dict:
@@ -126,7 +126,7 @@ def run(ctx) -> Dict:
             f"{got['mean_ms']:.3f}, p50 {got['p50_ms']:.3f}, p90 "
             f"{got['p90_ms']:.3f}, p95 {got['p95_ms']:.3f} ms")
         results = served.judge()
-        results.update(served_against_reference(served, got))
+        served_against_reference(served, got, results)
     finally:
         served.engine.shutdown()
     return {

@@ -64,32 +64,45 @@ class Served:
             f"{engine.buckets[-1].cap_graphs} structures")
         self.as_run = engine.predict(self.check, timeout=600)
 
-    def judge(self) -> Dict[str, bool]:
-        """After the window: the engine's energies and forces on the check
-        structures against the plain reference, as they were served and
-        from the same forward at highest matmul precision (a second engine
-        of one bucket, compiled under that setting; `jobs/checks.py`)."""
+    def highest_engine(self, config=None, model=None, mcfg=None):
+        """A second engine of the one bucket the check structures fall in,
+        compiled at highest matmul precision (`jobs/checks.py`). Its
+        programs take the weights as arguments: `swap_variables` gives it
+        another seed's."""
         import jax
         from hydragnn_tpu.serving.engine import select_bucket
-        ref_e, ref_f, _ = self.reference()
         bucket = select_bucket(
             self.engine.buckets, len(self.check),
             sum(s.num_nodes for s in self.check),
             sum(s.num_edges for s in self.check))
         with jax.default_matmul_precision("highest"):
-            exact = system.make_engine(self.config, self.model, self.mcfg,
-                                       self.variables, self.structures,
-                                       self.pools, buckets=[bucket])
+            exact = system.make_engine(
+                config or self.config, model or self.model,
+                mcfg or self.mcfg, self.variables, self.structures,
+                self.pools, buckets=[bucket])
             exact.warmup()
+        return exact
+
+    def compare(self, out: checks.Compared, label: str, tol: Dict, got
+                ) -> None:
+        """Served (energy, forces) answers on the check structures against
+        the plain reference."""
+        ref_e, ref_f, _ = self.reference()
+        out.arrays(label, np.array([r[0][0] for r in got]),
+                   np.concatenate([r[1] for r in got]), ref_e, ref_f, tol,
+                   f"{len(self.check)} check structures, one bucket")
+
+    def judge(self) -> checks.Compared:
+        """After the window: the engine's energies and forces on the check
+        structures against the plain reference, as they were served and
+        from the same forward at highest matmul precision."""
+        exact = self.highest_engine()
         try:
             at_highest = exact.predict(self.check, timeout=600)
         finally:
             exact.shutdown()
-        out = {}
-        for label, tol, got in (
-                ("engine_at_highest", checks.HIGHEST_TOL, at_highest),
-                ("engine_as_run", checks.AS_RUN_TOL, self.as_run)):
-            out.update(checks.against_reference(
-                label, np.array([r[0][0] for r in got]),
-                np.concatenate([r[1] for r in got]), ref_e, ref_f, say, tol))
+        out = checks.Compared(say)
+        self.compare(out, "engine_at_highest", checks.HIGHEST_TOL,
+                     at_highest)
+        self.compare(out, "engine_as_run", checks.AS_RUN_TOL, self.as_run)
         return out

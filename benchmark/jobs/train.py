@@ -100,88 +100,148 @@ class Checks:
     `as_run()` comes before the window and is its warm-up: it compiles and
     runs the train step and the eval step in the variants the trainer will
     call, and keeps what they gave. `judge()` comes after the window: the
-    reference, and the same programs traced at highest matmul precision."""
+    reference, and the same programs traced at highest matmul precision.
 
-    def __init__(self, comp, state, doc, config):
-        self.comp, self.state, self.doc, self.config = comp, state, doc, config
-        self.chk = system.check_structures(
-            comp.loaders[2].dataset,
-            min(system.CHECK_STRUCTURES, comp.loaders[0].batch_size))
+    Every shard of a data-parallel step gets `system.CHECK_STRUCTURES`
+    structures (`per_shard`), small and large: the picks are evenly spaced
+    by size and shard i holds picks[i::shards]. The batch keeps the
+    loader's padded shape, so the warm-up compiles what the window runs."""
+
+    def __init__(self, comp, doc, config, per_shard: int = None):
+        self.comp, self.doc, self.config = comp, doc, config
+        n = comp.num_shards
+        testset = comp.loaders[2].dataset
+        self.per_shard = per_shard or min(
+            system.CHECK_STRUCTURES, comp.loaders[0].batch_size // n,
+            len(testset) // n)
+        self.chk = system.check_structures(testset, self.per_shard * n)
         self.placed = comp.place(comp.collate(self.chk))
         # the positions, in `chk`, of the structures each shard holds
-        self.shards = [list(range(len(self.chk)))[i::comp.num_shards]
-                       for i in range(comp.num_shards)]
+        self.shards = [list(range(len(self.chk)))[i::n] for i in range(n)]
+        self.where = (f"{len(self.chk)} structures on one chip" if n == 1
+                      else f"mean of {n} shards x {self.per_shard} "
+                      "structures")
 
     def _first_step(self):
-        """(the state one train step on the check batch leaves, its loss),
-        under the matmul precision in force."""
+        """(the state one train step on the check batch leaves, its
+        metrics), under the matmul precision in force."""
         stepped, metrics = self.comp.train_step(copy_state(self.state),
                                                 self.placed)
-        return stepped, float(metrics["loss"])
+        return stepped, {k: float(metrics[k]) for k in (
+            "loss", "energy_loss", "force_loss")}
 
     def _evaluate(self):
         """The eval step on the check batch with the weights `as_run` left,
         under the matmul precision in force."""
         return jax.device_get(self.comp.eval_step(self.stepped, self.placed))
 
-    def as_run(self) -> None:
-        self.stepped, self.loss = self._first_step()
+    def as_run(self, state, warm: bool = True) -> None:
+        self.state = state
+        self.stepped, self.first = self._first_step()
         self.evaluated = self._evaluate()
-        # the step on its own output: a data-parallel step compiles once
-        # more for it (PERF.md, PR 21)
-        self.comp.train_step(copy_state(self.stepped), self.placed)
+        if warm:
+            # the step on its own output: a data-parallel step compiles
+            # once more for it (PERF.md, PR 21)
+            self.comp.train_step(copy_state(self.stepped), self.placed)
 
-    def judge(self) -> Dict[str, bool]:
-        comp, chk, doc, config = self.comp, self.chk, self.doc, self.config
-        out: Dict[str, bool] = {}
+    def at_highest(self):
+        """(the first step's metrics, the eval step's output) of the same
+        programs traced at highest matmul precision."""
         with jax.default_matmul_precision("highest"):
-            _, exact_loss = self._first_step()
-            exact_evaluated = self._evaluate()
+            _, first = self._first_step()
+            return first, self._evaluate()
 
-        # train mode: BatchNorm takes the statistics of the atoms one device
-        # sees, so a data-parallel step is held to the mean of its shards'
-        # losses, each with its own statistics (ROADMAP A8); the loss with
-        # whole-batch statistics is printed beside it
+    def outputs(self, first: Dict, evaluated) -> Dict:
+        """What a step pair gave, in the form `compare` judges: the train
+        step's loss and its terms; the eval step's energies and forces
+        (one chip) or its losses (the data-parallel step returns no more)."""
+        out = {"train": first}
+        if self.comp.num_shards == 1:
+            _, (energy, forces) = evaluated
+            out["energy"], out["forces"] = checks.unpad_ef(
+                energy, forces, self.chk)
+        else:
+            out["eval"] = {k: float(evaluated[k])
+                           for k in ("energy_loss", "force_loss")}
+        return out
+
+    def reference(self, fault: str = None, float64: bool = False) -> Dict:
+        """What the plain reference says of the check batch, in the same
+        form. Train mode: BatchNorm takes the statistics of the atoms one
+        device sees, so each shard is evaluated alone and the losses are
+        composed as the step composes them (ROADMAP A8). Eval mode: with
+        the weights the as-run step left. `fault="edge_mask"` plants that
+        negative control (`checks.CONTROLS`): the reference then stands in
+        the program's place."""
+        doc, config = self.doc, self.config
+        chk = ([system.drop_edges(s) for s in self.chk]
+               if fault == "edge_mask" else self.chk)
         variables = {"params": self.state.params,
                      "batch_stats": self.state.batch_stats}
-        want = float(np.mean([sum(common.mae_losses(
-            *system.reference_energy_forces(
+        terms = []
+        for members in self.shards:
+            e, f, struct = system.reference_energy_forces(
                 doc, config, variables, [chk[i] for i in members],
-                train=True))) for members in self.shards]))
-        if comp.num_shards > 1:
-            whole = sum(common.mae_losses(*system.reference_energy_forces(
-                doc, config, variables, chk, train=True)))
-            say(f"the check batch with whole-batch BatchNorm statistics "
-                f"(one device): reference loss {whole:.6f} (recorded, not "
-                "judged)")
-        out["train_step_loss_at_highest"] = checks.close(
-            "train-step loss on the check batch at highest precision",
-            exact_loss, want, say, checks.HIGHEST_TOL["loss"])
-        out["train_step_loss_as_run"] = checks.close(
-            "train-step loss on the check batch as run", self.loss, want,
-            say, checks.AS_RUN_TOL["loss"])
-
+                train=True, float64=float64)
+            e_loss, f_loss = common.mae_losses(e, f, struct)
+            terms.append({"energy_loss": e_loss, "force_loss": f_loss,
+                          "graphs": len(members)})
+        train = checks.compose(terms)
+        train["loss"] = train["energy_loss"] + train["force_loss"]
         variables = {"params": self.stepped.params,
                      "batch_stats": self.stepped.batch_stats}
         ref_e, ref_f, struct = system.reference_energy_forces(
-            doc, config, variables, chk, train=False)
-        for label, tol, got in (
-                ("eval_step_at_highest", checks.HIGHEST_TOL, exact_evaluated),
-                ("eval_step_as_run", checks.AS_RUN_TOL, self.evaluated)):
-            if comp.num_shards == 1:
-                _, (energy, forces) = got
-                out.update(checks.against_reference(
-                    label, *checks.unpad_ef(energy, forces, chk), ref_e,
-                    ref_f, say, tol))
-            else:
-                # the data-parallel eval step returns losses only: hold
-                # them to the reference's predictions composed the same way
-                want = checks.sharded_losses(ref_e, ref_f, struct,
-                                             self.shards)
-                for key in ("energy_loss", "force_loss"):
-                    out[f"{label}_{key}"] = checks.close(
-                        f"{label} {key}", float(got[key]), want[key], say,
-                        tol["loss"])
+            doc, config, variables, chk, train=False, float64=float64)
+        eval_terms = checks.shard_terms(ref_e, ref_f, struct, self.shards)
+        return {"train": train, "terms": terms, "energy": ref_e,
+                "forces": ref_f, "eval_terms": eval_terms,
+                "eval": checks.compose(eval_terms)}
+
+    def compare(self, out: checks.Compared, suffix: str, tol: Dict,
+                got: Dict, want: Dict) -> None:
+        """A loss is judged by the entry of `tol` named beside it and
+        printed where the table has none: at highest the scalars made of
+        forces are recorded only (`jobs/checks.py` says why), as run every
+        loss is held to the one loose `loss`."""
+        where = self.where
+        for name, term, key in (
+                ("train_step_loss", "loss", "loss"),
+                ("train_step_energy_loss", "energy_loss",
+                 "train_energy_loss"),
+                ("train_step_force_loss", "force_loss", "train_force_loss")):
+            out.close(f"{name}_{suffix}", got["train"][term],
+                      want["train"][term], tol, key, where)
+        label = f"eval_step_{suffix}"
+        if self.comp.num_shards == 1:
+            out.arrays(label, got["energy"], got["forces"], want["energy"],
+                       want["forces"], tol, where)
+        else:
+            # the data-parallel eval step returns losses only: hold them
+            # to the reference's predictions composed the same way
+            for key in ("energy_loss", "force_loss"):
+                out.close(f"{label}_{key}", got["eval"][key],
+                          want["eval"][key], tol,
+                          key if tol is checks.HIGHEST_TOL else "loss", where)
+
+    def judge(self) -> checks.Compared:
+        out = checks.Compared(say)
+        first, evaluated = self.at_highest()
+        want = self.reference()
+        checks.describe_shards(want["terms"], say, "train mode,")
+        if self.comp.num_shards > 1:
+            checks.describe_shards(want["eval_terms"], say, "eval mode,")
+        self.compare(out, "at_highest", checks.HIGHEST_TOL,
+                     self.outputs(first, evaluated), want)
+        self.compare(out, "as_run", checks.AS_RUN_TOL,
+                     self.outputs(self.first, self.evaluated), want)
+        return out
+
+    def control(self, fault: str) -> checks.Compared:
+        """The at-highest comparisons with the faulty reference in the
+        program's place: it has to FAIL one of them."""
+        out = checks.Compared(say)
+        self.compare(out, "at_highest", checks.HIGHEST_TOL,
+                     self.reference(fault), self.reference())
         return out
 
 
@@ -208,8 +268,8 @@ def run(ctx) -> Dict:
         f"per chip; {len(loader)} steps an epoch")
     state = comp.initial_state(ctx.seed)
     say("weights initialised")
-    against_reference = Checks(comp, state, doc, config)
-    against_reference.as_run()
+    against_reference = Checks(comp, doc, config)
+    against_reference.as_run(state)
     say("step programs ready")
 
     tr.initialize(sync=False)
@@ -244,9 +304,9 @@ def run(ctx) -> Dict:
         f"{work['edges'] / seconds:.1f} real edges/s; loss {first:.5f} "
         f"(first tenth) -> {last:.5f} (last tenth)")
     results = against_reference.judge()
-    results["every_loss_finite"] = bool(np.isfinite(losses).all()
-                                        and bad == 0)
-    results["loss_fell"] = bool(last < first)
+    results.flag("every_loss_finite", bool(np.isfinite(losses).all()
+                                           and bad == 0))
+    results.flag("loss_fell", bool(last < first))
     return {
         "end_to_end": {"train_graphs_per_s": work["graphs"] / seconds},
         "attempted": work["steps"], "failed": bad, "checks": results,

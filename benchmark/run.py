@@ -142,15 +142,15 @@ def main(argv: Optional[List[str]] = None) -> int:
                 system.ROOT, ".bench_trace", cell.name))
         result = job.run(ctx)
 
-    checks = dict(result["checks"])
-    checks["zero_compiles_in_window"] = ctx.window_compiles == 0
-    for name, ok in checks.items():
+    compared = result["checks"]         # jobs/checks.Compared
+    compared.flag("zero_compiles_in_window", ctx.window_compiles == 0)
+    for name, ok in compared.ok.items():
         say(f"check {name}: {'ok' if ok else 'FAILED'}")
-    line = {"correct": all(checks.values()),
+    line = {"correct": all(compared.ok.values()),
             "attempted": int(result["attempted"]),
             "failed": int(result["failed"]), "metrics": {},
             "device": system.device_report(ctx.memory_peak_bytes),
-            "checks": checks}
+            "checks": compared.ok}
     say(f"set-up took {ctx.setup_s:.1f} s: {ctx.setup_compiles} programs "
         f"built or fetched ({ctx.setup_cache_hits} from the persistent "
         f"cache) in {ctx.setup_compile_s:.1f} s")
@@ -172,6 +172,10 @@ def main(argv: Optional[List[str]] = None) -> int:
                             "unit": m["unit"]} for m in cell.end_to_end}
     else:
         say("rehearsal on the CPU: no metric is reported")
+    # each number compared beside its limit: the last lines on standard
+    # error, and the last key of the result line
+    line["compared"] = compared.numbers
+    compared.report()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -13,7 +13,9 @@ from __future__ import annotations
 import copy
 import functools
 import importlib
+import json
 import os
+import types
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
@@ -75,6 +77,21 @@ def check_structures(testset: Sequence, count: int = CHECK_STRUCTURES
     return [testset[by_size[i]] for i in picks]
 
 
+DROPPED_EDGE = 100     # `drop_edges` leaves out one edge in so many
+
+
+def drop_edges(sample):
+    """`sample` as the plain reference reads it, with one edge in
+    `DROPPED_EDGE` left out: the wrong-mask negative control of
+    `jobs/checks.CONTROLS`."""
+    keep = np.arange(len(sample.senders)) % DROPPED_EDGE != DROPPED_EDGE - 1
+    return types.SimpleNamespace(
+        x=sample.x, pos=sample.pos, num_nodes=sample.num_nodes,
+        energy=sample.energy, forces=sample.forces,
+        senders=sample.senders[keep], receivers=sample.receivers[keep],
+        edge_shifts=sample.edge_shifts[keep])
+
+
 def complete_config(config_doc: Dict, pools, batch_size: int,
                     training: Dict = None, serving: Dict = None) -> Dict:
     """The HydraGNN config as `run_training` would hold it after
@@ -103,15 +120,16 @@ def neighbor_format(config: Dict) -> bool:
 BATCHNORM_PASSES = 64
 
 
-def init_variables(model, calibration: Sequence, seed: int):
-    """Weights from the seed, made on the device in one jitted call, with
+def initialiser(model, calibration: Sequence):
+    """key -> weights, made on the device in one jitted call, with
     the BatchNorm running statistics a trained model would carry: 64
     train-mode passes over the `calibration` structures move them (momentum
     0.9) onto those structures' own statistics. At flax's initial values
     (mean 0, variance 1) nothing is normalised in eval mode, SchNet's
     activations shrink to 1e-6 layer by layer, and ``softplus(x) - log 2``
     there cancels to a few digits: two float32 evaluations of the same
-    mathematics then differ by percents (PERF.md, PR 22)."""
+    mathematics then differ by percents (PERF.md, PR 22). Whoever keeps
+    the function (a sweep over seeds) compiles it once."""
     import jax
     from hydragnn_tpu.graphs.batch import collate, with_neighbor_format
     n = 64 * (sum(s.num_nodes for s in calibration) // 64 + 1)
@@ -132,33 +150,60 @@ def init_variables(model, calibration: Sequence, seed: int):
         stats = jax.lax.fori_loop(0, BATCHNORM_PASSES, one_pass,
                                   variables["batch_stats"])
         return {"params": variables["params"], "batch_stats": stats}
-    return init(jax.random.PRNGKey(seed))
+    return init
 
 
-def load_reference(config_doc: Dict):
-    """The configuration's plain reference: the module of this package that
-    its file names under `reference`."""
-    name = os.path.splitext(os.path.basename(config_doc["reference"]))[0]
-    return importlib.import_module(f"benchmark.reference.{name}")
+def init_variables(model, calibration: Sequence, seed: int):
+    """Weights from `seed` (`initialiser`)."""
+    import jax
+    return initialiser(model, calibration)(jax.random.PRNGKey(seed))
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_fn(reference: str, arch_json: str, num_graphs: int,
+                  train: bool):
+    """The jitted reference of one configuration, one per (graphs, mode):
+    a sweep over seeds calls it with new weights and compiles nothing."""
+    import jax
+    from .reference import common
+    node_fn = importlib.import_module(
+        f"benchmark.reference.{reference}").node_energies(
+            json.loads(arch_json))
+    return jax.jit(functools.partial(
+        common.energies_and_forces, node_fn, num_graphs=num_graphs,
+        train=train))
 
 
 def reference_energy_forces(config_doc: Dict, config: Dict, variables,
-                            samples: Sequence, train: bool):
+                            samples: Sequence, train: bool,
+                            float64: bool = False):
     """(E [G], F [N, 3], structure dict) of the plain reference on
-    `samples`, as numpy, with the system's own weights."""
+    `samples`, as numpy, with the system's own weights. `float64`: the same
+    computation in double precision on the CPU backend beside the chip
+    (`calibrate tolerance --diagnose`: which side rounding moved)."""
     import jax
     from .reference import common
     arch = config["NeuralNetwork"]["Architecture"]
     struct = common.concat_structures(samples)
-    node_fn = load_reference(config_doc).node_energies(arch)
-    fn = jax.jit(functools.partial(
-        common.energies_and_forces, node_fn, num_graphs=len(samples),
-        train=train))
+    name = os.path.splitext(os.path.basename(config_doc["reference"]))[0]
+    fn = _reference_fn(name, json.dumps(arch, sort_keys=True,
+                                  default=lambda a: a.tolist()),
+                       len(samples), bool(train))
     plain = {"params": variables["params"],
              "batch_stats": variables.get("batch_stats", {})}
     arrays = {k: v for k, v in struct.items() if k not in ("energy",
                                                            "forces")}
-    energy, forces = fn(plain, arrays)
+    if float64:
+        def wide(a):
+            a = np.asarray(a)
+            return a.astype(np.float64) if a.dtype.kind == "f" else a
+        with jax.enable_x64(True), jax.default_device(
+                jax.devices("cpu")[0]):
+            energy, forces = jax.device_get(fn(
+                jax.tree_util.tree_map(wide, plain),
+                {k: wide(v) for k, v in arrays.items()}))
+    else:
+        energy, forces = fn(plain, arrays)
     return np.asarray(energy), np.asarray(forces), struct
 
 
@@ -255,11 +300,16 @@ class Training:
             self.place = lambda b: jax.tree_util.tree_map(
                 lambda a: None if a is None else jax.device_put(a), b)
 
+    @functools.cached_property
+    def _initialiser(self):
+        return initialiser(self.model,
+                           check_structures(self.loaders[2].dataset))
+
     def initial_state(self, seed: int):
+        import jax
         from hydragnn_tpu.train.train_step import TrainState
-        calibration = check_structures(self.loaders[2].dataset)
         return TrainState.create(
-            init_variables(self.model, calibration, seed), self.tx)
+            self._initialiser(jax.random.PRNGKey(seed)), self.tx)
 
     def collate(self, samples: Sequence):
         """`samples` as one host batch of the train loader's padded shape;

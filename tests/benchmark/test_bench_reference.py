@@ -88,7 +88,8 @@ def test_sharded_losses_compose_as_the_data_parallel_eval_step_does():
     ref_e = np.array([2.0, 2.0, 0.0])
     ref_f = np.concatenate([np.full((2, 3), 1.0), np.full((1, 3), 4.0),
                             np.full((3, 3), 2.0)])
-    got = checks.sharded_losses(ref_e, ref_f, struct, [[0, 2], [1]])
+    got = checks.compose(checks.shard_terms(ref_e, ref_f, struct,
+                                            [[0, 2], [1]]))
     # shard 0: graphs 0 and 2 (|dE| 1, 3; 5 atoms of |F| 1, 1, 2, 2, 2);
     # shard 1: graph 1 (|dE| 0; 1 atom of 4); weights 2/3 and 1/3
     assert got["energy_loss"] == pytest.approx(2 / 3 * 2.0 + 1 / 3 * 0.0)

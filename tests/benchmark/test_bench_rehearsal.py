@@ -26,7 +26,13 @@ def test_tiny_preset_runs_end_to_end_and_reports_no_metric(workload, chips,
     rc, out, err = run_cell(workload, trace=trace, devices=chips)
     assert rc == 0, err[-3000:]
     line = json.loads(out[-1])
-    assert LINE_KEYS <= set(line) <= LINE_KEYS | {"checks", "breakdown"}
+    assert LINE_KEYS <= set(line) <= LINE_KEYS | {"checks", "breakdown",
+                                                  "compared"}
+    # every number compared, beside its limit, under the last key
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == set(line["checks"])
+    assert all(len(pair) == 2 for pair in line["compared"].values())
+    assert err.strip().splitlines()[-1].startswith("compared ")
     assert ("breakdown" in line) == bool(trace)
     assert DEVICE_KEYS <= set(line["device"])
     assert line["device"]["platform"] == "cpu"
