@@ -94,9 +94,13 @@ class BaseStack(nn.Module):
     def __call__(self, batch: GraphBatch, train: bool = False):
         cfg = self.cfg
         act = activation_function_selection(cfg.activation)
-        cargs = self.conv_args(batch)
+        # trace vocabulary (PERF.md section 3): what is not a module gets
+        # an explicit scope; each conv is already named `conv_<i>` by flax
+        with jax.named_scope("geometry"):
+            cargs = self.conv_args(batch)
         x, pos = self.encode(batch, cargs, act, train)
-        return self.decode(x, pos, batch, cargs, act, train)
+        with jax.named_scope("heads"):
+            return self.decode(x, pos, batch, cargs, act, train)
 
     def encode(self, batch: GraphBatch, cargs, act, train: bool):
         """Conv-stack encoder (reference: Base.py:303-318). Subclasses with

@@ -30,10 +30,12 @@ class GINConv(nn.Module):
     def __call__(self, x, pos, batch, cargs):
         eps = self.param("eps", lambda k: jnp.asarray(self.eps_init, jnp.float32))
         if batch.nbr is not None:
-            agg = seg.neighbor_sum(x[batch.nbr], batch.nbr_mask)
+            agg = seg.neighbor_sum(seg.neighbor_gather(x, batch.nbr),
+                                   batch.nbr_mask)
         else:
-            agg = seg.segment_sum(x[batch.senders], batch.receivers,
-                                  x.shape[0], batch.edge_mask)
+            agg = seg.segment_sum(seg.neighbor_gather(x, batch.senders),
+                                  batch.receivers, x.shape[0],
+                                  batch.edge_mask)
         h = (1.0 + eps) * x + agg
         h = MLP([self.out_dim, self.out_dim], activation=jax.nn.relu)(h)
         return h, pos
@@ -46,10 +48,12 @@ class SAGEConv(nn.Module):
     @nn.compact
     def __call__(self, x, pos, batch, cargs):
         if batch.nbr is not None:
-            agg = seg.neighbor_mean(x[batch.nbr], batch.nbr_mask)
+            agg = seg.neighbor_mean(seg.neighbor_gather(x, batch.nbr),
+                                    batch.nbr_mask)
         else:
-            agg = seg.segment_mean(x[batch.senders], batch.receivers,
-                                   x.shape[0], batch.edge_mask)
+            agg = seg.segment_mean(seg.neighbor_gather(x, batch.senders),
+                                   batch.receivers, x.shape[0],
+                                   batch.edge_mask)
         h = nn.Dense(self.out_dim, name="lin_l")(agg) + \
             nn.Dense(self.out_dim, name="lin_r")(x)
         return h, pos
@@ -73,17 +77,19 @@ class GATv2Conv(nn.Module):
         if batch.nbr is not None:
             # dense layout: attention softmax is a masked reduction over the
             # K axis — no segment softmax, no scatters
-            e = g_l[:, None] + g_r[batch.nbr]                     # [N, K, H, F]
+            e = g_l[:, None] + seg.neighbor_gather(g_r, batch.nbr)  # [N,K,H,F]
             if use_ea:
-                e = e + nn.Dense(H * F, name="lin_edge")(
-                    batch.edge_attr).reshape(-1, H, F)[batch.nbr_edge]
+                e = e + seg.edge_gather(nn.Dense(H * F, name="lin_edge")(
+                    batch.edge_attr).reshape(-1, H, F), batch)
             e_act = jax.nn.leaky_relu(e, self.negative_slope)
             logits = jnp.sum(e_act * att, axis=-1)                # [N, K, H]
             alpha = seg.neighbor_softmax(logits, batch.nbr_mask)
-            out = seg.neighbor_sum(g_r[batch.nbr] * alpha[..., None],
-                                   batch.nbr_mask)               # [N, H, F]
+            out = seg.neighbor_sum(
+                seg.neighbor_gather(g_r, batch.nbr) * alpha[..., None],
+                batch.nbr_mask)                                   # [N, H, F]
         else:
-            e = g_l[batch.receivers] + g_r[batch.senders]         # [E, H, F]
+            e = (seg.neighbor_gather(g_l, batch.receivers)
+                 + seg.neighbor_gather(g_r, batch.senders))       # [E, H, F]
             if use_ea:
                 e = e + nn.Dense(H * F, name="lin_edge")(
                     batch.edge_attr).reshape(-1, H, F)
@@ -91,7 +97,7 @@ class GATv2Conv(nn.Module):
             logits = jnp.sum(e_act * att, axis=-1)                # [E, H]
             alpha = seg.segment_softmax(logits, batch.receivers, x.shape[0],
                                         batch.edge_mask)
-            msgs = g_r[batch.senders] * alpha[..., None]
+            msgs = seg.neighbor_gather(g_r, batch.senders) * alpha[..., None]
             out = seg.segment_sum(msgs, batch.receivers, x.shape[0],
                                   batch.edge_mask)
         if self.concat:
@@ -115,11 +121,12 @@ class MFConv(nn.Module):
         n, fin = x.shape
         d = self.max_degree + 1
         if batch.nbr is not None:
-            agg = seg.neighbor_sum(x[batch.nbr], batch.nbr_mask)
+            agg = seg.neighbor_sum(seg.neighbor_gather(x, batch.nbr),
+                                   batch.nbr_mask)
             deg = jnp.sum(batch.nbr_mask, axis=1)
         else:
-            agg = seg.segment_sum(x[batch.senders], batch.receivers, n,
-                                  batch.edge_mask)
+            agg = seg.segment_sum(seg.neighbor_gather(x, batch.senders),
+                                  batch.receivers, n, batch.edge_mask)
             deg = seg.degree(batch.receivers, n, batch.edge_mask)
         deg = jnp.clip(deg.astype(jnp.int32), 0, self.max_degree)
         w_l = self.param("w_l", nn.initializers.lecun_normal(), (d, fin, self.out_dim))
@@ -143,15 +150,17 @@ class CGConv(nn.Module):
         if batch.nbr is not None:
             k = batch.nbr.shape[1]
             xi = jnp.broadcast_to(x[:, None], (x.shape[0], k, x.shape[-1]))
-            parts = [xi, x[batch.nbr]]
+            parts = [xi, seg.neighbor_gather(x, batch.nbr)]
             if ea is not None:
-                parts.append(ea[batch.nbr_edge])
+                parts.append(seg.edge_gather(ea, batch))
             z = jnp.concatenate(parts, axis=-1)                  # [N, K, ·]
             gate = jax.nn.sigmoid(nn.Dense(x.shape[-1], name="lin_f")(z))
             core = jax.nn.softplus(nn.Dense(x.shape[-1], name="lin_s")(z))
             agg = seg.neighbor_sum(gate * core, batch.nbr_mask)
         else:
-            z = jnp.concatenate([x[batch.receivers], x[batch.senders]], axis=-1)
+            z = jnp.concatenate([seg.neighbor_gather(x, batch.receivers),
+                                 seg.neighbor_gather(x, batch.senders)],
+                                axis=-1)
             if ea is not None:
                 z = jnp.concatenate([z, ea], axis=-1)
             gate = jax.nn.sigmoid(nn.Dense(x.shape[-1], name="lin_f")(z))
@@ -227,8 +236,9 @@ class PNAConv(nn.Module):
             else:
                 # dense neighbor-list layout: [N, K, F] messages, axis-1
                 # reductions, zero scatters (with_neighbor_format)
-                h = proj_i[:, None, :] + proj_j[batch.nbr]
-                h = edge_terms(h, lambda ev: ev[batch.nbr_edge])
+                h = proj_i[:, None, :] + seg.neighbor_gather(proj_j,
+                                                             batch.nbr)
+                h = edge_terms(h, lambda ev: seg.edge_gather(ev, batch))
                 mean, mn, mx, sd, deg = seg.neighbor_aggregate(
                     h, batch.nbr_mask)
         else:
@@ -245,7 +255,8 @@ class PNAConv(nn.Module):
                     proj_i, proj_j, batch.senders, batch.receivers,
                     batch.edge_mask, n, 1e-5, interpret_mode())
             else:
-                h = proj_i[batch.receivers] + proj_j[batch.senders]
+                h = (seg.neighbor_gather(proj_i, batch.receivers)
+                     + seg.neighbor_gather(proj_j, batch.senders))
                 h = edge_terms(h, lambda ev: ev)
                 mean, mn, mx, sd, deg = seg.pna_aggregate(
                     h, batch.receivers, n, batch.edge_mask)

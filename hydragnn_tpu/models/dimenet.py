@@ -35,7 +35,8 @@ class HydraEmbeddingBlock(nn.Module):
     def __call__(self, x, rbf, batch):
         send, recv = batch.senders, batch.receivers
         rbf_emb = jax.nn.silu(nn.Dense(self.hidden, name="lin_rbf")(rbf))
-        parts = [x[send], x[recv], rbf_emb]
+        parts = [seg.neighbor_gather(x, send), seg.neighbor_gather(x, recv),
+                 rbf_emb]
         if self.edge_dim and batch.edge_attr is not None:
             parts.append(jax.nn.silu(
                 nn.Dense(self.hidden, name="lin_edge")(batch.edge_attr)))

@@ -64,7 +64,8 @@ class EGCL(nn.Module):
         # norm_diff=True (reference: EGCLStack.py:219-224)
         coord_diff = vec / (length + 1.0)[:, None]
 
-        parts = [x[recv], x[send], radial]
+        parts = [seg.neighbor_gather(x, recv), seg.neighbor_gather(x, send),
+                 radial]
         if self.edge_dim and batch.edge_attr is not None:
             parts.append(batch.edge_attr)
         m = MLP([self.hidden_dim, self.hidden_dim], activation=act,

@@ -78,7 +78,7 @@ class MACEInteraction(nn.Module):
                 name="radial_weights")(radial)            # [E, P*mul]
         w = w.reshape(w.shape[:-1] + (len(paths), self.mul))
         weights = {p: w[..., i, :] for i, p in enumerate(paths)}
-        h_e = {l: f[send] for l, f in h.items()}
+        h_e = {l: seg.neighbor_gather(f, send) for l, f in h.items()}
         sh_e = {l: f[:, None, :] for l, f in sh.items()}   # mul-broadcast
         msgs = tensor_product(h_e, sh_e, self.lmax_out, weights)
         agg = {l: seg.edge_aggregate_sum(m, batch) / self.avg_num_neighbors
@@ -179,16 +179,18 @@ class MACEStack(BaseStack):
         cutoff = float(cfg.radius)
 
         # ---- conv args (reference: _conv_args, MACEStack.py:409-455) ----
-        pos_mean = global_mean_pool(batch.pos, batch.node_graph,
-                                    batch.num_graphs, batch.node_mask)
-        pos = batch.pos - pos_mean[batch.node_graph]
-        node_attrs = process_node_attributes(batch.x, cfg.num_elements)
-        vec, length = edge_vectors(pos, batch.senders, batch.receivers,
-                                   batch.edge_shifts)
-        sh = real_spherical_harmonics(vec, lmax)
-        d = DISTANCE_TRANSFORMS[cfg.distance_transform or "None"](length)
-        radial = RADIAL_BASES[radial_type](d, cutoff, num_basis)
-        radial = radial * polynomial_cutoff(length, cutoff)[:, None]
+        with jax.named_scope("geometry"):
+            pos_mean = global_mean_pool(batch.pos, batch.node_graph,
+                                        batch.num_graphs, batch.node_mask)
+            pos = batch.pos - pos_mean[batch.node_graph]
+            node_attrs = process_node_attributes(batch.x, cfg.num_elements)
+            vec, length = edge_vectors(pos, batch.senders, batch.receivers,
+                                       batch.edge_shifts)
+            sh = real_spherical_harmonics(vec, lmax)
+            d = DISTANCE_TRANSFORMS[cfg.distance_transform or "None"](
+                length)
+            radial = RADIAL_BASES[radial_type](d, cutoff, num_basis)
+            radial = radial * polynomial_cutoff(length, cutoff)[:, None]
 
         # ---- embeddings ----
         feats: IrrepsDict = {
@@ -203,14 +205,18 @@ class MACEStack(BaseStack):
         for i in range(cfg.num_conv_layers):
             last = i == cfg.num_conv_layers - 1
             layer_lmax = node_lmax if not last else 0
-            msg = MACEInteraction(mul=mul, lmax_out=layer_lmax,
-                                  avg_num_neighbors=float(
-                                      cfg.avg_num_neighbors or 1.0),
-                                  name=f"interaction_{i}")(
-                feats, sh, radial, batch)
             nu = int(corr[i]) if i < len(corr) else int(corr[-1])
-            feats = MACEProduct(mul=mul, lmax=layer_lmax, correlation=nu,
-                                name=f"product_{i}")(msg, feats)
+            # the modules keep their names (parameter paths); the trace
+            # reads interaction + product as this stack's `conv_<i>`
+            with jax.named_scope(f"conv_{i}"):
+                msg = MACEInteraction(mul=mul, lmax_out=layer_lmax,
+                                      avg_num_neighbors=float(
+                                          cfg.avg_num_neighbors or 1.0),
+                                      name=f"interaction_{i}")(
+                    feats, sh, radial, batch)
+                feats = MACEProduct(mul=mul, lmax=layer_lmax,
+                                    correlation=nu,
+                                    name=f"product_{i}")(msg, feats)
             out_i = MACEReadout(cfg=self.cfg, nonlinear=last,
                                 name=f"readout_{i + 1}")(
                 scalar_part(feats), batch)

@@ -38,12 +38,12 @@ class PainnMessage(nn.Module):
         W = W * cosine_cutoff(dist, self.cutoff)[:, None]
         scal = MLP([F, F * 3], activation=jax.nn.silu,
                    name="scalar_message_mlp")(s)
-        filt = W * scal[send]
+        filt = W * seg.neighbor_gather(scal, send)
         gate_v, gate_e, msg_s = jnp.split(filt, 3, axis=-1)
         # the reference divides the (already normalized) direction by dist
         # again (PAINNStack.py:214-217) — kept for behavioral parity
         direction = norm_diff / jnp.maximum(dist, 1e-9)[:, None]
-        msg_v = v[send] * gate_v[:, None, :] + \
+        msg_v = seg.neighbor_gather(v, send) * gate_v[:, None, :] + \
             gate_e[:, None, :] * direction[:, :, None]
         ds = seg.edge_aggregate_sum(msg_s, batch)
         dv = seg.edge_aggregate_sum(msg_v, batch)

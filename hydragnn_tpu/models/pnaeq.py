@@ -32,7 +32,7 @@ def degree_scaler_aggregation(h, recv, num_nodes, edge_mask, deg_hist,
     scatters."""
     if batch is not None and batch.nbr_edge is not None:
         mean, mn, mx, sd, deg = seg.neighbor_aggregate(
-            h[batch.nbr_edge], batch.nbr_mask)
+            seg.edge_gather(h, batch), batch.nbr_mask)
     else:
         mean, mn, mx, sd, deg = seg.pna_aggregate(h, recv, num_nodes,
                                                   edge_mask)
@@ -68,7 +68,8 @@ class PNAEqMessage(nn.Module):
         send, recv = batch.senders, batch.receivers
         F = self.node_size
         rbf_attr = jnp.tanh(nn.Dense(F, name="rbf_emb")(rbf))
-        parts = [x[send], x[recv], rbf_attr]
+        parts = [seg.neighbor_gather(x, send), seg.neighbor_gather(x, recv),
+                 rbf_attr]
         if self.edge_dim and batch.edge_attr is not None:
             parts.append(nn.Dense(F, name="edge_encoder")(batch.edge_attr))
         pre_in = jnp.concatenate(parts, axis=-1)
@@ -78,7 +79,7 @@ class PNAEqMessage(nn.Module):
         filt = scal * nn.Dense(F * 3, use_bias=False, name="rbf_lin")(rbf)
         gate_v, gate_e, msg_s = jnp.split(filt, 3, axis=-1)
 
-        msg_v = v[send] * gate_v[:, None, :] + \
+        msg_v = seg.neighbor_gather(v, send) * gate_v[:, None, :] + \
             gate_e[:, None, :] * edge_vec[:, :, None]
         dv = seg.edge_aggregate_sum(msg_v, batch)
 

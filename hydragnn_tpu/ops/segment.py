@@ -6,15 +6,52 @@ utils/model/model.py:214-221). On TPU these lower to XLA scatter/gather which
 fuse well; padding entries are handled by masks rather than dynamic shapes.
 
 All functions take `num_segments` statically so XLA sees fixed shapes.
+
+Trace vocabulary (PERF.md section 3; metadata only, the lowered program is
+the same): the reductions here run under `jax.named_scope("aggregate")`,
+node -> neighbour gathers under "neighbor_gather" (`neighbor_gather`), the
+edge -> dense-slot layout conversion `ev[batch.nbr_edge]` under
+"edge_gather" (`edge_gather`). Convs call the two gather helpers instead of
+indexing, so a reduction of a device trace finds the same names after a
+refactor.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 _PALLAS_STATE = {"checked": False, "on": False}
+
+
+def _aggregate(fn):
+    """Run `fn` under the "aggregate" scope. A fresh context manager a
+    call: a shared `jax.named_scope` object keeps ONE saved name stack, so
+    nesting it (`pna_aggregate` -> `segment_sum`) would restore the wrong
+    one."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("aggregate"):
+            return fn(*args, **kwargs)
+    return scoped
+
+
+def neighbor_gather(x, index):
+    """`x[index]` for a node -> neighbour gather (`index` is `batch.nbr`,
+    `batch.senders` or `batch.receivers`), named for the trace."""
+    with jax.named_scope("neighbor_gather"):
+        return x[index]
+
+
+def edge_gather(edge_values, batch):
+    """Per-edge values [E, ...] into the dense neighbour layout [N, K, ...]
+    (`edge_values[batch.nbr_edge]`), named for the trace: on the v5e this
+    layout conversion and its transpose are the largest single term of the
+    PNAPlus step and of the SchNet forward (PERF.md section 5)."""
+    with jax.named_scope("edge_gather"):
+        return edge_values[batch.nbr_edge]
 
 
 def _use_pallas() -> bool:
@@ -57,6 +94,7 @@ def _accum_f32(data):
     return data, None
 
 
+@_aggregate
 def segment_sum(data, segment_ids, num_segments, mask=None,
                 indices_are_sorted=False):
     """`indices_are_sorted` is the static XLA hint for nondecreasing
@@ -79,6 +117,7 @@ def segment_sum(data, segment_ids, num_segments, mask=None,
     return out if store_dtype is None else out.astype(store_dtype)
 
 
+@_aggregate
 def segment_count(segment_ids, num_segments, mask=None,
                   indices_are_sorted=False):
     ones = jnp.ones((segment_ids.shape[0],), jnp.float32)
@@ -98,6 +137,7 @@ def segment_mean(data, segment_ids, num_segments, mask=None,
     return total / count.reshape(count.shape + (1,) * (total.ndim - 1))
 
 
+@_aggregate
 def segment_max(data, segment_ids, num_segments, mask=None, neutral=-1e30):
     if mask is not None:
         data = jnp.where(_bcast(mask, data), data, neutral)
@@ -106,6 +146,7 @@ def segment_max(data, segment_ids, num_segments, mask=None, neutral=-1e30):
     return jnp.where(out <= neutral, 0.0, out)
 
 
+@_aggregate
 def segment_min(data, segment_ids, num_segments, mask=None, neutral=1e30):
     if mask is not None:
         data = jnp.where(_bcast(mask, data), data, neutral)
@@ -137,6 +178,7 @@ def pna_stats_epilogue(s, sq, cnt, mn, mx, eps=1e-5):
     return mean, mn, mx, std, cnt[..., 0]
 
 
+@_aggregate
 def pna_aggregate(data, segment_ids, num_segments, mask=None, eps=1e-5):
     """Fused PNA aggregation -> (mean, min, max, std, degree).
 
@@ -157,6 +199,7 @@ def pna_aggregate(data, segment_ids, num_segments, mask=None, eps=1e-5):
     return pna_stats_epilogue(s, sq, cnt, mn, mx, eps)
 
 
+@_aggregate
 def neighbor_aggregate(h, nbr_mask, eps=1e-5):
     """PNA statistics over the dense neighbor-list layout
     (graphs.batch.with_neighbor_format): h is [N, K, F] per-slot messages,
@@ -182,6 +225,7 @@ def neighbor_aggregate(h, nbr_mask, eps=1e-5):
     return mean, mn, mx, std, cnt
 
 
+@_aggregate
 def neighbor_sum(h, nbr_mask):
     """Masked sum over the K axis of [N, K, ...] dense-layout messages.
     Reduced-precision inputs accumulate in f32 (the same policy as
@@ -206,7 +250,7 @@ def edge_aggregate_sum(edge_values, batch):
     masked K-axis reduction — no scatter) and the masked segment scatter
     otherwise. Drop-in for the edge->node aggregation step of any conv."""
     if batch.nbr_edge is not None:
-        return neighbor_sum(edge_values[batch.nbr_edge], batch.nbr_mask)
+        return neighbor_sum(edge_gather(edge_values, batch), batch.nbr_mask)
     return segment_sum(edge_values, batch.receivers, batch.num_nodes,
                        batch.edge_mask)
 
@@ -222,8 +266,9 @@ def filter_weighted_aggregate(h, w, batch):
     contract pinned in tests/test_kernels.py), else the unfused
     gather + masked segment scatter."""
     if batch.nbr_edge is not None:
-        return neighbor_sum((h[batch.senders] * w)[batch.nbr_edge],
-                            batch.nbr_mask)
+        return neighbor_sum(
+            edge_gather(neighbor_gather(h, batch.senders) * w, batch),
+            batch.nbr_mask)
     from ..kernels import interpret_mode
     from ..kernels.fused_mp_pallas import (fused_filter_scatter,
                                            fused_mp_enabled)
@@ -235,14 +280,15 @@ def filter_weighted_aggregate(h, w, batch):
         return fused_filter_scatter(h, w, batch.senders,
                                     batch.receivers, batch.edge_mask,
                                     batch.num_nodes, interpret_mode())
-    return segment_sum(h[batch.senders] * w, batch.receivers,
-                       batch.num_nodes, batch.edge_mask)
+    return segment_sum(neighbor_gather(h, batch.senders) * w,
+                       batch.receivers, batch.num_nodes, batch.edge_mask)
 
 
 def edge_aggregate_mean(edge_values, batch):
     """Mean counterpart of `edge_aggregate_sum`."""
     if batch.nbr_edge is not None:
-        return neighbor_mean(edge_values[batch.nbr_edge], batch.nbr_mask)
+        return neighbor_mean(edge_gather(edge_values, batch),
+                             batch.nbr_mask)
     return segment_mean(edge_values, batch.receivers, batch.num_nodes,
                         batch.edge_mask)
 

@@ -108,11 +108,13 @@ def make_composed_train_step(model, cfg: ModelConfig,
             opt_spec = param_sharding_zero(mesh, opt_state,
                                            min_size=zero_min_size)
             opt_state = jax.lax.with_sharding_constraint(opt_state, opt_spec)
-        updates, new_opt = tx.update(grads, opt_state, state.params)
-        updates = freeze_conv_grads(updates, cfg)
-        if zero_opt:
-            new_opt = jax.lax.with_sharding_constraint(new_opt, opt_spec)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, opt_state, state.params)
+            updates = freeze_conv_grads(updates, cfg)
+            if zero_opt:
+                new_opt = jax.lax.with_sharding_constraint(new_opt,
+                                                           opt_spec)
+            new_params = optax.apply_updates(state.params, updates)
         return state.replace(params=new_params, batch_stats=new_bs,
                              opt_state=new_opt, step=state.step + 1), metrics
 

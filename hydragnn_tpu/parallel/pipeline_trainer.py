@@ -449,11 +449,12 @@ def _apply_updates(state: TrainState, grads, tx, freeze, mesh,
         opt_spec = param_sharding_zero(mesh, opt_state, axis="data",
                                        min_size=zero_min_size)
         opt_state = jax.lax.with_sharding_constraint(opt_state, opt_spec)
-    updates, new_opt = tx.update(grads, opt_state, state.params)
-    updates = freeze(updates)
-    if opt_spec is not None:
-        new_opt = jax.lax.with_sharding_constraint(new_opt, opt_spec)
-    new_params = optax.apply_updates(state.params, updates)
+    with jax.named_scope("optimizer"):
+        updates, new_opt = tx.update(grads, opt_state, state.params)
+        updates = freeze(updates)
+        if opt_spec is not None:
+            new_opt = jax.lax.with_sharding_constraint(new_opt, opt_spec)
+        new_params = optax.apply_updates(state.params, updates)
     return state.replace(params=new_params, opt_state=new_opt,
                          step=state.step + 1)
 

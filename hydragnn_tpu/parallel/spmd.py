@@ -29,7 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config.config import ModelConfig
 from ..graphs.batch import GraphBatch
 from ..train.train_step import (TrainState, _nonfinite_watchdog,
-                                eval_metrics_and_outputs,
+                                apply_optimizer, eval_metrics_and_outputs,
                                 freeze_conv_grads, make_forward_fn,
                                 make_loss_fn)
 
@@ -77,18 +77,21 @@ def _make_spmd_step_body(model, cfg: ModelConfig,
         # NaN poisons every replica — the pre-reduce flag names the step
         # that actually went bad); pmax: the STEP is bad if ANY shard is
         nonfinite = _nonfinite_watchdog(total, grads)
-        grads = freeze_conv_grads(jax.lax.pmean(grads, "data"), cfg)
-        metrics = dict(jax.lax.pmean(metrics, "data"))
-        metrics["nonfinite_steps"] = jax.lax.pmax(nonfinite, "data")
-        # cross-replica BatchNorm running stats (SyncBatchNorm semantics)
-        new_bs = jax.lax.pmean(new_bs, "data")
+        # the step's one gradient exchange, named for the trace (PERF.md
+        # section 3); the small metric/BatchNorm reductions ride with it
+        with jax.named_scope("grad_allreduce"):
+            grads = freeze_conv_grads(jax.lax.pmean(grads, "data"), cfg)
+            metrics = dict(jax.lax.pmean(metrics, "data"))
+            metrics["nonfinite_steps"] = jax.lax.pmax(nonfinite, "data")
+            # cross-replica BatchNorm running stats (SyncBatchNorm
+            # semantics)
+            new_bs = jax.lax.pmean(new_bs, "data")
         return grads, new_bs, metrics
 
     def per_device(params, batch_stats, opt_state, batch: GraphBatch):
         grads, new_bs, metrics = grads_per_device(params, batch_stats, batch)
-        updates, new_opt = tx.update(grads, opt_state, params)
-        updates = freeze_conv_grads(updates, cfg)
-        new_params = optax.apply_updates(params, updates)
+        new_params, new_opt = apply_optimizer(tx, cfg, grads, opt_state,
+                                              params)
         return new_params, new_bs, new_opt, metrics
 
     if zero_opt:
@@ -108,10 +111,12 @@ def _make_spmd_step_body(model, cfg: ModelConfig,
                                            min_size=zero_min_size)
             opt_state = jax.lax.with_sharding_constraint(
                 state.opt_state, opt_spec)
-            updates, new_opt = tx.update(grads, opt_state, state.params)
-            updates = freeze_conv_grads(updates, cfg)
-            new_opt = jax.lax.with_sharding_constraint(new_opt, opt_spec)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = tx.update(grads, opt_state, state.params)
+                updates = freeze_conv_grads(updates, cfg)
+                new_opt = jax.lax.with_sharding_constraint(new_opt,
+                                                           opt_spec)
+                new_params = optax.apply_updates(state.params, updates)
             return state.replace(params=new_params, batch_stats=new_bs,
                                  opt_state=new_opt,
                                  step=state.step + 1), metrics

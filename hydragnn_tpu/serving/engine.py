@@ -84,10 +84,21 @@ loads the bucket ladder from disk instead of recompiling
 (``compile_store_hits`` vs ``compile_fresh`` report the split), and
 ``latency_snapshot()`` so the router can compute fleet-aggregate
 percentiles from raw per-replica latencies.
+
+Latency and spans (docs/observability.md "Span taxonomy"): a request's
+latency runs from its ARRIVAL (entry of ``submit_structure``, before the
+graph build, or of ``submit``) to its result being set. With a span
+recorder installed every request (``req``) and every executed batch
+(``batch``) leaves linked spans: ``serve.request`` over
+``serve.graph_build`` and ``serve.queue_wait``; ``serve.batch`` over
+``serve.collate``, ``serve.dispatch``, ``serve.fetch`` and
+``serve.unpad``; and the dispatcher's own ``serve.await_request`` and
+``serve.coalesce_wait``.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -213,15 +224,24 @@ def select_bucket(buckets: Sequence[PackBudget], count: int, tot_n: int,
 
 
 class _Request:
-    __slots__ = ("sample", "future", "n", "e", "t_submit", "deadline")
+    __slots__ = ("sample", "future", "n", "e", "t_submit", "deadline",
+                 "req", "t_arrival")
 
     def __init__(self, sample: GraphSample, future: Future,
-                 deadline_ms: Optional[float] = None):
+                 deadline_ms: Optional[float] = None,
+                 req: Optional[int] = None,
+                 t_arrival: Optional[float] = None):
         self.sample = sample
         self.future = future
         self.n = sample.num_nodes
         self.e = sample.num_edges
         self.t_submit = time.perf_counter()
+        # the request's identifier on its spans, and when it ARRIVED: the
+        # entry of `submit_structure` (before the graph build) or of
+        # `submit`. Latency (`stats()`, /metrics, `serve.request`) runs
+        # from arrival; the deadline and `serve.queue_wait` from t_submit
+        self.req = req
+        self.t_arrival = self.t_submit if t_arrival is None else t_arrival
         # absolute expiry on the same clock as t_submit; None/0 = none
         self.deadline = (self.t_submit + float(deadline_ms) / 1e3
                          if deadline_ms else None)
@@ -529,6 +549,10 @@ class InferenceEngine:
         self.queue_rejections = 0  # guarded-by: _lock
         self.circuit_rejections = 0  # guarded-by: _lock
         self._metrics_server = None
+        # span identifiers (docs/observability.md): every serving span
+        # carries the `req` or the `batch` it belongs to
+        self._req_ids = itertools.count()
+        self._batch_ids = itertools.count()
         self._dispatcher = threading.Thread(target=self._loop,
                                             name="serve-dispatch",
                                             daemon=True)
@@ -547,10 +571,26 @@ class InferenceEngine:
         (default: the engine's default_deadline_ms) bounds how long the
         request may wait — once expired it resolves with
         `DeadlineExceededError` instead of occupying a batch slot."""
+        t_arrival = time.perf_counter()
+        req = next(self._req_ids)
+        try:
+            return self._submit(sample, deadline_ms, req, t_arrival)
+        except BaseException as e:  # noqa: BLE001 — re-raised
+            self._span_request(req, t_arrival, error=type(e).__name__)
+            raise
+
+    def _submit(self, sample: GraphSample, deadline_ms: Optional[float],
+                req: int, t_arrival: float) -> Future:
+        """`submit` for a request that arrived at `t_arrival` under the
+        identifier `req` (`submit_structure` stamps both before it builds
+        the graph). A rejection raised here gets its `serve.request`
+        span from the caller, which holds `req`; one resolved here (an
+        invalid sample) gets it here."""
         fut: Future = Future()
         err = self._validate(sample)
         if err is not None:
             fut.set_exception(err)
+            self._span_request(req, t_arrival, error=type(err).__name__)
             return fut
         if deadline_ms is None:
             deadline_ms = self.default_deadline_ms
@@ -569,7 +609,8 @@ class InferenceEngine:
             # under the lock so a request can never land behind the
             # shutdown sentinel
             self._queue.put(  # hydralint: disable=lock-discipline -- unbounded queue, put cannot block; ordering vs the shutdown sentinel needs the lock
-                _Request(sample, fut, deadline_ms=deadline_ms))
+                _Request(sample, fut, deadline_ms=deadline_ms, req=req,
+                         t_arrival=t_arrival))
             depth = self._queue.qsize()
             if depth > self.max_queue_depth:
                 self.max_queue_depth = depth
@@ -719,6 +760,22 @@ class InferenceEngine:
         either way the edges are bitwise the fresh build's (PR 5 total
         order). The returned future carries `.rebuilt` and
         `.graph_build_ms` breadcrumbs next to the usual `.bucket`."""
+        t_arrival = time.perf_counter()
+        req = next(self._req_ids)
+        try:
+            return self._submit_structure(
+                req, t_arrival, positions, node_features, cell,
+                graph_feats, session, deadline_ms)
+        except BaseException as e:  # noqa: BLE001 — re-raised
+            # shed load is what an operator traces: a request rejected
+            # here (admission, open breaker, a bad structure) still
+            # leaves its `serve.request`, with the error
+            self._span_request(req, t_arrival, error=type(e).__name__)
+            raise
+
+    def _submit_structure(self, req: int, t_arrival: float, positions,
+                          node_features, cell, graph_feats, session,
+                          deadline_ms) -> Future:
         self._require_structure()
         # load shedding must shed the HOST work too: a read-only
         # admission precheck fast-fails an open breaker / full queue /
@@ -757,7 +814,8 @@ class InferenceEngine:
         rec = _spans.current_recorder()
         if rec is not None:
             rec.add("serve.graph_build", t0, build_s, "serving",
-                    {"rebuilt": bool(rebuilt),
+                    {"req": req, "parent": "serve.request",
+                     "rebuilt": bool(rebuilt),
                      "incremental": session is not None,
                      "edges": int(sample.num_edges)})
         with self._lock:
@@ -780,7 +838,7 @@ class InferenceEngine:
         reg.gauge_set("serve.nbr_rebuild_fraction", rebuilds / updates,
                       help="rebuilds over neighbor-list updates since "
                            "engine start")
-        fut = self.submit(sample, deadline_ms=deadline_ms)
+        fut = self._submit(sample, deadline_ms, req, t_arrival)
         fut.rebuilt = bool(rebuilt)  # breadcrumbs beside `.bucket`: did
         fut.graph_build_ms = build_s * 1e3  # this step rebuild, and what
         # the host-side structure -> graph stage cost
@@ -898,7 +956,7 @@ class InferenceEngine:
         if bucket is None:
             bucket = select_bucket(self.buckets, 1, req.n, req.e)
         shards = [[req]] + [[] for _ in range(self.num_shards - 1)]
-        outs, _ = self._forward_requests(shards, bucket)
+        outs, _ = self._forward_requests(shards, bucket, None)
         return self._unpad(shards, bucket, outs)[0]
 
     def warmup(self) -> int:
@@ -1157,24 +1215,34 @@ class InferenceEngine:
         return hit
 
     def _forward_requests(self, shards: List[List[_Request]],
-                          bucket: PackBudget
+                          bucket: PackBudget, batch_id: Optional[int]
                           ) -> Tuple[List[np.ndarray], str]:
-        if self.num_shards > 1:
-            parts = [self._collate_bucket([r.sample for r in sh], bucket)
-                     if sh else None for sh in shards]
-            batch = self._stack_shards(parts, bucket)
-        else:
-            batch = self._collate_bucket([r.sample for r in shards[0]],
-                                         bucket)
-        compiled = self._get_compiled(bucket, batch)
-        # ONE snapshot of the (variables, version) pair: a concurrent
-        # hot-swap lands entirely before or entirely after this batch,
-        # and the echoed version always names the weights that ran
-        with self._lock:
-            variables = self._variables
-            version = self.model_version
-        outs = compiled(variables, batch)
-        return [np.asarray(o) for o in outs], version
+        """Collate, call the compiled program, fetch: three spans of the
+        batch `batch_id` (None: `forward_single`, no `serve.batch`), so
+        what the host does while the device is idle (collate) and while
+        it waits for the device (fetch) can be told apart."""
+        ids = {"batch": batch_id, "parent": "serve.batch"}
+        with _spans.span("serve.collate", "serving", **ids):
+            if self.num_shards > 1:
+                parts = [self._collate_bucket([r.sample for r in sh],
+                                              bucket)
+                         if sh else None for sh in shards]
+                batch = self._stack_shards(parts, bucket)
+            else:
+                batch = self._collate_bucket(
+                    [r.sample for r in shards[0]], bucket)
+        with _spans.span("serve.dispatch", "serving", **ids):
+            compiled = self._get_compiled(bucket, batch)
+            # ONE snapshot of the (variables, version) pair: a concurrent
+            # hot-swap lands entirely before or entirely after this
+            # batch, and the echoed version always names the weights
+            # that ran
+            with self._lock:
+                variables = self._variables
+                version = self.model_version
+            outs = compiled(variables, batch)
+        with _spans.span("serve.fetch", "serving", **ids):
+            return [np.asarray(o) for o in outs], version
 
     def _unpad(self, shards: List[List[_Request]], bucket: PackBudget,
                outs: List[np.ndarray]) -> List[List[np.ndarray]]:
@@ -1201,6 +1269,15 @@ class InferenceEngine:
                 no += req.n
         return results
 
+    @staticmethod
+    def _span_request(req: int, t_arrival: float, **args) -> None:
+        """The `serve.request` span, as the request resolves (its
+        future's result or error set, or its submit call rejected):
+        arrival -> now, with its `batch` or the `error` it resolved to.
+        Every path that ends a request calls this once."""
+        _spans.record("serve.request", t_arrival,
+                      _spans.now() - t_arrival, "serving", req=req, **args)
+
     def _fail_expired(self, req: _Request) -> None:
         with self._lock:
             self.deadline_expired += 1
@@ -1209,6 +1286,8 @@ class InferenceEngine:
                 f"deadline expired after "
                 f"{(time.perf_counter() - req.t_submit) * 1e3:.1f} ms "
                 "in queue"))
+            self._span_request(req.req, req.t_arrival,
+                               error="DeadlineExceededError")
 
     def _record_batch_failure(self) -> None:
         with self._lock:
@@ -1252,6 +1331,7 @@ class InferenceEngine:
                     # executing: re-open so the next submit re-probes
                     self._breaker_state = "open"
             return
+        batch_id = next(self._batch_ids)
         try:
             # deterministic batch-failure injection; counted per executed
             # batch (utils/faults.py serving-dispatch site)
@@ -1267,29 +1347,34 @@ class InferenceEngine:
                     "fits no bucket — the coalescer's fill caps must "
                     "bound every batch by the largest bucket")
             # request-lifecycle spans (docs/observability.md): queue-wait
-            # per request (submit -> dispatch), then the batch's forward
-            # and unpad stages, all carrying the bucket/parity
-            # breadcrumbs the futures advertise. One recorder check keeps
-            # the disabled path at a single branch per batch.
+            # per request (submit -> dispatch); then the batch: collate,
+            # dispatch and fetch (inside `_forward_requests`; the older
+            # `serve.forward` spans the three) and unpad, all children of
+            # `serve.batch`, which lists its requests and carries the
+            # bucket/parity breadcrumbs the futures advertise. One
+            # recorder check keeps the disabled path at a single branch
+            # per batch.
             rec = _spans.current_recorder()
             if rec is not None:
                 t_disp = _spans.now()
                 for r in reqs:
                     rec.add("serve.queue_wait", r.t_submit,
-                            t_disp - r.t_submit, "serving")
+                            t_disp - r.t_submit, "serving",
+                            {"req": r.req, "batch": batch_id,
+                             "parent": "serve.request"})
                 t_fwd = _spans.now()
-            outs, version = self._forward_requests(shards, bucket)
+            outs, version = self._forward_requests(shards, bucket,
+                                                   batch_id)
             if rec is not None:
                 rec.add("serve.forward", t_fwd, _spans.now() - t_fwd,
                         "serving",
-                        {"bucket": [bucket.n_node, bucket.n_edge,
+                        {"batch": batch_id, "parent": "serve.batch",
+                         "bucket": [bucket.n_node, bucket.n_edge,
                                     bucket.n_graph],
                          "requests": len(reqs), "parity": self.parity})
-                t_unpad = _spans.now()
-            results = self._unpad(shards, bucket, outs)
-            if rec is not None:
-                rec.add("serve.unpad", t_unpad, _spans.now() - t_unpad,
-                        "serving")
+            with _spans.span("serve.unpad", "serving", batch=batch_id,
+                             parent="serve.batch"):
+                results = self._unpad(shards, bucket, outs)
             done = time.perf_counter()
             tot_n = sum(r.n for r in reqs)
             tot_e = sum(r.e for r in reqs)
@@ -1302,7 +1387,7 @@ class InferenceEngine:
                 self._real_edge_slots += tot_e
                 self._total_node_slots += bucket.n_node * self.num_shards
                 self._total_edge_slots += bucket.n_edge * self.num_shards
-                self._latencies.extend(done - r.t_submit for r in reqs)
+                self._latencies.extend(done - r.t_arrival for r in reqs)
             for req, res in zip(reqs, results):
                 req.future.bucket = bucket  # adjudication breadcrumbs: the
                 req.future.parity = self.parity       # bucket this batch
@@ -1313,6 +1398,17 @@ class InferenceEngine:
                 req.future.tier = self.tier  # + the fleet tier that
                 # served it (int8 fast vs fp32 accurate; serving/fleet.py)
                 req.future.set_result(res)
+                if rec is not None:
+                    # the request ends with ITS OWN result, not with the
+                    # last of the batch's
+                    self._span_request(req.req, req.t_arrival,
+                                       batch=batch_id)
+            if rec is not None:
+                rec.add("serve.batch", t_disp, _spans.now() - t_disp,
+                        "serving",
+                        {"batch": batch_id, "reqs": [r.req for r in reqs],
+                         "bucket": [bucket.n_node, bucket.n_edge,
+                                    bucket.n_graph]})
         except BaseException as e:  # noqa: BLE001 — must reach the callers
             # dispatcher supervision: a failed batch resolves only ITS OWN
             # futures; the dispatcher survives and the breaker decides
@@ -1321,6 +1417,9 @@ class InferenceEngine:
             for req in reqs:
                 if not req.future.done():
                     req.future.set_exception(e)
+                    self._span_request(req.req, req.t_arrival,
+                                       batch=batch_id,
+                                       error=type(e).__name__)
         else:
             self._record_batch_success()
 
@@ -1331,7 +1430,9 @@ class InferenceEngine:
         then the next shard opens; the batch flushes at max_batch_size
         total requests, when every shard is full, or max_wait_ms after
         `first` was dequeued — whichever first. Returns
-        (shards, leftover_or_sentinel)."""
+        (shards, leftover_or_sentinel). The loop is one
+        `serve.coalesce_wait` span: the dispatcher holding the first
+        request back for company."""
         big = self.buckets[-1]
         shards: List[List[_Request]] = [[first]]
         rem_n = big.cap_nodes - first.n
@@ -1339,31 +1440,32 @@ class InferenceEngine:
         total = 1
         deadline = time.perf_counter() + (self.max_wait_s if wait else 0.0)
         leftover = None
-        while total < self.max_batch_size:
-            timeout = deadline - time.perf_counter()
-            try:
-                nxt = (self._queue.get_nowait() if timeout <= 0
-                       else self._queue.get(timeout=timeout))
-            except queue.Empty:
-                break
-            if nxt is _SHUTDOWN:
-                leftover = nxt
-                break
-            if (nxt.deadline is not None
-                    and time.perf_counter() > nxt.deadline):
-                self._fail_expired(nxt)
-                continue
-            if (nxt.n > rem_n or nxt.e > rem_e
-                    or len(shards[-1]) >= self._shard_fill_cap):
-                if len(shards) >= self.num_shards:
+        with _spans.span("serve.coalesce_wait", "serving", req=first.req):
+            while total < self.max_batch_size:
+                timeout = deadline - time.perf_counter()
+                try:
+                    nxt = (self._queue.get_nowait() if timeout <= 0
+                           else self._queue.get(timeout=timeout))
+                except queue.Empty:
+                    break
+                if nxt is _SHUTDOWN:
                     leftover = nxt
                     break
-                shards.append([])
-                rem_n, rem_e = big.cap_nodes, big.cap_edges
-            shards[-1].append(nxt)
-            rem_n -= nxt.n
-            rem_e -= nxt.e
-            total += 1
+                if (nxt.deadline is not None
+                        and time.perf_counter() > nxt.deadline):
+                    self._fail_expired(nxt)
+                    continue
+                if (nxt.n > rem_n or nxt.e > rem_e
+                        or len(shards[-1]) >= self._shard_fill_cap):
+                    if len(shards) >= self.num_shards:
+                        leftover = nxt
+                        break
+                    shards.append([])
+                    rem_n, rem_e = big.cap_nodes, big.cap_edges
+                shards[-1].append(nxt)
+                rem_n -= nxt.n
+                rem_e -= nxt.e
+                total += 1
         while len(shards) < self.num_shards:
             shards.append([])
         return shards, leftover
@@ -1398,6 +1500,8 @@ class InferenceEngine:
             return False
         if not req.future.done():
             req.future.set_exception(err)
+            self._span_request(req.req, req.t_arrival,
+                               error=type(err).__name__)
         return True
 
     def _loop(self):
@@ -1405,7 +1509,9 @@ class InferenceEngine:
         try:
             while True:
                 if pending is None:
-                    req = self._queue.get()
+                    # queue empty: the dispatcher waits for a request
+                    with _spans.span("serve.await_request", "serving"):
+                        req = self._queue.get()
                 else:
                     req, pending = pending, None
                 if req is _SHUTDOWN:
@@ -1436,6 +1542,8 @@ class InferenceEngine:
                 if fatal is not None:
                     if not req.future.done():
                         req.future.set_exception(fatal)
+                        self._span_request(req.req, req.t_arrival,
+                                           error=type(fatal).__name__)
                 else:
                     shards, leftover = self._coalesce(req, wait=False)
                     self._execute(shards)

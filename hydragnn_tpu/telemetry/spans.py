@@ -17,6 +17,17 @@ speaks the format. The opt-in ``device_trace`` bracket additionally
 captures a jax.profiler trace (XLA HLO + device timelines, TensorBoard/
 XProf-viewable) around a region — host spans tell you WHERE to point it.
 
+Two clocks, one link. A `span()` (the context-manager form) is entered
+as a ``jax.profiler.TraceAnnotation`` as well, so whenever a device trace
+is being captured it lands on ``/host:CPU`` of the ``.xplane.pb`` on the
+profiler's own clock, next to the device's operations. A `record()`ed span
+(one whose start predates the call site) exists on ``perf_counter`` only;
+`mark_clock()` — called when a recorder is installed and when a device
+trace bracket opens — writes one ``hydragnn.clock`` annotation whose
+``perf_counter_ns`` stat is the span clock read as the annotation starts,
+so the trace file itself carries the offset between the two clocks:
+``profiler_ns = perf_counter_ns - clock.perf_counter_ns + clock.start_ns``.
+
 Disabled-by-default contract: when no recorder is installed, the
 module-level ``record``/``span`` helpers are a single global read + None
 check — the per-batch call sites in the trainer/loader/engine stay at
@@ -133,10 +144,12 @@ _RECORDER: Optional[SpanRecorder] = None
 
 def install_recorder(rec: Optional[SpanRecorder]) -> Optional[SpanRecorder]:
     """Install the process span recorder (None = disable); returns the
-    previous one."""
+    previous one. Installing one marks the clock (`mark_clock`)."""
     global _RECORDER
     prev = _RECORDER
     _RECORDER = rec
+    if rec is not None:
+        mark_clock()
     return prev
 
 
@@ -157,19 +170,60 @@ def record(name: str, t_start: float, dur_s: float, cat: str = "host",
         rec.add(name, t_start, dur_s, cat, args or None)
 
 
-@contextlib.contextmanager
+def _annotation(name: str, **kwargs):
+    # jax is imported on first use: importing this module stays cheap, and
+    # outside a profiler session an annotation costs one flag check
+    import jax
+    return jax.profiler.TraceAnnotation(name, **kwargs)
+
+
+CLOCK_EVENT = "hydragnn.clock"
+
+
+def mark_clock() -> None:
+    """One `hydragnn.clock` annotation on the profiler's timeline (when a
+    trace is being captured; nothing otherwise) carrying the span clock's
+    reading at its start — module docstring, "Two clocks"."""
+    with _annotation(CLOCK_EVENT, perf_counter_ns=time.perf_counter_ns()):
+        pass
+
+
+class _Span:
+    """`span()` with a recorder installed: a TraceAnnotation on the
+    profiler's clock and a complete event in the recorder. A span that
+    ends after its recorder was taken out is dropped: a recorder holds what
+    ended while it was installed (an idle dispatcher's wait that outlives
+    a traced window would otherwise count minutes against it)."""
+    __slots__ = ("rec", "name", "cat", "args", "t0", "ann")
+
+    def __init__(self, rec, name, cat, args):
+        self.rec, self.name, self.cat, self.args = rec, name, cat, args
+
+    def __enter__(self):
+        self.ann = _annotation(self.name, **self.args)
+        self.ann.__enter__()
+        self.t0 = _CLOCK()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = _CLOCK() - self.t0
+        self.ann.__exit__(exc_type, exc, tb)
+        if _RECORDER is self.rec:
+            self.rec.add(self.name, self.t0, dur, self.cat,
+                         self.args or None)
+        return False
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
 def span(name: str, cat: str = "host", **args):
-    """Context-manager span around a host region; near-free when no
-    recorder is installed."""
+    """Context-manager span around a host region; with no recorder
+    installed, one global read + None check and a shared no-op."""
     rec = _RECORDER
     if rec is None:
-        yield
-        return
-    t0 = _CLOCK()
-    try:
-        yield
-    finally:
-        rec.add(name, t0, _CLOCK() - t0, cat, args or None)
+        return _NO_SPAN
+    return _Span(rec, name, cat, args)
 
 
 def now() -> float:
@@ -191,6 +245,7 @@ def device_trace(log_dir: str):
     import jax
     os.makedirs(log_dir, exist_ok=True)
     jax.profiler.start_trace(log_dir)
+    mark_clock()
     try:
         yield
     finally:
@@ -233,6 +288,7 @@ class EpochDeviceTrace:
             out = os.path.join(self.prefix or ".", "profile")
             os.makedirs(out, exist_ok=True)
             jax.profiler.start_trace(out)
+            mark_clock()
             self._active = True
         return self
 

@@ -73,16 +73,17 @@ def multihead_loss(cfg: ModelConfig, loss_name: str, outputs, outputs_var,
     weighted-sum combine, so a one-hot-weighted mixture step matches the
     corresponding single-dataset step bitwise on exactly-representable
     data (tests/test_gfm.py pins it)."""
-    targets = head_targets(cfg, batch)
-    tot = 0.0
-    tasks = []
-    for ih, head in enumerate(cfg.heads):
-        mask = head_loss_mask(batch, ih, head)
-        var = outputs_var[ih] if outputs_var is not None else None
-        li = masked_loss(loss_name, outputs[ih], targets[ih], mask, var)
-        tasks.append(li)
-        tot = tot + cfg.task_weights[ih] * li
-    return tot, tasks
+    with jax.named_scope("loss"):  # trace scope, PERF.md section 3
+        targets = head_targets(cfg, batch)
+        tot = 0.0
+        tasks = []
+        for ih, head in enumerate(cfg.heads):
+            mask = head_loss_mask(batch, ih, head)
+            var = outputs_var[ih] if outputs_var is not None else None
+            li = masked_loss(loss_name, outputs[ih], targets[ih], mask, var)
+            tasks.append(li)
+            tot = tot + cfg.task_weights[ih] * li
+        return tot, tasks
 
 
 def auto_force_weight(energy, forces, graph_mask, node_mask,
@@ -124,9 +125,12 @@ def energy_forces_from_node_head(apply_fn: Callable, variables, batch,
                                   0.0)),
                 (graph_e, new_bs))
 
-    (_, (graph_e, new_bs)), neg_forces = jax.value_and_grad(
-        total_energy, has_aux=True)(batch.pos)
-    return graph_e, -neg_forces, new_bs
+    # trace scope (PERF.md section 3): the forward and its transpose with
+    # respect to the positions, in training, evaluation and serving alike
+    with jax.named_scope("ef_forces"):
+        (_, (graph_e, new_bs)), neg_forces = jax.value_and_grad(
+            total_energy, has_aux=True)(batch.pos)
+        return graph_e, -neg_forces, new_bs
 
 
 def energy_force_loss(apply_fn: Callable, variables, cfg: ModelConfig,
@@ -148,13 +152,16 @@ def energy_force_loss(apply_fn: Callable, variables, cfg: ModelConfig,
     graph_e, forces_pred, new_bs = energy_forces_from_node_head(
         apply_fn, variables, batch, train=train)
 
-    e_loss = masked_loss(loss_name, graph_e, batch.energy, batch.graph_mask)
-    f_loss = masked_loss(loss_name, forces_pred, batch.forces, batch.node_mask)
-    if force_weight == "auto":
-        force_weight = auto_force_weight(batch.energy, batch.forces,
-                                         batch.graph_mask, batch.node_mask,
-                                         energy_weight)
-    total = energy_weight * e_loss + force_weight * f_loss
+    with jax.named_scope("loss"):
+        e_loss = masked_loss(loss_name, graph_e, batch.energy,
+                             batch.graph_mask)
+        f_loss = masked_loss(loss_name, forces_pred, batch.forces,
+                             batch.node_mask)
+        if force_weight == "auto":
+            force_weight = auto_force_weight(
+                batch.energy, batch.forces, batch.graph_mask,
+                batch.node_mask, energy_weight)
+        total = energy_weight * e_loss + force_weight * f_loss
     return total, {"energy_loss": e_loss, "force_loss": f_loss,
                    "energy_pred": graph_e, "forces_pred": forces_pred,
                    "batch_stats": new_bs}

@@ -160,6 +160,17 @@ def make_loss_fn(model, cfg: ModelConfig, loss_name: str = "mse",
     return loss_fn
 
 
+def apply_optimizer(tx: optax.GradientTransformation, cfg: ModelConfig,
+                    grads, opt_state, params):
+    """(new_params, new_opt_state): `tx.update` + the conv freeze on the
+    updates + `apply_updates`, under the trace scope "optimizer" (PERF.md
+    section 3) — the one copy the single-device and SPMD steps share."""
+    with jax.named_scope("optimizer"):
+        updates, new_opt = tx.update(grads, opt_state, params)
+        updates = freeze_conv_grads(updates, cfg)
+        return optax.apply_updates(params, updates), new_opt
+
+
 def _nonfinite_watchdog(loss, grads):
     """1.0 when this step's loss or ANY gradient leaf carries a
     non-finite value, else 0.0 — the per-step brick of the bf16
@@ -196,9 +207,8 @@ def _make_step_body(model, cfg: ModelConfig, tx: optax.GradientTransformation,
         metrics = {**metrics,
                    "nonfinite_steps": _nonfinite_watchdog(total, grads)}
         grads = freeze_conv_grads(grads, cfg)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        updates = freeze_conv_grads(updates, cfg)
-        new_params = optax.apply_updates(state.params, updates)
+        new_params, new_opt = apply_optimizer(
+            tx, cfg, grads, state.opt_state, state.params)
         new_state = state.replace(params=new_params, batch_stats=new_bs,
                                   opt_state=new_opt, step=state.step + 1)
         return new_state, metrics
@@ -304,9 +314,8 @@ def make_sampled_train_step(model, cfg: ModelConfig,
         metrics = {**metrics,
                    "nonfinite_steps": _nonfinite_watchdog(total, grads)}
         grads = freeze_conv_grads(grads, cfg)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        updates = freeze_conv_grads(updates, cfg)
-        new_params = optax.apply_updates(state.params, updates)
+        new_params, new_opt = apply_optimizer(
+            tx, cfg, grads, state.opt_state, state.params)
         new_state = state.replace(params=new_params, batch_stats=new_bs,
                                   opt_state=new_opt, step=state.step + 1)
         return new_state, metrics, inter

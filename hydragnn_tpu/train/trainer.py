@@ -307,6 +307,11 @@ def train_validate_test(
     # blocked on the input pipeline (collation + staging) vs dispatching
     # steps — the input-bound fraction the async loader is meant to erase
     stall = HostStallMonitor(tracer=tr)
+    # the global optimizer-step number the step-level host spans carry
+    # (`dataload_wait`, `step_dispatch`, `device_wait`, and the
+    # StepTraceAnnotation round the step): a span carries the step it
+    # precedes or belongs to; a resumed run counts on from its epoch
+    stall.step = start_epoch * len(train_loader)
     prev_compiled = 0  # jit-recompile counter baseline (utils/profiling)
     # span taxonomy (docs/observability.md): the placement callables are
     # wrapped so host->device staging shows up as `h2d` spans on the
@@ -395,10 +400,13 @@ def train_validate_test(
                               and batch.x.shape[0] == steps_per_call
                               and (max_num_batch is None
                                    or nb + steps_per_call <= max_num_batch))
-                with tr.timer("train_step"), stall.step_timer():
+                nb_before = nb
+                with tr.timer("train_step", step=stall.step), \
+                        stall.step_timer():
                     if full_group:
                         state, metrics = multi_train_step(state, batch)
-                        _accumulate_metrics(acc_train, metrics, summed=True)
+                        _accumulate_metrics(acc_train, metrics, summed=True,
+                                            span_args=stall.span_args())
                         nb += steps_per_call
                     elif group:
                         # remainder group, or a max_num_batch cap inside
@@ -411,12 +419,15 @@ def train_validate_test(
                             b_i = jax.tree_util.tree_map(
                                 lambda a, i=i: a[i], batch)
                             state, m = train_step(state, b_i)
-                            _accumulate_metrics(acc_train, m)
+                            _accumulate_metrics(
+                                acc_train, m, span_args=stall.span_args())
                             nb += 1
                     else:
                         state, metrics = train_step(state, batch)
-                        _accumulate_metrics(acc_train, metrics)
+                        _accumulate_metrics(acc_train, metrics,
+                                            span_args=stall.span_args())
                         nb += 1
+                stall.step += nb - nb_before
                 if max_num_batch is not None and nb >= max_num_batch:
                     break
         if preempted:
@@ -601,11 +612,8 @@ def train_validate_test(
             # per-line contract for exactly the degraded runs worth
             # inspecting
             # pipelined runs (run_training sets telemetry.pipeline_info):
-            # the schedule's closed-form bubble fraction as a gauge plus
-            # per-stage idle spans — a SCHEDULE-MODEL overlay (each
-            # stage's fill/drain ticks scaled to this epoch's measured
-            # step time), not a device measurement; cat "pipeline-model"
-            # marks it as such in the trace (docs/pipeline.md)
+            # the schedule's closed-form bubble fractions as gauges — a
+            # SCHEDULE MODEL, not a device measurement (docs/pipeline.md)
             if pinfo:
                 reg.gauge_set("pipeline_bubble_frac",
                               float(pinfo["bubble_frac"]),
@@ -615,24 +623,6 @@ def train_validate_test(
                               float(pinfo["train_bubble_frac"]),
                               help="closed-form fwd+bwd train-step bubble "
                                    "for the active schedule")
-                rec = _spans.current_recorder()
-                if rec is not None and stall.step_s > 0:
-                    S_p = int(pinfo["stages"])
-                    ticks = float(pinfo["train_ticks"])
-                    t_end = _spans.now()
-                    # every stage does 2*M useful ticks per step (each
-                    # microbatch crosses it once forward, once backward);
-                    # the rest of the step's ticks are fill/drain idle
-                    idle_ticks = max(
-                        ticks - 2 * int(pinfo["microbatches"]), 0)
-                    dur = stall.step_s * idle_ticks / max(ticks, 1.0)
-                    for s in range(S_p):
-                        rec.add("pipe.stage_idle", t_end - dur, dur,
-                                "pipeline-model",
-                                {"stage": s, "epoch": epoch,
-                                 "idle_ticks": idle_ticks,
-                                 "ticks_per_step": ticks,
-                                 "schedule": pinfo["schedule"]})
             data = {"nonfinite_steps": nonfinite_steps, "batches": nb}
             for k, v in (("train_loss", train_loss),
                          ("val_loss", val_loss),
@@ -749,19 +739,16 @@ def train_validate_test(
 
 def _traced_place(place_fn):
     """Wrap a batch-placement callable so host->device staging shows up
-    as `h2d` spans (telemetry/spans.py). With no recorder installed the
+    as `h2d` spans (telemetry/spans.py). No `step` on them: the prefetch
+    thread places a batch a few steps ahead of its own, and evaluation
+    batches come through here too. With no recorder installed the
     per-batch cost is one global read + None check."""
     if place_fn is None:
         return None
 
     def placed(batch):
-        rec = _spans.current_recorder()
-        if rec is None:
+        with _spans.span("h2d", "loader"):
             return place_fn(batch)
-        t0 = _spans.now()
-        out = place_fn(batch)
-        rec.add("h2d", t0, _spans.now() - t0, "loader")
-        return out
 
     return placed
 
@@ -782,19 +769,15 @@ def _group_batches(loader, size):
         yield _stack_batches(buf)
 
 
-def _accumulate_metrics(acc: Dict[str, float], metrics, summed=False):
+def _accumulate_metrics(acc: Dict[str, float], metrics, summed=False,
+                        span_args=None):
     """Accumulate the loss/per-task scalars from one step (or one stacked
     multi-step, `summed=True`) into `acc` — one host transfer for the whole
     metrics dict, not one per key. The device_get blocks until the step's
     dependency chain is done, so under telemetry it is recorded as the
     `device_wait` span — the dispatch-vs-execute split of the step
     timeline (docs/observability.md)."""
-    rec = _spans.current_recorder()
-    if rec is not None:
-        t0 = _spans.now()
-        vals = jax.device_get(metrics)
-        rec.add("device_wait", t0, _spans.now() - t0, "device")
-    else:
+    with _spans.span("device_wait", "device", **(span_args or {})):
         vals = jax.device_get(metrics)
     for k, v in vals.items():
         if (k == "loss" or k == "nonfinite_steps" or k.startswith("task_")

@@ -1,8 +1,9 @@
 """Region tracer + aggregate timers.
 
 reference: hydragnn/utils/profiling_and_tracing/tracer.py:14-167 (Tracer
-facade with GPTL/Score-P backends, @profile decorator, timer contextmanager)
-and time_utils.py:22-138 (class-level timer dicts, min/max/avg across ranks).
+facade with GPTL/Score-P backends, @profile decorator, timer contextmanager).
+The reference's time_utils.py (class-level timer dicts) has no port: nothing
+called it, and a region's aggregate lives in `Tracer.times` / `counts`.
 
 TPU mapping: `jax.profiler.TraceAnnotation` replaces Score-P regions;
 `jax.block_until_ready` replaces cudasync for accurate walls
@@ -65,31 +66,41 @@ class Tracer:
         self.add_time(name, time.perf_counter() - t0, t_start=t0)
 
     def add_time(self, name: str, dt: float,
-                 t_start: Optional[float] = None):
+                 t_start: Optional[float] = None, **args):
         """Accumulate a measured region (external timers — the stall
         monitor — report through here so aggregates and spans cannot
         drift). `t_start` is the perf_counter start for span placement;
-        None means "ends now"."""
+        None means "ends now". `args` go on the span (the trainer's
+        `step`)."""
         self.times[name] = self.times.get(name, 0.0) + dt
         self.counts[name] = self.counts.get(name, 0) + 1
         if t_start is None:
             t_start = time.perf_counter() - dt
-        _spans.record(name, t_start, dt, cat="tracer")
+        _spans.record(name, t_start, dt, cat="tracer", **args)
 
     @contextlib.contextmanager
-    def timer(self, name: str):
-        """reference: tracer.py:157-167 `tr.timer` contextmanager."""
+    def timer(self, name: str, step: Optional[int] = None):
+        """reference: tracer.py:157-167 `tr.timer` contextmanager. With a
+        `step` the region is a ``jax.profiler.StepTraceAnnotation``, so
+        XProf groups the device work under it by step number, and the
+        span carries `step`."""
         if not self.enabled:
             yield
             return
-        ctx = (jax.profiler.TraceAnnotation(name)
-               if self.use_jax_annotations else contextlib.nullcontext())
+        if not self.use_jax_annotations:
+            ctx = contextlib.nullcontext()
+        elif step is None:
+            ctx = jax.profiler.TraceAnnotation(name)
+        else:
+            ctx = jax.profiler.StepTraceAnnotation(name, step_num=step)
+        args = {} if step is None else {"step": step}
         with ctx:
-            self.start(name)
+            t0 = time.perf_counter()
             try:
                 yield
             finally:
-                self.stop(name)
+                self.add_time(name, time.perf_counter() - t0, t_start=t0,
+                              **args)
 
     def profile(self, name: Optional[str] = None):
         """reference: tracer.py:145-155 `@tr.profile` decorator."""
@@ -132,7 +143,15 @@ class HostStallMonitor:
 
     def __init__(self, tracer: Optional[Tracer] = None):
         self.tracer = tracer
+        # the optimizer step the trainer is at (it sets this): the
+        # `dataload_wait` before step k and its `step_dispatch` carry k
+        self.step: Optional[int] = None
         self.reset()
+
+    def span_args(self) -> Dict[str, int]:
+        """What the step-level spans carry: `step`, once the trainer has
+        set it."""
+        return {} if self.step is None else {"step": self.step}
 
     def reset(self):
         self.wait_s = 0.0
@@ -151,7 +170,8 @@ class HostStallMonitor:
                 dt = time.perf_counter() - t0
                 self.wait_s += dt
                 if self.tracer is not None:
-                    self.tracer.add_time("dataload_wait", dt, t_start=t0)
+                    self.tracer.add_time("dataload_wait", dt, t_start=t0,
+                                         **self.span_args())
             self.batches += 1
             yield batch
 
@@ -164,7 +184,8 @@ class HostStallMonitor:
             dt = time.perf_counter() - t0
             self.step_s += dt
             if self.tracer is not None:
-                self.tracer.add_time("step_dispatch", dt, t_start=t0)
+                self.tracer.add_time("step_dispatch", dt, t_start=t0,
+                                     **self.span_args())
 
     def input_bound_frac(self) -> float:
         total = self.wait_s + self.step_s

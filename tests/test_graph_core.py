@@ -149,24 +149,6 @@ class TestRadiusGraph:
         assert set(zip(s1.tolist(), r1.tolist())) == set(zip(s2.tolist(), r2.tolist()))
 
 
-def test_timer_aggregation():
-    """Timer accumulates per-name min/max/avg (reference: time_utils.py)."""
-    import time as _time
-    from hydragnn_tpu.utils.time_utils import Timer, print_timers, reset_timers
-    reset_timers()
-    t = Timer("unit")
-    for _ in range(3):
-        t.start()
-        _time.sleep(0.01)
-        t.stop()
-    assert Timer.number_calls["unit"] >= 3
-    assert Timer.timers_local["unit"] >= 0.03
-    assert Timer.timers_min["unit"] <= Timer.timers_max["unit"] + 1e-9
-    out = print_timers()
-    assert "unit" in out
-    reset_timers()
-
-
 def test_descriptor_transforms():
     """Spherical + PointPair descriptors append edge columns and are
     rotation-equivariant/invariant as appropriate."""

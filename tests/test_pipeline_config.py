@@ -560,9 +560,9 @@ def test_pipeline_validation_new_errors():
 def test_pipeline_telemetry_bubble_metrics(tmp_path):
     """Satellite: pipelined runs report through the PR 7 telemetry layer
     — the closed-form bubble_frac gauge, pipeline fields in the epoch
-    JSONL (data bucket: deterministic), and per-stage idle spans (the
-    schedule-model overlay, cat "pipeline-model") land in the run
-    artifacts every epoch, not just under BENCH_MFU."""
+    JSONL (data bucket: deterministic) land in the run artifacts every
+    epoch, not just under BENCH_MFU. (The formula-made `pipe.stage_idle`
+    spans went in PR 26: a span recorder holds measurements.)"""
     import json as _json
     cfg = _cfg(2)
     cfg["NeuralNetwork"]["Training"]["num_epoch"] = 1  # one epoch pins
@@ -591,13 +591,6 @@ def test_pipeline_telemetry_bubble_metrics(tmp_path):
     assert "hydragnn_pipeline_bubble_frac" in prom
     assert "hydragnn_pipeline_train_bubble_frac" in prom
     assert "hydragnn_train_achieved_flops_per_s" not in prom
-    trace = _json.load(open(tel_dir + "/trace.json"))
-    idles = [ev for ev in trace["traceEvents"]
-             if ev.get("name") == "pipe.stage_idle"]
-    # one span per stage per epoch, tagged with its schedule-model args
-    assert len(idles) == 2
-    assert all(ev["cat"] == "pipeline-model" for ev in idles)
-    assert {ev["args"]["stage"] for ev in idles} == {0, 1}
 
 
 @pytest.mark.slow

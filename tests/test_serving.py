@@ -29,6 +29,7 @@ from hydragnn_tpu.serving.engine import (InferenceEngine, _Request,
                                          bucket_ladder, select_bucket)
 
 from tests.deterministic_data import deterministic_graph_dataset
+from tests.test_serving_faults import _BlockedDispatcher
 from tests.utils import make_config
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -579,6 +580,250 @@ def test_structure_session_rejects_rotational_invariance(structured):
             eng.structure_session()
     finally:
         eng.shutdown()
+
+
+@pytest.fixture(scope="module")
+def served_spans(structured):
+    """The spans of 12 raw-structure requests, sent from three threads to
+    an engine that coalesces (4 a batch, 20 ms of company), with what the
+    engine reports of them."""
+    import threading
+    from hydragnn_tpu.telemetry import spans as tspans
+    structures, samples, cfg, base = structured
+    eng = InferenceEngine(base._model, base._variables, base.mcfg,
+                          reference_samples=samples, max_batch_size=4,
+                          max_wait_ms=20.0, structure_config=cfg)
+    eng.warmup()
+    rec = tspans.SpanRecorder("test")
+    previous = tspans.install_recorder(rec)
+    try:
+        def client(k):
+            for pos, nfm, _ in structures[k::3][:4]:
+                eng.submit_structure(pos, nfm).result(timeout=60)
+                time.sleep(0.003)
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        time.sleep(0.05)      # the dispatcher is back in await_request
+        stats = eng.stats()
+    finally:
+        tspans.install_recorder(previous)
+        eng.shutdown()
+    events = [e for e in rec.chrome_trace()["traceEvents"]
+              if e.get("ph") == "X"]
+    return events, stats, eng._dispatcher.ident
+
+
+def _inside(inner, outer, slack_us=50.0):
+    return (outer["ts"] - slack_us <= inner["ts"]
+            and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + slack_us)
+
+
+def test_request_span_contains_its_children(served_spans):
+    """`serve.request` runs from ARRIVAL to the result being set: it
+    contains its `serve.graph_build`, its `serve.queue_wait` and its
+    batch's `serve.fetch`, all found through one `req`."""
+    events, _, _ = served_spans
+    by_name = {}
+    for e in events:
+        by_name.setdefault(e["name"], []).append(e)
+    requests = by_name["serve.request"]
+    assert len(requests) == 12
+    assert sorted(e["args"]["req"] for e in requests) == sorted(
+        set(e["args"]["req"] for e in requests)), "req is unique"
+    batches = {e["args"]["batch"]: e for e in by_name["serve.batch"]}
+    assert sorted(r for b in batches.values()
+                  for r in b["args"]["reqs"]) == sorted(
+        e["args"]["req"] for e in requests)
+    for request in requests:
+        req, batch = request["args"]["req"], request["args"]["batch"]
+        assert req in batches[batch]["args"]["reqs"]
+        for child in ("serve.graph_build", "serve.queue_wait"):
+            (span,) = [e for e in by_name[child]
+                       if e["args"]["req"] == req]
+            assert span["args"]["parent"] == "serve.request"
+            assert _inside(span, request), (child, span, request)
+        for child in ("serve.collate", "serve.dispatch", "serve.fetch",
+                      "serve.unpad", "serve.forward"):
+            (span,) = [e for e in by_name[child]
+                       if e["args"]["batch"] == batch]
+            assert span["args"]["parent"] == "serve.batch"
+            assert _inside(span, batches[batch]), (child, span)
+            assert _inside(span, request), (child, span, request)
+        # the build comes first, then the wait, then the batch
+        (build,) = [e for e in by_name["serve.graph_build"]
+                    if e["args"]["req"] == req]
+        (wait,) = [e for e in by_name["serve.queue_wait"]
+                   if e["args"]["req"] == req]
+        assert build["ts"] + build["dur"] <= wait["ts"] + 50.0
+        assert wait["ts"] + wait["dur"] <= batches[batch]["ts"] + 50.0
+
+
+def test_engine_latency_runs_from_arrival(served_spans):
+    """`engine.stats()` (and so /metrics) counts the graph build: its p50
+    is no less than the build's median, and equals the median of the
+    `serve.request` spans."""
+    events, stats, _ = served_spans
+    build = np.median([e["dur"] for e in events
+                       if e["name"] == "serve.graph_build"]) * 1e-3
+    request = np.median([e["dur"] for e in events
+                         if e["name"] == "serve.request"]) * 1e-3
+    assert stats["count"] == 12
+    assert stats["p50_ms"] >= build
+    assert stats["p50_ms"] == pytest.approx(request, abs=0.2)
+    waits = [e["dur"] for e in events if e["name"] == "serve.queue_wait"]
+    requests = sorted(e["dur"] for e in events
+                      if e["name"] == "serve.request")
+    assert min(requests) > min(waits)
+
+
+def test_dispatcher_spans_do_not_overlap(served_spans):
+    """`serve.await_request`, `serve.coalesce_wait` and `serve.batch` are
+    one thread's time, end to end: no two overlap, so no share of a window
+    made of them can pass 100%."""
+    events, _, dispatcher = served_spans
+    mine = sorted((e for e in events if e["name"] in (
+        "serve.await_request", "serve.coalesce_wait", "serve.batch")),
+        key=lambda e: e["ts"])
+    assert {e["name"] for e in mine} == {
+        "serve.await_request", "serve.coalesce_wait", "serve.batch"}
+    assert {e["tid"] for e in mine} == {dispatcher}
+    for a, b in zip(mine, mine[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1.0, (a, b)
+    span = mine[-1]["ts"] + mine[-1]["dur"] - mine[0]["ts"]
+    assert sum(e["dur"] for e in mine) <= span + 1.0
+    # every coalesce names the request it held back
+    reqs = {e["args"]["req"] for e in events if e["name"] == "serve.request"}
+    assert {e["args"]["req"] for e in mine
+            if e["name"] == "serve.coalesce_wait"} <= reqs
+
+
+def _ends_shed_by_admission(eng, structures, park):
+    """Two requests fail with their batch, a third is shed at the door
+    (full queue) before its graph is built, a fourth after shutdown."""
+    from hydragnn_tpu.serving.engine import QueueFullError
+    (p0, n0, _), (p1, n1, _), (p2, n2, _) = structures[:3]
+    f0 = eng.submit_structure(p0, n0)
+    assert park.entered.wait(30)
+    f1 = eng.submit_structure(p1, n1)
+    with pytest.raises(QueueFullError):
+        eng.submit_structure(p2, n2)
+    park.release.set()
+    for f in (f0, f1):
+        assert f.exception(timeout=60) is not None
+    eng.shutdown()
+    with pytest.raises(RuntimeError, match="shut down"):
+        eng.submit_structure(p2, n2)
+    return {0: "InjectedFault", 1: "InjectedFault", 2: "QueueFullError",
+            3: "RuntimeError"}, {2, 3}
+
+
+def _ends_behind_the_breaker(eng, structures, park):
+    """The first batch fails and trips the breaker: the request queued
+    behind it is failed by the dispatcher, the next is shed at the door."""
+    from hydragnn_tpu.serving.engine import CircuitOpenError
+    (p0, n0, _), (p1, n1, _), (p2, n2, _) = structures[:3]
+    f0 = eng.submit_structure(p0, n0)
+    assert park.entered.wait(30)
+    f1 = eng.submit_structure(p1, n1)
+    park.release.set()
+    assert f0.exception(timeout=60) is not None
+    assert isinstance(f1.exception(timeout=60), CircuitOpenError)
+    with pytest.raises(CircuitOpenError):
+        eng.submit_structure(p2, n2)
+    return {0: "InjectedFault", 1: "CircuitOpenError",
+            2: "CircuitOpenError"}, {2}
+
+
+def _ends_expired_or_invalid(eng, structures, park):
+    """A deadline that lapses in the queue, a structure without features
+    (raised by `submit_structure`) and a sample of the wrong width
+    (resolved by `submit`)."""
+    from hydragnn_tpu.serving.engine import DeadlineExceededError
+    (p0, n0, _), (p1, n1, _) = structures[:2]
+    f0 = eng.submit_structure(p0, n0)
+    assert park.entered.wait(30)
+    f1 = eng.submit_structure(p1, n1, deadline_ms=1.0)
+    time.sleep(0.05)
+    with pytest.raises(ValueError, match="node_features"):
+        eng.submit_structure(p0)
+    wide = copy.copy(eng._proto)
+    wide.x = np.zeros((wide.x.shape[0], wide.x.shape[1] + 1), np.float32)
+    f3 = eng.submit(wide)
+    assert isinstance(f3.exception(timeout=0), ValueError)
+    park.release.set()
+    assert f0.exception(timeout=60) is not None
+    assert isinstance(f1.exception(timeout=60), DeadlineExceededError)
+    return {0: "InjectedFault", 1: "DeadlineExceededError",
+            2: "ValueError", 3: "ValueError"}, {2, 3}
+
+
+def _ends_drained_after_the_dispatcher_died(eng, structures, park):
+    """The dispatcher dies inside a batch (whose own request is lost with
+    it): what is still queued is drained with the fatal error."""
+    (p0, n0, _), (p1, n1, _) = structures[:2]
+    eng.submit_structure(p0, n0)
+    assert park.entered.wait(30)
+    f1 = eng.submit_structure(p1, n1)
+    park.release.set()
+    assert isinstance(f1.exception(timeout=60), MemoryError)
+    return {1: "MemoryError"}, set()
+
+
+@pytest.mark.parametrize("scenario,engine_kw,dies", [
+    (_ends_shed_by_admission, {"max_queue": 1, "breaker_threshold": 0},
+     None),
+    (_ends_behind_the_breaker,
+     {"breaker_threshold": 1, "breaker_reset_s": 30.0}, None),
+    (_ends_expired_or_invalid, {"breaker_threshold": 0}, None),
+    (_ends_drained_after_the_dispatcher_died, {}, MemoryError("died")),
+], ids=["admission", "breaker", "deadline_invalid", "drain"])
+def test_a_request_that_ends_in_an_error_leaves_its_request_span(
+        structured, scenario, engine_kw, dies):
+    """Shed load is what an operator traces: whichever way a request ends
+    (rejected at the door by `submit_structure` or `submit`, failed with
+    its batch, expired, caught behind the open breaker, drained), it
+    leaves exactly one `serve.request`, with the error's class. Every
+    executed batch is made to fail before it compiles anything."""
+    from hydragnn_tpu.telemetry import spans as tspans
+    from hydragnn_tpu.utils.faults import (install_fault_plan,
+                                           parse_fault_plan)
+    structures, samples, cfg, base = structured
+    eng = InferenceEngine(base._model, base._variables, base.mcfg,
+                          reference_samples=samples, max_batch_size=1,
+                          max_wait_ms=0.0, structure_config=cfg,
+                          **engine_kw)
+    park = _BlockedDispatcher(eng, then=dies)
+    rec = tspans.SpanRecorder("test")
+    previous = tspans.install_recorder(rec)
+    install_fault_plan(parse_fault_plan("serving-dispatch@0,1,2,3"))
+    try:
+        expected, unbuilt = scenario(eng, structures, park)
+    finally:
+        park.release.set()
+        eng.shutdown()
+        install_fault_plan(None)
+        tspans.install_recorder(previous)
+    events = [e for e in rec.chrome_trace()["traceEvents"]
+              if e.get("ph") == "X"]
+    ended = {}
+    for e in events:
+        if e["name"] == "serve.request":
+            assert e["args"]["req"] not in ended, "one span a request"
+            ended[e["args"]["req"]] = e["args"].get("error")
+    assert ended == expected
+    # no span names a request that left no `serve.request` (the one the
+    # dying dispatcher took with it apart)
+    named = {e["args"]["req"] for e in events
+             if isinstance(e.get("args", {}).get("req"), int)}
+    assert named - set(ended) <= ({0} if dies else set())
+    # a request shed at the door never built its graph
+    assert not unbuilt & {e["args"]["req"] for e in events
+                          if e["name"] == "serve.graph_build"}
 
 
 def test_structure_counters_health_metrics_registry(structured):

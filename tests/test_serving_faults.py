@@ -55,9 +55,11 @@ def _engine(served, **kw):
 
 class _BlockedDispatcher:
     """Deterministically park the dispatcher inside its first _execute so
-    tests can fill/expire the queue without racing the batch loop."""
+    tests can fill/expire the queue without racing the batch loop.
+    `then`: an exception the released dispatcher dies of instead of
+    executing."""
 
-    def __init__(self, eng):
+    def __init__(self, eng, then=None):
         self.entered = threading.Event()
         self.release = threading.Event()
         self._orig = eng._execute
@@ -65,6 +67,8 @@ class _BlockedDispatcher:
         def blocked(shards):
             self.entered.set()
             assert self.release.wait(30)
+            if then is not None:
+                raise then
             return self._orig(shards)
 
         eng._execute = blocked
