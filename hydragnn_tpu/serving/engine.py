@@ -94,16 +94,31 @@ recorder installed every request (``req``) and every executed batch
 ``serve.collate``, ``serve.dispatch``, ``serve.fetch`` and
 ``serve.unpad``; and the dispatcher's own ``serve.await_request`` and
 ``serve.coalesce_wait``.
+
+The dispatch pipeline (docs/serving.md "Dispatch pipeline"): the call of
+a compiled program returns as soon as its work is enqueued, and the
+dispatcher keeps that. A dispatched batch's completion (fetch, unpad,
+statistics, ``set_result``) is OWED, not done at once: while a batch's
+worth of requests is queued (``max_batch_size``: the next batch fills
+without the wait for company) the dispatcher coalesces, collates and
+dispatches batch n+1 first, then completes batch n, so the device starts
+n+1 the moment n ends. At most ``inflight_depth`` batches are owed: 2
+where two executions of the largest bucket's program fit the device
+memory that is free when that program is compiled, else 1 (the serial
+path); 1 too while the breaker is not closed, so a half-open probe is
+alone in flight. With a shorter queue a batch is completed as soon as it
+is dispatched: the batches formed are the serial path's, always.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import queue
 import threading
 import time
 from concurrent.futures import Future
-from typing import Any, Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Deque, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -245,6 +260,45 @@ class _Request:
         # absolute expiry on the same clock as t_submit; None/0 = none
         self.deadline = (self.t_submit + float(deadline_ms) / 1e3
                          if deadline_ms else None)
+
+
+class _Dispatched:
+    """One batch between its dispatch and its completion: what the
+    dispatcher still owes. `outs` are the program's outputs, still on the
+    device; `error` is set instead when the dispatch itself failed (the
+    batch's futures fail in its turn, so batches resolve in dispatch
+    order)."""
+    __slots__ = ("batch_id", "shards", "reqs", "bucket", "outs", "version",
+                 "overlapped", "rec", "t_disp", "t_fwd", "error")
+
+    def __init__(self, batch_id: int, shards: List[List[_Request]],
+                 reqs: List[_Request], overlapped: bool):
+        self.batch_id = batch_id
+        self.shards = shards
+        self.reqs = reqs
+        self.overlapped = overlapped
+        self.bucket = self.outs = self.version = self.error = None
+        self.rec = self.t_disp = self.t_fwd = None
+
+    def ready(self) -> bool:
+        """Whether completing this batch would not wait for the device."""
+        return self.error is not None or all(
+            o.is_ready() for o in self.outs)
+
+
+def _free_device_bytes(devices) -> Optional[int]:
+    """The least memory free on any of `devices`, by
+    `device.memory_stats()`; None where the backend keeps no such
+    statistics (the CPU: the host's memory is not the engine's to
+    ration)."""
+    free = None
+    for d in devices:
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        left = int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+        free = left if free is None else min(free, left)
+    return free
 
 
 class InferenceEngine:
@@ -553,6 +607,14 @@ class InferenceEngine:
         # carries the `req` or the `batch` it belongs to
         self._req_ids = itertools.count()
         self._batch_ids = itertools.count()
+        # the dispatch pipeline (module docstring): the batches
+        # dispatched and not yet completed, oldest first (the
+        # dispatcher's own: no other thread touches it), how many there
+        # may be, and how many were dispatched while another was owed
+        self._owed: Deque[_Dispatched] = collections.deque()
+        self.inflight_depth = 1  # guarded-by: _lock — resolved when the
+        # largest bucket's program is compiled (`_resolve_depth`)
+        self.batches_overlapped = 0  # guarded-by: _lock
         self._dispatcher = threading.Thread(target=self._loop,
                                             name="serve-dispatch",
                                             daemon=True)
@@ -956,8 +1018,8 @@ class InferenceEngine:
         if bucket is None:
             bucket = select_bucket(self.buckets, 1, req.n, req.e)
         shards = [[req]] + [[] for _ in range(self.num_shards - 1)]
-        outs, _ = self._forward_requests(shards, bucket, None)
-        return self._unpad(shards, bucket, outs)[0]
+        outs, _ = self._enqueue(shards, bucket, None)
+        return self._unpad(shards, bucket, self._fetch(outs, None))[0]
 
     def warmup(self) -> int:
         """Precompile every bucket (and for `num_shards > 1` the stacked
@@ -1023,6 +1085,7 @@ class InferenceEngine:
         with self._lock:
             self.requests_done = 0
             self.batches_run = 0
+            self.batches_overlapped = 0
             self._occupancy_sum = 0.0
             self._real_node_slots = 0
             self._total_node_slots = 0
@@ -1053,6 +1116,10 @@ class InferenceEngine:
             out = {
                 "requests": self.requests_done,
                 "batches": self.batches_run,
+                # the dispatch pipeline: batches dispatched while another
+                # was still owed its completion, and how many may be
+                "batches_overlapped": self.batches_overlapped,
+                "inflight_depth": self.inflight_depth,
                 "batch_occupancy": (self._occupancy_sum / self.batches_run
                                     if self.batches_run else 0.0),
                 "padding_frac_nodes": (
@@ -1204,6 +1271,8 @@ class InferenceEngine:
                                                proto_batch).compile()
             if self._compile_store is not None:
                 self._compile_store.save(self._store_key(bucket), compiled)
+        depth = (self._resolve_depth(compiled)
+                 if bucket == self.buckets[-1] else None)
         with self._lock:
             hit = self._compiled.setdefault(bucket, compiled)
             if hit is compiled:
@@ -1212,15 +1281,38 @@ class InferenceEngine:
                     self.compile_store_hits += 1
                 else:
                     self.compile_fresh += 1
+                if depth is not None:
+                    self.inflight_depth = depth
         return hit
 
-    def _forward_requests(self, shards: List[List[_Request]],
-                          bucket: PackBudget, batch_id: Optional[int]
-                          ) -> Tuple[List[np.ndarray], str]:
-        """Collate, call the compiled program, fetch: three spans of the
-        batch `batch_id` (None: `forward_single`, no `serve.batch`), so
-        what the host does while the device is idle (collate) and while
-        it waits for the device (fetch) can be told apart."""
+    def _resolve_depth(self, compiled) -> int:
+        """How many batches may be in flight: a runtime may give every
+        enqueued execution its own temporaries, so 2 only where two
+        executions of the LARGEST bucket's program (arguments, the
+        weights among them, outputs and temporaries, by
+        `memory_analysis()`) fit what the engine's devices report free
+        now that the program is compiled; 1 where they do not, or where
+        the executable does not say what it needs. (The v5e's runtime
+        keeps ONE scratch reservation for its loaded programs, which the
+        executions in flight share: there the second batch costs its
+        inputs and outputs only, and the peak stays where it was, 6.06
+        GB in PR 27's runs. The bound is for the runtime that does not.)"""
+        try:
+            need = compiled.memory_analysis()
+            one = (need.argument_size_in_bytes + need.output_size_in_bytes
+                   + need.temp_size_in_bytes)
+            free = _free_device_bytes(self.devices)
+        except Exception:  # noqa: BLE001 — no sizes: stay serial
+            return 1
+        return 2 if free is None or 2 * one <= free else 1
+
+    def _enqueue(self, shards: List[List[_Request]], bucket: PackBudget,
+                 batch_id: Optional[int]) -> Tuple[List[Any], str]:
+        """Collate and call the compiled program: two spans of the batch
+        `batch_id` (None: `forward_single`, no `serve.batch`). The call
+        returns once the inputs' placement and the execution are
+        enqueued: the outputs that come back are still the device's to
+        fill in, and `_fetch` waits for them."""
         ids = {"batch": batch_id, "parent": "serve.batch"}
         with _spans.span("serve.collate", "serving", **ids):
             if self.num_shards > 1:
@@ -1240,9 +1332,16 @@ class InferenceEngine:
             with self._lock:
                 variables = self._variables
                 version = self.model_version
-            outs = compiled(variables, batch)
-        with _spans.span("serve.fetch", "serving", **ids):
-            return [np.asarray(o) for o in outs], version
+            return compiled(variables, batch), version
+
+    @staticmethod
+    def _fetch(outs: List[Any], batch_id: Optional[int]
+               ) -> List[np.ndarray]:
+        """The outputs as host arrays: waits for the device to finish
+        the batch (and whatever it had to run before it)."""
+        with _spans.span("serve.fetch", "serving", batch=batch_id,
+                         parent="serve.batch"):
+            return [np.asarray(o) for o in outs]
 
     def _unpad(self, shards: List[List[_Request]], bucket: PackBudget,
                outs: List[np.ndarray]) -> List[List[np.ndarray]]:
@@ -1304,10 +1403,17 @@ class InferenceEngine:
 
     def _record_batch_success(self) -> None:
         with self._lock:
+            if self._breaker_state == "open":
+                # this batch was already dispatched when the one before
+                # it failed and tripped the breaker: the trip stands, and
+                # the probe decides
+                return
             self._consec_failures = 0
             self._breaker_state = "closed"
 
     def _execute(self, shards: List[List[_Request]]):
+        """Dispatch one coalesced batch; its completion (`_complete`) is
+        owed (`_owed`), and `_settle` decides when it is paid."""
         # deadline sweep at dispatch time: requests that expired while
         # coalescing/queueing resolve with DeadlineExceededError and never
         # occupy a batch slot (their FLOPs would be pure waste — nobody is
@@ -1332,6 +1438,8 @@ class InferenceEngine:
                     self._breaker_state = "open"
             return
         batch_id = next(self._batch_ids)
+        flight = _Dispatched(batch_id, shards, reqs,
+                             overlapped=bool(self._owed))
         try:
             # deterministic batch-failure injection; counted per executed
             # batch (utils/faults.py serving-dispatch site)
@@ -1346,82 +1454,125 @@ class InferenceEngine:
                     f"({count} graphs, {need_n} nodes, {need_e} edges) "
                     "fits no bucket — the coalescer's fill caps must "
                     "bound every batch by the largest bucket")
+            flight.bucket = bucket
             # request-lifecycle spans (docs/observability.md): queue-wait
-            # per request (submit -> dispatch); then the batch: collate,
-            # dispatch and fetch (inside `_forward_requests`; the older
-            # `serve.forward` spans the three) and unpad, all children of
-            # `serve.batch`, which lists its requests and carries the
+            # per request (submit -> dispatch); then the batch: collate
+            # and dispatch here, fetch and unpad at completion (the
+            # older `serve.forward` spans collate to fetch), all children
+            # of `serve.batch`, which lists its requests and carries the
             # bucket/parity breadcrumbs the futures advertise. One
             # recorder check keeps the disabled path at a single branch
             # per batch.
-            rec = _spans.current_recorder()
+            flight.rec = rec = _spans.current_recorder()
             if rec is not None:
-                t_disp = _spans.now()
+                flight.t_disp = _spans.now()
                 for r in reqs:
                     rec.add("serve.queue_wait", r.t_submit,
-                            t_disp - r.t_submit, "serving",
+                            flight.t_disp - r.t_submit, "serving",
                             {"req": r.req, "batch": batch_id,
                              "parent": "serve.request"})
-                t_fwd = _spans.now()
-            outs, version = self._forward_requests(shards, bucket,
-                                                   batch_id)
-            if rec is not None:
-                rec.add("serve.forward", t_fwd, _spans.now() - t_fwd,
-                        "serving",
-                        {"batch": batch_id, "parent": "serve.batch",
-                         "bucket": [bucket.n_node, bucket.n_edge,
-                                    bucket.n_graph],
-                         "requests": len(reqs), "parity": self.parity})
-            with _spans.span("serve.unpad", "serving", batch=batch_id,
-                             parent="serve.batch"):
-                results = self._unpad(shards, bucket, outs)
-            done = time.perf_counter()
-            tot_n = sum(r.n for r in reqs)
-            tot_e = sum(r.e for r in reqs)
-            with self._lock:
-                self.batches_run += 1
-                self.requests_done += len(reqs)
-                self._occupancy_sum += len(reqs) / (bucket.cap_graphs *
-                                                    self.num_shards)
-                self._real_node_slots += tot_n
-                self._real_edge_slots += tot_e
-                self._total_node_slots += bucket.n_node * self.num_shards
-                self._total_edge_slots += bucket.n_edge * self.num_shards
-                self._latencies.extend(done - r.t_arrival for r in reqs)
-            for req, res in zip(reqs, results):
-                req.future.bucket = bucket  # adjudication breadcrumbs: the
-                req.future.parity = self.parity       # bucket this batch
-                req.future.parity_rtol = self.parity_rtol  # ran on + the
-                req.future.parity_atol = self.parity_atol  # parity bound
-                req.future.model_version = version  # + the hot-swap tag:
-                # which weights actually served this request
-                req.future.tier = self.tier  # + the fleet tier that
-                # served it (int8 fast vs fp32 accurate; serving/fleet.py)
-                req.future.set_result(res)
-                if rec is not None:
-                    # the request ends with ITS OWN result, not with the
-                    # last of the batch's
-                    self._span_request(req.req, req.t_arrival,
-                                       batch=batch_id)
-            if rec is not None:
-                rec.add("serve.batch", t_disp, _spans.now() - t_disp,
-                        "serving",
-                        {"batch": batch_id, "reqs": [r.req for r in reqs],
-                         "bucket": [bucket.n_node, bucket.n_edge,
-                                    bucket.n_graph]})
+                flight.t_fwd = _spans.now()
+            flight.outs, flight.version = self._enqueue(shards, bucket,
+                                                        batch_id)
         except BaseException as e:  # noqa: BLE001 — must reach the callers
-            # dispatcher supervision: a failed batch resolves only ITS OWN
-            # futures; the dispatcher survives and the breaker decides
-            # whether to keep admitting
+            flight.error = e
+        self._owed.append(flight)
+
+    def _complete(self) -> None:
+        """Pay the oldest owed batch what it is owed: fetch (the wait for
+        the device), unpad, statistics, every future's result, the
+        batch's spans, the breaker's accounting. A batch that failed, at
+        its dispatch or here, fails only ITS OWN futures; the dispatcher
+        survives and the breaker decides whether to keep admitting."""
+        flight = self._owed[0]
+        try:
+            self._deliver(flight)
+        except BaseException as e:  # noqa: BLE001 — must reach the callers
             self._record_batch_failure()
-            for req in reqs:
-                if not req.future.done():
-                    req.future.set_exception(e)
-                    self._span_request(req.req, req.t_arrival,
-                                       batch=batch_id,
-                                       error=type(e).__name__)
+            self._fail_batch(flight, e)
         else:
             self._record_batch_success()
+        finally:
+            self._owed.popleft()
+
+    def _fail_batch(self, flight: _Dispatched, error: BaseException
+                    ) -> None:
+        for req in flight.reqs:
+            if not req.future.done():
+                req.future.set_exception(error)
+                self._span_request(req.req, req.t_arrival,
+                                   batch=flight.batch_id,
+                                   error=type(error).__name__)
+
+    def _deliver(self, flight: _Dispatched) -> None:
+        if flight.error is not None:
+            raise flight.error
+        batch_id, shards, reqs = flight.batch_id, flight.shards, flight.reqs
+        bucket, rec = flight.bucket, flight.rec
+        outs = self._fetch(flight.outs, batch_id)
+        # deterministic failure of a batch that WAS dispatched, where the
+        # device's own error would surface (utils/faults.py serving-fetch
+        # site), once per fetched batch
+        fault_point("serving-fetch")
+        if rec is not None:
+            rec.add("serve.forward", flight.t_fwd,
+                    _spans.now() - flight.t_fwd, "serving",
+                    {"batch": batch_id, "parent": "serve.batch",
+                     "bucket": [bucket.n_node, bucket.n_edge,
+                                bucket.n_graph],
+                     "requests": len(reqs), "parity": self.parity})
+        with _spans.span("serve.unpad", "serving", batch=batch_id,
+                         parent="serve.batch"):
+            results = self._unpad(shards, bucket, outs)
+        done = time.perf_counter()
+        tot_n = sum(r.n for r in reqs)
+        tot_e = sum(r.e for r in reqs)
+        with self._lock:
+            self.batches_run += 1
+            self.batches_overlapped += flight.overlapped
+            self.requests_done += len(reqs)
+            self._occupancy_sum += len(reqs) / (bucket.cap_graphs *
+                                                self.num_shards)
+            self._real_node_slots += tot_n
+            self._real_edge_slots += tot_e
+            self._total_node_slots += bucket.n_node * self.num_shards
+            self._total_edge_slots += bucket.n_edge * self.num_shards
+            self._latencies.extend(done - r.t_arrival for r in reqs)
+        for req, res in zip(reqs, results):
+            req.future.bucket = bucket  # adjudication breadcrumbs: the
+            req.future.parity = self.parity       # bucket this batch
+            req.future.parity_rtol = self.parity_rtol  # ran on + the
+            req.future.parity_atol = self.parity_atol  # parity bound
+            req.future.model_version = flight.version  # + the hot-swap
+            # tag: which weights actually served this request
+            req.future.tier = self.tier  # + the fleet tier that
+            # served it (int8 fast vs fp32 accurate; serving/fleet.py)
+            req.future.set_result(res)
+            if rec is not None:
+                # the request ends with ITS OWN result, not with the
+                # last of the batch's
+                self._span_request(req.req, req.t_arrival, batch=batch_id)
+        if rec is not None:
+            rec.add("serve.batch", flight.t_disp,
+                    _spans.now() - flight.t_disp, "serving",
+                    {"batch": batch_id, "reqs": [r.req for r in reqs],
+                     "bucket": [bucket.n_node, bucket.n_edge,
+                                bucket.n_graph]})
+
+    def _settle(self) -> None:
+        """Before the dispatcher looks at the queue again: complete every
+        owed batch that can be read without waiting (an answer is never
+        held for the next batch's sake), and the oldest ones while as
+        many are owed as may be: `inflight_depth`, and 1 while the
+        breaker is not closed, so that a half-open probe is alone in
+        flight and the request after it meets the breaker it left."""
+        while self._owed:
+            with self._lock:
+                depth = (self.inflight_depth
+                         if self._breaker_state == "closed" else 1)
+            if len(self._owed) < depth and not self._owed[0].ready():
+                return
+            self._complete()
 
     def _coalesce(self, first: _Request, wait: bool = True):
         """Greedy arrival-order coalescing into per-shard bins: the
@@ -1508,17 +1659,29 @@ class InferenceEngine:
         pending = None
         try:
             while True:
-                if pending is None:
+                self._settle()
+                if self._owed and (self._queue.qsize() + (pending is not None)
+                                   < self.max_batch_size):
+                    # a batch is in flight and the queue would not fill
+                    # the next one without the wait for company: that
+                    # wait must not sit between this batch's device work
+                    # and its futures, nor be cut short (the batches are
+                    # the ones the serial path forms), so complete first
+                    self._complete()
+                    continue
+                if pending is not None:
+                    req, pending = pending, None
+                elif self._owed:
+                    req = self._queue.get()  # a batch's worth is queued
+                else:
                     # queue empty: the dispatcher waits for a request
                     with _spans.span("serve.await_request", "serving"):
                         req = self._queue.get()
-                else:
-                    req, pending = pending, None
                 if req is _SHUTDOWN:
                     break
                 if self._fast_fail(req):
                     continue
-                shards, pending = self._coalesce(req)
+                shards, pending = self._coalesce(req, wait=not self._owed)
                 self._execute(shards)
                 if pending is _SHUTDOWN:
                     break
@@ -1526,12 +1689,18 @@ class InferenceEngine:
             with self._lock:  # submit() reads _fatal under the lock
                 self._fatal = e
         finally:
-            # drain everything still queued — a shutdown (or dispatcher
-            # crash) must never leave a caller's future hanging. _fatal
-            # is snapshotted under the lock once: only this thread ever
-            # writes it, and the write (if any) happened above
+            # complete what is owed and drain everything still queued — a
+            # shutdown (or dispatcher crash) must never leave a caller's
+            # future hanging. _fatal is snapshotted under the lock once:
+            # only this thread ever writes it, and the write (if any)
+            # happened above
             with self._lock:
                 fatal = self._fatal
+            while self._owed:
+                if fatal is not None:
+                    self._fail_batch(self._owed.popleft(), fatal)
+                else:
+                    self._complete()
             while True:
                 try:
                     req = self._queue.get_nowait()
@@ -1547,6 +1716,8 @@ class InferenceEngine:
                 else:
                     shards, leftover = self._coalesce(req, wait=False)
                     self._execute(shards)
+                    while self._owed:
+                        self._complete()
                     if leftover is not None and leftover is not _SHUTDOWN:
                         self._queue.put(leftover)
 

@@ -414,6 +414,8 @@ class ReplicaRouter:
         out["requests"] = sum(st["requests"]
                               for st in per_replica.values())
         out["batches"] = sum(st["batches"] for st in per_replica.values())
+        out["batches_overlapped"] = sum(st["batches_overlapped"]
+                                        for st in per_replica.values())
         out.update(latency_percentiles(latencies))
         return out
 

@@ -45,6 +45,8 @@ def engine_prometheus(engine, registry: Optional[MetricsRegistry] = None
          "requests resolved by the dispatcher"),
         ("serving_batches_total", stats["batches"],
          "coalesced batches executed"),
+        ("serving_batches_overlapped_total", stats["batches_overlapped"],
+         "batches dispatched while an earlier one was still in flight"),
         ("serving_batch_failures_total", stats["batch_failures"],
          "batches whose forward raised"),
         ("serving_deadline_expired_total", stats["deadline_expired"],
@@ -77,6 +79,8 @@ def engine_prometheus(engine, registry: Optional[MetricsRegistry] = None
          "fraction of executed node slots that were padding"),
         ("serving_padding_frac_edges", stats["padding_frac_edges"],
          "fraction of executed edge slots that were padding"),
+        ("serving_inflight_depth", stats["inflight_depth"],
+         "batches that may be dispatched and not completed (1 or 2)"),
         ("serving_queue_depth", health["queue_depth"],
          "requests currently queued"),
         ("serving_max_queue_depth", stats["max_queue_depth"],

@@ -15,7 +15,7 @@ Plan grammar (``HYDRAGNN_FAULT_PLAN`` env / ``Training.fault_plan``)::
     plan  := entry (';' entry)*
     entry := site '@' index (',' index)*
     site  := checkpoint-write | loader-fetch | forward-step
-             | serving-dispatch | replica-kill | swap-fail
+             | serving-dispatch | serving-fetch | replica-kill | swap-fail
              | trial-kill | trial-hang | trial-spawn-fail
              | rank-kill | rank-hang | rank-spawn-fail
     index := non-negative int — the 0-based invocation count of that site
@@ -41,9 +41,14 @@ import threading
 from typing import Dict, FrozenSet, List, Optional, Tuple
 
 SITES = ("checkpoint-write", "loader-fetch", "forward-step",
-         "serving-dispatch", "replica-kill", "swap-fail",
+         "serving-dispatch", "serving-fetch", "replica-kill", "swap-fail",
          "trial-kill", "trial-hang", "trial-spawn-fail",
          "rank-kill", "rank-hang", "rank-spawn-fail")
+# ``serving-dispatch`` fires on the engine's dispatcher thread, once per
+# batch, before the batch is collated; ``serving-fetch`` there too, once
+# per batch that WAS dispatched, as its outputs are read (where a
+# device's own error surfaces): the failure of a batch that another may
+# already be running behind.
 # Fleet-level sites (docs/fault_tolerance.md, serving/fleet.py):
 # ``replica-kill`` fires once per ReplicaRouter dispatch and abruptly
 # kills the replica the router selected for that request (its in-flight
@@ -94,7 +99,8 @@ class FaultPlan:
     ``fault_point(site)`` increments the site's counter and raises when the
     current index is listed. Counters are per-plan (installing a plan
     resets them) and thread-safe — loader-fetch fires on collation worker
-    threads, serving-dispatch on the dispatcher thread."""
+    threads, serving-dispatch and serving-fetch on the dispatcher
+    thread."""
 
     injections: Dict[str, FrozenSet[int]]
 

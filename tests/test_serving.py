@@ -29,7 +29,8 @@ from hydragnn_tpu.serving.engine import (InferenceEngine, _Request,
                                          bucket_ladder, select_bucket)
 
 from tests.deterministic_data import deterministic_graph_dataset
-from tests.test_serving_faults import _BlockedDispatcher
+from tests.test_serving_faults import (_BlockedDispatcher, _ParkedFetch,
+                                       _queued_behind_a_parked_dispatcher)
 from tests.utils import make_config
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -208,6 +209,203 @@ def test_max_wait_flushes_partial_batch(served):
         eng.shutdown()
 
 
+# --------------------------------------------------------- dispatch pipeline
+
+def _pipelined(served, **kw):
+    samples, _, mcfg, model, variables = served
+    kw.setdefault("max_batch_size", 2)
+    kw.setdefault("max_wait_ms", 500.0)
+    eng = InferenceEngine(model, variables, mcfg,
+                          reference_samples=samples, neighbor_format=True,
+                          **kw)
+    eng.warmup()
+    return eng
+
+
+def _assert_bitwise(eng, sample, fut):
+    got = fut.result(timeout=60)
+    ref = eng.forward_single(sample, bucket=fut.bucket)
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_next_batch_is_dispatched_while_the_last_one_runs(served):
+    """With a queue longer than one bucket, batch n+1 is collated and
+    dispatched before batch n's futures resolve, and `batches_overlapped`
+    counts it; the answers are the serial path's, bit for bit."""
+    samples = served[0]
+    eng = _pipelined(served)
+    try:
+        assert eng.stats()["inflight_depth"] == 2
+        park = _ParkedFetch(eng)
+        futs = _queued_behind_a_parked_dispatcher(eng, samples[:6])
+        assert park.entered.wait(30), "batch 0 was never fetched"
+        # batch 0 is being fetched, and batch 1 is on the device behind it
+        assert park.await_inflight(2), "batch 1 waited for batch 0"
+        assert not any(f.done() for f in futs)
+        # never a third: the depth bounds what is enqueued on the device
+        assert eng._queue.qsize() == 2
+        park.release.set()
+        for s, f in zip(samples, futs):
+            _assert_bitwise(eng, s, f)
+        st = eng.stats()
+        assert st["batches"] == 3 and st["requests"] == 6
+        # batch 1 left behind batch 0; once the device was done batch 1
+        # could be read, so it was answered BEFORE batch 2 was prepared
+        assert st["batches_overlapped"] == 1
+        eng.reset_stats()
+        assert eng.stats()["batches_overlapped"] == 0
+        assert eng.stats()["inflight_depth"] == 2
+    finally:
+        eng.shutdown()
+
+
+def test_lone_request_is_answered_without_a_successor(served):
+    """With less than a batch's worth queued nothing overlaps: a lone
+    request is dispatched after its own window and answered at once; a
+    full batch's answers never wait for the window of the request behind
+    it, and that window is not cut short either."""
+    samples = served[0]
+    eng = _pipelined(served, max_wait_ms=1000.0)
+    try:
+        t0 = time.perf_counter()
+        lone = eng.submit(samples[0])
+        _assert_bitwise(eng, samples[0], lone)
+        assert time.perf_counter() - t0 >= 0.9, "its own window"
+        st = eng.stats()
+        assert st["batches"] == 1 and st["batches_overlapped"] == 0
+        t0 = time.perf_counter()
+        full = [eng.submit(s) for s in samples[1:3]]   # no window: full
+        behind = eng.submit(samples[3])     # not a batch's worth: it waits
+        for f in full:
+            f.result(timeout=60)
+        assert time.perf_counter() - t0 < 0.9
+        assert not behind.done(), "its window was cut short"
+        _assert_bitwise(eng, samples[3], behind)
+        assert time.perf_counter() - t0 >= 0.9
+        st = eng.stats()
+        assert st["batches"] == 3 and st["batches_overlapped"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_hot_swap_between_two_in_flight_batches(served):
+    """A swap that lands between two dispatches, both batches in flight:
+    each carries the version, and the answers, of the weights it ran
+    with."""
+    import jax
+    samples, _, _, _, variables = served
+    eng = _pipelined(served)
+    try:
+        old = [eng.forward_single(s, bucket=eng.buckets[-1])
+               for s in samples[:4]]
+        swapped = {"params": jax.tree_util.tree_map(
+            lambda a: a * 1.25, variables["params"]),
+            "batch_stats": variables.get("batch_stats", {})}
+        enqueue, swaps = eng._enqueue, []
+
+        def swap_after_the_first(shards, bucket, batch_id):
+            out = enqueue(shards, bucket, batch_id)
+            if batch_id is not None and not swaps:
+                swaps.append(eng.swap_variables(swapped, "v1"))
+            return out
+
+        eng._enqueue = swap_after_the_first
+        park = _ParkedFetch(eng)
+        futs = _queued_behind_a_parked_dispatcher(eng, samples[:4])
+        assert park.entered.wait(30) and park.await_inflight(2)
+        assert swaps == ["v0"]
+        park.release.set()
+        first, second = futs[:2], futs[2:]
+        for f in futs:
+            f.result(timeout=60)
+        assert [f.model_version for f in futs] == ["v0", "v0", "v1", "v1"]
+        for s, f in zip(samples[2:4], second):
+            _assert_bitwise(eng, s, f)     # the engine serves v1 now
+        # the swap moved the numbers, and back under the old weights the
+        # first batch's answers are the single-request ones
+        for f, before in zip(second, old[2:]):
+            assert any(not np.array_equal(np.asarray(a), np.asarray(b))
+                       for a, b in zip(f.result(), before))
+        eng.swap_variables(variables, "v0")
+        for s, f in zip(samples, first):
+            _assert_bitwise(eng, s, f)
+    finally:
+        eng.shutdown()
+
+
+def test_pipeline_stress_every_future_resolves_once(served):
+    """Sixteen clients against the dispatcher, with the interpreter
+    switching threads every 10 us: every future resolves once, to the
+    single-request answer; the counters add up and nothing is left in
+    flight."""
+    import threading
+    samples = served[0]
+    eng = _pipelined(served, max_batch_size=4, max_wait_ms=1.0)
+    resolved, mine = [], []
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def client(k):
+            for i in range(6):
+                s = samples[(k * 6 + i) % len(samples)]
+                f = eng.submit(s)
+                f.add_done_callback(lambda _f: resolved.append(1))
+                mine.append((s, f))
+
+        threads = [threading.Thread(target=client, args=(k,))
+                   for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        for _, f in mine:
+            f.result(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    try:
+        st = eng.stats()
+        assert len(mine) == 96 and st["requests"] == 96
+        assert len(resolved) == 96, "a callback ran twice, or never"
+        assert 0 <= st["batches_overlapped"] < st["batches"] <= 96
+        for s, f in mine[::7]:
+            _assert_bitwise(eng, s, f)
+    finally:
+        eng.shutdown()
+    assert not eng._owed
+
+
+@pytest.mark.parametrize("free,depth", [(1, 1), (1 << 60, 2), (None, 2)],
+                         ids=["no_room", "room", "no_statistics"])
+def test_inflight_depth_follows_free_device_memory(served, monkeypatch,
+                                                   free, depth):
+    """Depth 2 only where two executions of the largest bucket's program
+    fit the memory the device reports free; 1, the serial path, where they
+    do not: then batch 1 is not dispatched until batch 0 is answered."""
+    from hydragnn_tpu.serving import engine as engine_mod
+    monkeypatch.setattr(engine_mod, "_free_device_bytes",
+                        lambda devices: free)
+    samples, _, mcfg, model, variables = served
+    eng = InferenceEngine(model, variables, mcfg, reference_samples=samples,
+                          max_batch_size=2, max_wait_ms=500.0,
+                          neighbor_format=True)
+    try:
+        assert eng.stats()["inflight_depth"] == 1, "unresolved: serial"
+        eng.warmup()
+        assert eng.stats()["inflight_depth"] == depth
+        park = _ParkedFetch(eng)
+        futs = _queued_behind_a_parked_dispatcher(eng, samples[:4])
+        assert park.entered.wait(30) and park.await_inflight(depth)
+        assert eng._queue.qsize() == 2 * (2 - depth)
+        park.release.set()
+        for s, f in zip(samples, futs):
+            _assert_bitwise(eng, s, f)
+        assert eng.stats()["batches_overlapped"] == depth - 1
+    finally:
+        eng.shutdown()
+
+
 def test_occupancy_and_padding_stats(served, engine):
     engine.reset_stats()
     samples, _, _, _, _ = served
@@ -283,7 +481,7 @@ def test_execute_failure_propagates_not_hangs(served):
     try:
         def boom(*a, **kw):
             raise RuntimeError("injected forward failure")
-        eng._forward_requests = boom
+        eng._enqueue = boom
         futs = [eng.submit(s) for s in samples[:6]]
         for f in futs:
             with pytest.raises(RuntimeError, match="injected"):
@@ -682,16 +880,22 @@ def test_engine_latency_runs_from_arrival(served_spans):
 
 
 def test_dispatcher_spans_do_not_overlap(served_spans):
-    """`serve.await_request`, `serve.coalesce_wait` and `serve.batch` are
-    one thread's time, end to end: no two overlap, so no share of a window
-    made of them can pass 100%."""
-    events, _, dispatcher = served_spans
-    mine = sorted((e for e in events if e["name"] in (
-        "serve.await_request", "serve.coalesce_wait", "serve.batch")),
-        key=lambda e: e["ts"])
-    assert {e["name"] for e in mine} == {
-        "serve.await_request", "serve.coalesce_wait", "serve.batch"}
-    assert {e["tid"] for e in mine} == {dispatcher}
+    """`serve.await_request`, `serve.coalesce_wait` and the four steps of
+    a batch (`serve.collate`, `.dispatch`, `.fetch`, `.unpad`) are one
+    thread's time and never overlap each other, so no share of a window
+    made of them can pass 100%. A `serve.batch` runs from its dispatch to
+    its results with the next batch's dispatch possibly in between: two
+    may overlap, never more than `inflight_depth` are open at once, and
+    every child names `serve.batch` as its parent and its `batch`."""
+    events, stats, dispatcher = served_spans
+    waits = ("serve.await_request", "serve.coalesce_wait")
+    steps = ("serve.collate", "serve.dispatch", "serve.fetch",
+             "serve.unpad")
+    mine = sorted((e for e in events if e["name"] in waits + steps),
+                  key=lambda e: e["ts"])
+    assert {e["name"] for e in mine} == set(waits + steps)
+    batches = [e for e in events if e["name"] == "serve.batch"]
+    assert {e["tid"] for e in mine + batches} == {dispatcher}
     for a, b in zip(mine, mine[1:]):
         assert a["ts"] + a["dur"] <= b["ts"] + 1.0, (a, b)
     span = mine[-1]["ts"] + mine[-1]["dur"] - mine[0]["ts"]
@@ -700,6 +904,21 @@ def test_dispatcher_spans_do_not_overlap(served_spans):
     reqs = {e["args"]["req"] for e in events if e["name"] == "serve.request"}
     assert {e["args"]["req"] for e in mine
             if e["name"] == "serve.coalesce_wait"} <= reqs
+    # at most `inflight_depth` batches between dispatch and results
+    assert stats["inflight_depth"] == 2
+    edges = sorted([(e["ts"], 1) for e in batches]
+                   + [(e["ts"] + e["dur"], -1) for e in batches])
+    open_now = most = 0
+    for _, step in edges:
+        open_now += step
+        most = max(most, open_now)
+    assert 1 <= most <= stats["inflight_depth"]
+    ids = {e["args"]["batch"] for e in batches}
+    assert len(ids) == len(batches)
+    for child in steps + ("serve.forward",):
+        spans = [e for e in events if e["name"] == child]
+        assert {e["args"]["batch"] for e in spans} == ids, child
+        assert {e["args"]["parent"] for e in spans} == {"serve.batch"}
 
 
 def _ends_shed_by_admission(eng, structures, park):

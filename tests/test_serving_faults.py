@@ -74,6 +74,68 @@ class _BlockedDispatcher:
         eng._execute = blocked
 
 
+class _Computing:
+    """An output the device has not finished: not ready until released."""
+
+    def __init__(self, out, release):
+        self.out, self.release = out, release
+
+    def is_ready(self):
+        return self.release.is_set()
+
+    def __array__(self, *args, **kwargs):
+        return np.asarray(self.out)
+
+
+class _ParkedFetch:
+    """A device that is still computing until `release` is set: a
+    dispatched batch's outputs are not ready, and the dispatcher is held
+    inside the first fetch it enters, so tests can look at an engine with
+    batches in flight. `forward_single` (no batch id) is never held."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.entered = threading.Event()
+        self.release = threading.Event()
+        fetch, enqueue = eng._fetch, eng._enqueue
+
+        def computing(shards, bucket, batch_id):
+            outs, version = enqueue(shards, bucket, batch_id)
+            if batch_id is not None:
+                outs = [_Computing(o, self.release) for o in outs]
+            return outs, version
+
+        def parked(outs, batch_id):
+            if batch_id is not None:
+                self.entered.set()
+                assert self.release.wait(30)
+            return fetch(outs, batch_id)
+
+        eng._enqueue = computing
+        eng._fetch = parked
+
+    def await_inflight(self, n, timeout=30.0):
+        """True once `n` batches are dispatched and not completed."""
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            if len(self.eng._owed) == n:
+                return True
+            time.sleep(0.002)
+        return False
+
+
+def _queued_behind_a_parked_dispatcher(eng, samples):
+    """Submit `samples` so that all of them are queued before the
+    dispatcher dispatches the first batch: the queue is never empty
+    between two dispatches, as under load."""
+    block = _BlockedDispatcher(eng)
+    futs = [eng.submit(s) for s in samples]
+    assert block.entered.wait(30)
+    eng._execute = block._orig
+    block.release.set()
+    return futs
+
+
 # ------------------------------------------------------- injected failures
 
 def test_dispatch_fault_resolves_only_its_batch(served):
@@ -120,6 +182,115 @@ def test_no_futures_lost_under_repeated_faults(served):
         assert health["dispatcher_alive"]
         # the engine still serves cleanly afterwards
         assert eng.submit(samples[0]).result(timeout=60) is not None
+    finally:
+        eng.shutdown()
+
+
+def test_fetch_fault_fails_only_its_batch(served):
+    """A batch that fails AFTER it was dispatched (`serving-fetch`) fails
+    its own futures; the batch dispatched behind it, already on the device,
+    is served."""
+    samples, _, _, _ = served
+    eng = _engine(served, max_batch_size=2, max_wait_ms=500.0,
+                  breaker_threshold=0)
+    try:
+        eng.warmup()
+        assert eng.stats()["inflight_depth"] == 2
+        install_fault_plan(parse_fault_plan("serving-fetch@0"))
+        park = _ParkedFetch(eng)
+        futs = _queued_behind_a_parked_dispatcher(eng, samples[:4])
+        assert park.entered.wait(30)
+        assert park.await_inflight(2), "the second batch never overlapped"
+        park.release.set()
+        for f in futs[:2]:
+            assert isinstance(f.exception(timeout=60), InjectedFault)
+        for s, f in zip(samples[2:4], futs[2:]):
+            got = f.result(timeout=60)
+            ref = eng.forward_single(s, bucket=f.bucket)
+            for a, b in zip(got, ref):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        health = eng.health()
+        assert health["batch_failures"] == 1
+        assert health["dispatcher_alive"]
+        assert eng.stats()["batches"] == 1
+    finally:
+        eng.shutdown()
+
+
+def test_batch_behind_the_tripping_one_is_served_and_breaker_stays_open(
+        served):
+    """With two in flight the batch dispatched behind the one that trips
+    the breaker still runs and is answered, but its success does not close
+    an open breaker: the probe does, alone in flight."""
+    samples, _, _, _ = served
+    eng = _engine(served, max_batch_size=2, max_wait_ms=500.0,
+                  breaker_threshold=1, breaker_reset_s=0.2)
+    try:
+        eng.warmup()
+        install_fault_plan(parse_fault_plan("serving-fetch@0"))
+        park = _ParkedFetch(eng)
+        futs = _queued_behind_a_parked_dispatcher(eng, samples[:4])
+        assert park.entered.wait(30) and park.await_inflight(2)
+        park.release.set()
+        for f in futs[:2]:
+            assert isinstance(f.exception(timeout=60), InjectedFault)
+        for f in futs[2:]:
+            assert f.result(timeout=60) is not None
+        assert park.await_inflight(0)
+        health = eng.health()
+        assert health["state"] == "open" and health["trip_count"] == 1
+        with pytest.raises(CircuitOpenError):
+            eng.submit(samples[0])
+        time.sleep(0.25)  # the window elapses: the next submit probes
+        assert eng.submit(samples[0]).result(timeout=60) is not None
+        assert park.await_inflight(0)
+        assert eng.health()["state"] == "closed"
+        assert eng.health()["probe_count"] == 1
+    finally:
+        eng.shutdown()
+
+
+def test_shutdown_with_two_in_flight_serves_everything(served):
+    samples, _, _, _ = served
+    eng = _engine(served, max_batch_size=2, max_wait_ms=500.0)
+    eng.warmup()
+    park = _ParkedFetch(eng)
+    futs = _queued_behind_a_parked_dispatcher(eng, samples[:8])
+    assert park.entered.wait(30) and park.await_inflight(2)
+    eng.shutdown(wait=False)
+    assert not any(f.done() for f in futs)
+    park.release.set()
+    eng.shutdown(wait=True)
+    assert not eng._owed
+    for f in futs:  # dispatched, queued: every one is answered
+        assert f.result(timeout=0) is not None
+    assert eng.stats()["batches"] == 4
+
+
+def test_dying_dispatcher_with_two_in_flight_leaves_no_future_pending(
+        served):
+    """The dispatcher dies with two batches dispatched and not completed:
+    they fail with the fatal error, as does what is queued."""
+    samples, _, _, _ = served
+    eng = _engine(served, max_batch_size=2, max_wait_ms=500.0)
+    try:
+        eng.warmup()
+        owed_at_death = []
+
+        def dies():
+            owed_at_death.append(len(eng._owed))
+            raise MemoryError("died")
+
+        eng._complete = dies
+        futs = _queued_behind_a_parked_dispatcher(eng, samples[:6])
+        for f in futs:
+            assert isinstance(f.exception(timeout=60), MemoryError)
+        assert owed_at_death == [2]
+        eng._dispatcher.join(30)
+        assert not eng.health()["dispatcher_alive"]
+        assert not eng._owed
+        with pytest.raises(RuntimeError, match="dispatcher died"):
+            eng.submit(samples[0])
     finally:
         eng.shutdown()
 
