@@ -76,6 +76,7 @@ class GraphDataLoader:
         self.pack_rank, self.pack_nproc = int(pack_rank), int(pack_nproc)
         self.pack_budget = None
         self._sizes = None        # lazily-scanned (nodes[], edges[]) arrays
+        self._pair_counts = None  # real edge pairs per sample, when asked
         self._plan_cache = {}     # epoch -> (bins, selections)
         if self.packing:
             # budget-packed batching (graphs/packing.py): shapes come from
@@ -213,11 +214,15 @@ class GraphDataLoader:
             return [i for shard in sel for i in shard]
         return list(sel)
 
-    def padding_stats(self):
+    def padding_stats(self, pair_space: bool = False):
         """Measured padding waste of the current epoch's plan —
         `padding_frac_nodes` / `padding_frac_edges` over all node/edge
         slots the compiled program will execute (the FLOP-waste proxy
-        reported by trainer/bench), plus bookkeeping fields.
+        reported by trainer/bench), plus bookkeeping fields. With
+        `pair_space` (and the dense neighbour table) also `pad_pair_share`,
+        the masked share of the [N, K, K] pair space that directional
+        message passing derives from the table (models/dimenet.py): one
+        more scan of every sample's edges, so only who asks pays it.
 
         Returns None for fixed-mode loaders over non-in-memory datasets:
         the size scan would deserialize every sample from disk/socket
@@ -235,8 +240,17 @@ class GraphDataLoader:
             g = self.graphs_per_shard
             sels = [tuple(tuple(sel[sh * g:(sh + 1) * g])
                           for sh in range(self.num_shards)) for sel in sels]
+        pairs = None
+        if pair_space and self.neighbor_k is not None:
+            if self._pair_counts is None:
+                from ..graphs.triplets import count_triplets
+                self._pair_counts = np.array(
+                    [count_triplets(s.senders, s.receivers)
+                     for s in self.dataset], np.int64)
+            pairs = self._pair_counts
         stats = plan_padding_stats(sels, nodes, edges,
-                                   self.n_node, self.n_edge)
+                                   self.n_node, self.n_edge, pairs=pairs,
+                                   neighbor_k=self.neighbor_k)
         stats["packing"] = "packed" if self.packing else "fixed"
         return stats
 

@@ -239,17 +239,22 @@ def plan_steps(bins: Sequence[Tuple[int, ...]], num_shards: int,
 
 
 def plan_padding_stats(selections: Sequence, nodes: np.ndarray,
-                       edges: np.ndarray, n_node: int, n_edge: int
+                       edges: np.ndarray, n_node: int, n_edge: int,
+                       pairs: np.ndarray = None, neighbor_k: int = None
                        ) -> Dict[str, float]:
     """Measured waste of a plan: fraction of node/edge slots that are
     padding over the epoch (the FLOP-waste proxy the trainer/bench
     report). Works for packed (nested per-shard tuples) and fixed (flat
-    tuples) selections."""
+    tuples) selections. With `pairs` (real edge pairs per sample) and the
+    neighbour table's K also `pad_pair_share`: the masked share of the
+    [n_node, K, K] pair space a directional stack derives from the table."""
     nodes = np.asarray(nodes)
     edges = np.asarray(edges)
+    pairs = None if pairs is None else np.asarray(pairs)
     shards = 0
     real_n = 0
     real_e = 0
+    real_p = 0
     graphs = 0
     for sel in selections:
         parts = sel if sel and isinstance(sel[0], tuple) else (sel,)
@@ -259,10 +264,17 @@ def plan_padding_stats(selections: Sequence, nodes: np.ndarray,
                 idx = np.asarray(part, np.int64)
                 real_n += int(nodes[idx].sum())
                 real_e += int(edges[idx].sum())
+                if pairs is not None:
+                    real_p += int(pairs[idx].sum())
                 graphs += len(part)
     node_slots = shards * n_node
     edge_slots = shards * n_edge
+    extra = {}
+    if pairs is not None and neighbor_k and node_slots:
+        extra["pad_pair_share"] = 1.0 - real_p / (
+            node_slots * neighbor_k * neighbor_k)
     return {
+        **extra,
         "padding_frac_nodes": (1.0 - real_n / node_slots) if node_slots
         else 0.0,
         "padding_frac_edges": (1.0 - real_e / edge_slots) if edge_slots

@@ -2,8 +2,11 @@
 
 The reference builds triplets per batch on the GPU with torch_sparse
 SparseTensor (reference: hydragnn/models/DIMEStack.py:181-205 `triplets`).
-Under XLA we need static shapes, so triplets are enumerated on the host at
-collation time into padded [T] index arrays (SURVEY.md §7 hard part (c)).
+Under XLA we need static shapes, so on the scatter path triplets are
+enumerated on the host at collation time into padded [T] index arrays
+(SURVEY.md §7 hard part (c)). With the dense neighbour table (the default
+layout) the model derives the same pairs on the device as an [N, K, K]
+space (models/dimenet.py) and nothing here runs.
 
 A triplet (k->j->i) is a pair of edges (e1 = k->j, e2 = j->i) with k != i;
 `idx_kj`/`idx_ji` index into the batch edge arrays.
@@ -18,18 +21,26 @@ from .batch import GraphBatch
 
 
 def count_triplets(senders: np.ndarray, receivers: np.ndarray) -> int:
-    """Exact number of triplets a single graph yields (for budget sizing).
+    """Exact number of triplets a single graph yields: the host list's
+    budget, and the real share of the [N, K, K] pair space the dense path
+    derives on the device (`pad_pair_share`).
 
     Handles asymmetric edge sets (max_neighbours capping drops one direction
-    of a pair): pairs = sum_e deg_in(sender(e)), minus the k == i back-tracks
-    which exist only where the reverse edge is actually present."""
+    of a pair) and periodic images (several edges between one pair of
+    nodes): pairs = sum_e deg_in(sender(e)), minus the k == i back-tracks,
+    which for edge (j->i) are ALL edges (i->j), whatever their image."""
+    senders = np.asarray(senders, np.int64)
+    receivers = np.asarray(receivers, np.int64)
     if len(senders) == 0:
         return 0
-    n = int(max(senders.max(initial=-1), receivers.max(initial=-1)) + 1)
+    n = int(max(senders.max(), receivers.max()) + 1)
     deg_in = np.bincount(receivers, minlength=n)   # edges k->j per node j
     pairs = int(deg_in[senders].sum())
-    edge_set = set(zip(senders.tolist(), receivers.tolist()))
-    backtracks = sum(1 for s, r in edge_set if (r, s) in edge_set)
+    directed, multiplicity = np.unique(senders * n + receivers,
+                                       return_counts=True)
+    reverse = receivers * n + senders
+    at = np.minimum(np.searchsorted(directed, reverse), len(directed) - 1)
+    backtracks = int(multiplicity[at][directed[at] == reverse].sum())
     return pairs - backtracks
 
 
@@ -87,7 +98,12 @@ def add_triplets(batch: GraphBatch, budget: int) -> GraphBatch:
 def sample_triplets(senders: np.ndarray, receivers: np.ndarray
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """Per-sample local triplet edge-pair indices (kj, ji). Computed once per
-    sample; batches just offset and concatenate these."""
+    sample; batches just offset and concatenate these.
+
+    The per-edge Python loop below (10 M iterations over a pool of 4,096
+    OC20-sized structures) is paid only on the scatter path: with the dense
+    neighbour table the model derives the pairs on the device and
+    `maybe_triplet_transform` returns None, so no run builds both."""
     n = int(max(senders.max(initial=-1), receivers.max(initial=-1)) + 1)
     order = np.argsort(receivers, kind="stable")
     sorted_recv = receivers[order]
@@ -162,8 +178,13 @@ def make_triplet_transform(samples: Sequence, graphs_per_batch: int):
 
 
 def maybe_triplet_transform(model_type: str, samples: Sequence,
-                            graphs_per_shard: int):
-    """One shared helper for run_training/run_prediction wiring."""
-    if model_type != "DimeNet":
+                            graphs_per_shard: int,
+                            neighbor_format: bool = False):
+    """One shared helper for run_training/run_prediction wiring, given the
+    RESOLVED batch layout: with the dense neighbour table DimeNet derives
+    its pair space inside the jitted program from `batch.nbr` (models/
+    dimenet.py) and needs no transform; the host list is built only for
+    the scatter path (graph sharding, HYDRAGNN_NEIGHBOR_FORMAT=0)."""
+    if model_type != "DimeNet" or neighbor_format:
         return None
     return TripletTransform(samples, graphs_per_shard)

@@ -11,9 +11,10 @@ Trace vocabulary (PERF.md section 3; metadata only, the lowered program is
 the same): the reductions here run under `jax.named_scope("aggregate")`,
 node -> neighbour gathers under "neighbor_gather" (`neighbor_gather`), the
 edge -> dense-slot layout conversion `ev[batch.nbr_edge]` under
-"edge_gather" (`edge_gather`). Convs call the two gather helpers instead of
-indexing, so a reduction of a device trace finds the same names after a
-refactor.
+"edge_gather" (`edge_gather`), the slot -> pair row gather of directional
+message passing under "pair_gather" (`row_gather`). Convs call the gather
+helpers instead of indexing, so a reduction of a device trace finds the same
+names after a refactor.
 """
 from __future__ import annotations
 
@@ -52,6 +53,16 @@ def edge_gather(edge_values, batch):
     PNAPlus step and of the SchNet forward (PERF.md section 5)."""
     with jax.named_scope("edge_gather"):
         return edge_values[batch.nbr_edge]
+
+
+def row_gather(slot_values, nbr):
+    """`slot_values[nbr]`: for every slot (i, a) of the dense layout the
+    whole ROW of slot values of its neighbour j = nbr[i, a], [N, K, ...] ->
+    [N, K, K, ...] (one contiguous block per gather index). The pair space
+    of directional message passing (models/dimenet.py) is made of these;
+    the gather, and its scatter-add transpose, run under "pair_gather"."""
+    with jax.named_scope("pair_gather"):
+        return slot_values[nbr]
 
 
 def _use_pallas() -> bool:

@@ -54,14 +54,14 @@ def run_prediction(config_or_path, datasets: Optional[Tuple] = None,
     batch_size = int(train_cfg["batch_size"])
     from .parallel.mesh import resolve_num_shards
     num_shards = resolve_num_shards(num_shards or 1, batch_size)
-    from .graphs.triplets import maybe_triplet_transform
-    batch_transform = maybe_triplet_transform(
-        mcfg.model_type, trainset + valset + testset,
-        max(batch_size // max(num_shards, 1), 1))
     from .utils.envflags import env_flag
     arch = config["NeuralNetwork"]["Architecture"]
     nbr_fmt = env_flag("HYDRAGNN_NEIGHBOR_FORMAT",
                        bool(arch.get("neighbor_format", True)))
+    from .graphs.triplets import maybe_triplet_transform
+    batch_transform = maybe_triplet_transform(
+        mcfg.model_type, trainset + valset + testset,
+        max(batch_size // max(num_shards, 1), 1), nbr_fmt)
     _, _, test_loader = create_dataloaders(trainset, valset, testset,
                                            batch_size,
                                            num_shards=num_shards,
@@ -76,6 +76,10 @@ def run_prediction(config_or_path, datasets: Optional[Tuple] = None,
             n_graph=test_loader.n_graph, np_out=True)
         if batch_transform is not None:
             init_batch = batch_transform(init_batch)
+        if test_loader.neighbor_k is not None:
+            from .graphs.batch import with_neighbor_format
+            init_batch = with_neighbor_format(init_batch,
+                                              k=test_loader.neighbor_k)
         variables = init_params(model, init_batch)
         tx = select_optimizer(train_cfg)
         template = TrainState.create(variables, tx)
@@ -90,13 +94,15 @@ def run_prediction(config_or_path, datasets: Optional[Tuple] = None,
     serving = resolve_serving(config)
     use_engine = serving.enabled if serve is None else bool(serve)
     if use_engine and batch_transform is not None:
-        # triplet-transformed batches (DimeNet) need per-batch host index
-        # tables the engine does not rebuild per bucket yet — same
-        # auto-disable contract as budget packing (docs/serving.md)
+        # the host-built triplet list (DimeNet WITHOUT the dense neighbour
+        # table) needs per-batch index tables the engine does not rebuild
+        # per bucket; with the table on there is no transform and the
+        # engine serves DimeNet like any stack (docs/serving.md)
         import logging
         logging.getLogger("hydragnn_tpu").warning(
-            "serving engine does not support triplet batch transforms "
-            "(DimeNet); falling back to the legacy prediction loop")
+            "serving engine does not support the host-built triplet list "
+            "(DimeNet with neighbor_format off); falling back to the "
+            "legacy prediction loop")
         use_engine = False
 
     if use_engine:

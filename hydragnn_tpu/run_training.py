@@ -141,9 +141,14 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
         config["NeuralNetwork"]["Training"])
     _arch0 = config["NeuralNetwork"]["Architecture"]
     _tcfg0 = config["NeuralNetwork"]["Training"]
-    if packing and _arch0["model_type"] == "DimeNet":
-        log("batch_packing: DimeNet's static triplet budget is not "
-            "pack-aware yet; falling back to fixed-shape batching")
+    if (packing and _arch0["model_type"] == "DimeNet"
+            and not env_flag("HYDRAGNN_NEIGHBOR_FORMAT",
+                             bool(_arch0.get("neighbor_format", True)))):
+        # with the dense table (the default) DimeNet derives its pairs on
+        # the device and packs like any stack
+        log("batch_packing: DimeNet without the dense neighbour table "
+            "takes the host-built triplet list, whose static budget is "
+            "not pack-aware; falling back to fixed-shape batching")
         packing = False
     if packing and (int(_arch0.get("graph_shards", 1) or 1) > 1
                     or int(_tcfg0.get("pipeline_stages", 1) or 1) > 1):
@@ -289,11 +294,6 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
         local_shards, local_batch = validate_multiprocess_spmd(
             num_shards, batch_size)
 
-    from .graphs.triplets import maybe_triplet_transform
-    batch_transform = maybe_triplet_transform(
-        nn["Architecture"]["model_type"], trainset + valset + testset,
-        max(batch_size // max(num_shards, 1), 1))
-
     # dense neighbor-list layout (zero-scatter aggregation): default-on —
     # every stack consumes it when present (cross-layout equivalence is
     # tested for all 13 in tests/test_graph_core.py); K pinned across
@@ -307,6 +307,13 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
         log("graph_shards > 1: disabling the dense neighbor-list layout "
             "(edge-sharded aggregation uses the segment path)")
         nbr_fmt = False
+
+    # DimeNet derives its edge-pair space on the device from the dense
+    # table; only without the table does it need the host-built list
+    from .graphs.triplets import maybe_triplet_transform
+    batch_transform = maybe_triplet_transform(
+        nn["Architecture"]["model_type"], trainset + valset + testset,
+        max(batch_size // max(num_shards, 1), 1), nbr_fmt)
 
     # HYDRAGNN_USE_ddstore serves training samples from the C++ DDStore
     # (reference: the --ddstore path wrapping datasets in DistDataset,
@@ -327,9 +334,10 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
     if mp_spmd:
         if batch_transform is not None:
             raise ValueError(
-                "multi-process SPMD does not support triplet-transform "
-                "models yet (the static triplet budget is not globally "
-                "reduced; train DimeNet single-process)")
+                "multi-process SPMD does not support the host-built "
+                "triplet list (its static budget is not globally reduced); "
+                "keep the dense neighbour table on (neighbor_format), with "
+                "which DimeNet derives its pairs on the device")
         if not packing:
             from .parallel.multiprocess import allreduce_max_int
             from .preprocess.load_data import loader_budgets
@@ -387,6 +395,12 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
                          n_graph=train_loader.n_graph, np_out=True)
     if batch_transform is not None:
         init_batch = batch_transform(init_batch)
+    if train_loader.neighbor_k is not None:
+        # the layout the loader makes: a stack that derives index spaces
+        # from the dense table (DimeNet) has nothing else to init on
+        from .graphs.batch import with_neighbor_format
+        init_batch = with_neighbor_format(init_batch,
+                                          k=train_loader.neighbor_k)
     tx = select_optimizer(train_cfg)
     if pipeline_stages > 1:
         # (config already validated before the loader was built)
@@ -399,6 +413,12 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
         model = create_model(mcfg)
         variables = init_params(model, init_batch)
         state = TrainState.create(variables, tx)
+        if nbr_fmt and getattr(model, "derives_pair_space", False):
+            pad = train_loader.padding_stats(pair_space=True) or {}
+            share = pad.get("pad_pair_share")
+            log(f"layout: neighbor_format=True K={train_loader.neighbor_k}"
+                "; the stack derives its [N, K, K] pair space on the device"
+                + ("" if share is None else f", pad_pair_share={share:.3f}"))
 
     # resume / transfer: Training.continue + startfrom name the run whose
     # checkpoint seeds this one (reference: load_existing_model_config,

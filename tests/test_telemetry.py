@@ -351,7 +351,7 @@ def _documented_spans():
     doc = open(os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "docs", "observability.md")).read()
     section = doc[doc.index("## Span taxonomy"):
-                  doc.index("## JSONL event log")]
+                  doc.index("**Device-side names.**")]
     return sorted(set(re.findall(r"^\| `([a-z0-9_.<>]+)` \|", section,
                                  re.MULTILINE)))
 
@@ -384,6 +384,38 @@ def test_span_table_matches_call_sites(name):
     nothing that no call site records."""
     assert name in _call_site_spans(), f"{name}: documented, no call site"
     assert name in _documented_spans(), f"{name}: recorded, not documented"
+
+
+@functools.lru_cache(maxsize=None)
+def _scopes():
+    """(documented, called): the device-side scope table of
+    docs/observability.md and the literal `jax.named_scope("...")` names
+    of the package (kernels aside: no program of the table runs one)."""
+    import os
+    import re
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    doc = open(os.path.join(repo, "docs", "observability.md")).read()
+    section = doc[doc.index("**Device-side names.**"):
+                  doc.index("## JSONL event log")]
+    documented = set(re.findall(r"^\| `([a-z_]+)` \|", section,
+                                re.MULTILINE))
+    called = set()
+    for folder, _, files in os.walk(os.path.join(repo, "hydragnn_tpu")):
+        if os.path.basename(folder) == "kernels":
+            continue
+        for name in files:
+            if name.endswith(".py"):
+                called.update(re.findall(
+                    r"named_scope\(\s*\"([a-z_]+)\"\)",
+                    open(os.path.join(folder, name)).read()))
+    return documented, called
+
+
+@pytest.mark.parametrize("name", sorted(set().union(*_scopes())))
+def test_device_scope_table_matches_call_sites(name):
+    documented, called = _scopes()
+    assert name in called, f"{name}: documented, no call site"
+    assert name in documented, f"{name}: a scope, not documented"
 
 
 def test_train_spans_carry_the_step(telemetry_run):
