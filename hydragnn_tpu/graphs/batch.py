@@ -57,11 +57,22 @@ class GraphBatch:
     idx_ji: Optional[jnp.ndarray] = None    # [T] edge index of (j->i)
     triplet_mask: Optional[jnp.ndarray] = None  # [T] bool
     # fixed-degree neighbor-list layout (with_neighbor_format): aggregation
-    # becomes a dense [N, K, F] gather + axis reduction with zero scatters —
-    # the TPU-native alternative to segment ops for bounded-degree graphs
+    # becomes a dense [N, K, F] gather + axis reduction, no scatter in the
+    # forward pass — the TPU-native alternative to segment ops for
+    # bounded-degree graphs. The backward pass of the edge -> slot gather
+    # is a gather too where the batch carries `edge_slot`
+    # (ops/segment.edge_gather); the transposes of the node -> slot gathers
+    # are true many-to-one sums and still scatter
     nbr: Optional[jnp.ndarray] = None        # [N, K] int32 sender of slot k
     nbr_edge: Optional[jnp.ndarray] = None   # [N, K] int32 edge id of slot k
     nbr_mask: Optional[jnp.ndarray] = None   # [N, K] bool
+    # inverse of nbr_edge: the flat slot (receiver * K + rank) of every real
+    # edge, 0 for a padding edge (never read: masked by edge_mask). Present
+    # ONLY beside tables whose last slot [N - 1, K - 1] is a padding slot
+    # (mask False, index E - 1), from which ops/segment.edge_gather reads
+    # the padding value: build_neighbor_tables, the one producer, gives
+    # None otherwise
+    edge_slot: Optional[jnp.ndarray] = None  # [E] int32
     # sampled giant-graph training (preprocess/sampling.py,
     # docs/sampling.md): node slots are one k-hop computation graph laid
     # out [seeds | hop1 | ... | padding]; the loss is taken over seeds
@@ -365,15 +376,23 @@ def build_neighbor_tables(senders: np.ndarray, receivers: np.ndarray,
                           k: Optional[int] = None, k_multiple: int = 8):
     """Receiver-major fixed-degree neighbor tables from a padded edge list.
 
-    Returns (nbr [N, K], nbr_edge [N, K], nbr_mask [N, K]): slot k of node i
-    holds the sender and edge id of i's k-th in-edge. Padding slots point at
-    the padding node/edge with mask False. K is the max in-degree rounded up
-    to `k_multiple` (or the explicit `k`, which must fit).
+    Returns (nbr [N, K], nbr_edge [N, K], nbr_mask [N, K], edge_slot [E]):
+    slot k of node i holds the sender and edge id of i's k-th in-edge.
+    Padding slots point at the padding node/edge with mask False. K is the
+    max in-degree rounded up to `k_multiple` (or the explicit `k`, which
+    must fit). `edge_slot` is the inverse of `nbr_edge`: a real edge has one
+    receiver and so sits in exactly one slot, `receiver * K + rank`; a
+    padding edge reads 0 (masked by `edge_mask` wherever it is used). It is
+    None where the LAST slot is real (a hand-built batch whose last node is
+    real and full; collate always leaves a padding node): ops/segment.
+    edge_gather takes that slot for a padding slot, and a batch without
+    `edge_slot` indexes plainly. This is the one place that rule lives.
 
     Aggregating over the K axis of a [N, K, F] gather replaces the segment
-    scatter entirely — the dense layout the TPU prefers for bounded-degree
-    radius graphs (no analogue in the reference: PyG scatters,
-    hydragnn/models/Base.py:18).
+    scatter of the forward pass — the dense layout the TPU prefers for
+    bounded-degree radius graphs (no analogue in the reference: PyG
+    scatters, hydragnn/models/Base.py:18). `edge_slot` does the same for
+    the backward pass of that gather (ops/segment.edge_gather).
     """
     senders = np.asarray(senders)
     receivers = np.asarray(receivers)
@@ -388,6 +407,7 @@ def build_neighbor_tables(senders: np.ndarray, receivers: np.ndarray,
     nbr = np.full((n_node, k), n_node - 1, np.int32)
     nbr_edge = np.full((n_node, k), n_edge - 1, np.int32)
     nbr_mask = np.zeros((n_node, k), bool)
+    edge_slot = np.zeros(n_edge, np.int32)
     # vectorized fill: stable-sort real edges by receiver, then the slot of
     # edge e is its rank within its receiver run (arange minus run start)
     eids = np.nonzero(real)[0]
@@ -403,7 +423,8 @@ def build_neighbor_tables(senders: np.ndarray, receivers: np.ndarray,
         nbr[r_sorted, slots] = senders[e_sorted]
         nbr_edge[r_sorted, slots] = e_sorted
         nbr_mask[r_sorted, slots] = True
-    return nbr, nbr_edge, nbr_mask
+        edge_slot[e_sorted] = r_sorted * k + slots
+    return nbr, nbr_edge, nbr_mask, None if nbr_mask[-1, -1] else edge_slot
 
 
 def neighbor_budget_for_dataset(samples, k_multiple: int = 8) -> int:
@@ -433,13 +454,15 @@ def with_neighbor_format(batch: GraphBatch, k: Optional[int] = None,
     Default-on (run_training): the r3 CPU sweep measured the dense
     layout ahead of the segment pipeline at every steps-per-call
     setting (41.5/47.6/51.4 vs 39.5/26.7/43.6 g/s at spc 1/4/10,
-    BENCH_SWEEP.json) — it removes the scatter entirely, which also
+    BENCH_SWEEP.json) — it removes the forward pass's scatter, which also
     sidesteps the Pallas-vs-XLA-scatter question wherever it applies."""
-    nbr, nbr_edge, nbr_mask = build_neighbor_tables(
+    nbr, nbr_edge, nbr_mask, edge_slot = build_neighbor_tables(
         np.asarray(batch.senders), np.asarray(batch.receivers),
         np.asarray(batch.edge_mask), batch.num_nodes, batch.num_edges,
         k=k, k_multiple=k_multiple)
     as_jnp = isinstance(batch.x, jnp.ndarray)
     conv = jnp.asarray if as_jnp else (lambda a: a)
     return batch.replace(nbr=conv(nbr), nbr_edge=conv(nbr_edge),
-                         nbr_mask=conv(nbr_mask))
+                         nbr_mask=conv(nbr_mask),
+                         edge_slot=None if edge_slot is None
+                         else conv(edge_slot))

@@ -235,7 +235,8 @@ class PNAConv(nn.Module):
                     interpret_mode())
             else:
                 # dense neighbor-list layout: [N, K, F] messages, axis-1
-                # reductions, zero scatters (with_neighbor_format)
+                # reductions, no scatter in the forward pass
+                # (with_neighbor_format)
                 h = proj_i[:, None, :] + seg.neighbor_gather(proj_j,
                                                              batch.nbr)
                 h = edge_terms(h, lambda ev: seg.edge_gather(ev, batch))

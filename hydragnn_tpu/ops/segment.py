@@ -50,9 +50,82 @@ def edge_gather(edge_values, batch):
     """Per-edge values [E, ...] into the dense neighbour layout [N, K, ...]
     (`edge_values[batch.nbr_edge]`), named for the trace: on the v5e this
     layout conversion and its transpose are the largest single term of the
-    PNAPlus step and of the SchNet forward (PERF.md section 5)."""
+    PNAPlus step and of the SchNet forward (PERF.md section 5).
+
+    A batch that carries `edge_slot`, the inverse of the table
+    (graphs/batch.build_neighbor_tables), gets the same values under a
+    hand-written derivative: every real edge sits in exactly one slot, so
+    the transpose of this gather is a gather too (`_slot_to_edge`), and the
+    transpose of that one is this one again. No backward pass of any order
+    then scatters; jax's own transpose is a scatter-add of N x K updates,
+    serial on the chip, because XLA cannot know the index is injective.
+    A batch without it (hand-built, an old pickle) indexes plainly."""
+    if batch.edge_slot is None:
+        with jax.named_scope("edge_gather"):
+            return edge_values[batch.nbr_edge]
+    return _edge_to_slot(edge_values, batch.nbr_edge, batch.nbr_mask,
+                         batch.edge_slot, batch.edge_mask)
+
+
+@jax.custom_vjp
+def _edge_to_slot(edge_values, nbr_edge, nbr_mask, edge_slot, edge_mask):
+    """`edge_values[nbr_edge]`, bit for bit in every slot, without reading
+    one row N x K times: every padding slot of the table names edge E - 1
+    (graphs/batch.build_neighbor_tables), and a gather whose indices all
+    name one row runs at the pace of a scatter on the TPU. So a padding
+    slot s gathers a row of its own, s mod E, and then takes by a select
+    what the LAST slot read, which keeps the table's index: a batch carries
+    `edge_slot` only where that slot is a padding slot
+    (graphs/batch.build_neighbor_tables holds the rule). (Not
+    `edge_values[E - 1]`: a second reader of `edge_values` made the
+    compiler keep two layouts of it, 0.45 GiB more in the PNAPlus step.)"""
     with jax.named_scope("edge_gather"):
-        return edge_values[batch.nbr_edge]
+        n, k = nbr_edge.shape
+        slot = (jax.lax.broadcasted_iota(jnp.int32, (n, k), 0) * k
+                + jax.lax.broadcasted_iota(jnp.int32, (n, k), 1))
+        keep = nbr_mask | (slot == n * k - 1)
+        rows = edge_values[jnp.where(keep, nbr_edge,
+                                     slot % edge_values.shape[0])]
+        return jnp.where(_bcast(nbr_mask, rows), rows, rows[-1, -1])
+
+
+@jax.custom_vjp
+def _slot_to_edge(d_slot, nbr_edge, nbr_mask, edge_slot, edge_mask):
+    """The transpose of `_edge_to_slot`, [N, K, ...] -> [E, ...], exact for
+    any `d_slot`: a real edge reads its one slot, a padding edge reads
+    nothing, and edge E - 1, at which every padding slot points, also takes
+    the sum over those slots (one masked reduction, one row written). What
+    a padding edge gathers is masked, so it gathers a slot of its own (e mod
+    N x K) and not the table's 0: one row read by every padding edge costs
+    a quarter more here too (PERF.md section 6, PR 29)."""
+    with jax.named_scope("edge_gather"):
+        # (node, k) indices, NOT a flat index into d_slot.reshape(N * K,
+        # ...): XLA moves that reshape up through the elementwise producers
+        # of d_slot, whose [N, F] -> [N, K, F] broadcasts then no longer
+        # fuse (the PNAPlus step compiled to 15.1 GiB against 7.0: PR 29)
+        n, k = nbr_edge.shape
+        slot = jnp.where(edge_mask, edge_slot,
+                         jnp.arange(edge_slot.shape[0]) % (n * k))
+        d_edge = d_slot[slot // k, slot % k]
+        d_edge = jnp.where(_bcast(edge_mask, d_edge), d_edge, 0)
+        at_padding = jnp.sum(jnp.where(_bcast(nbr_mask, d_slot), 0, d_slot),
+                             axis=(0, 1), dtype=d_slot.dtype)
+        last = _bcast(jnp.arange(d_edge.shape[0]) == d_edge.shape[0] - 1,
+                      d_edge)
+        return jnp.where(last, d_edge + at_padding, d_edge)
+
+
+def _transposes_of_each_other(f, g):
+    """Both are linear in their first argument and save nothing but the
+    index tables, which take no cotangent."""
+    for fn, transpose in ((f, g), (g, f)):
+        fn.defvjp(
+            lambda x, *tables, fn=fn: (fn(x, *tables), tables),
+            lambda tables, ct, transpose=transpose:
+                (transpose(ct, *tables),) + (None,) * len(tables))
+
+
+_transposes_of_each_other(_edge_to_slot, _slot_to_edge)
 
 
 def row_gather(slot_values, nbr):
@@ -258,8 +331,10 @@ def neighbor_mean(h, nbr_mask):
 def edge_aggregate_sum(edge_values, batch):
     """Sum per-edge values into receiver nodes, using the dense
     neighbor-list layout when the batch carries one (gather by nbr_edge +
-    masked K-axis reduction — no scatter) and the masked segment scatter
-    otherwise. Drop-in for the edge->node aggregation step of any conv."""
+    masked K-axis reduction — no scatter in the forward pass, and none in
+    the backward pass of that gather where the batch carries `edge_slot`)
+    and the masked segment scatter otherwise. Drop-in for the edge->node
+    aggregation step of any conv."""
     if batch.nbr_edge is not None:
         return neighbor_sum(edge_gather(edge_values, batch), batch.nbr_mask)
     return segment_sum(edge_values, batch.receivers, batch.num_nodes,

@@ -413,12 +413,19 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
         model = create_model(mcfg)
         variables = init_params(model, init_batch)
         state = TrainState.create(variables, tx)
-        if nbr_fmt and getattr(model, "derives_pair_space", False):
-            pad = train_loader.padding_stats(pair_space=True) or {}
-            share = pad.get("pad_pair_share")
-            log(f"layout: neighbor_format=True K={train_loader.neighbor_k}"
-                "; the stack derives its [N, K, K] pair space on the device"
-                + ("" if share is None else f", pad_pair_share={share:.3f}"))
+        if nbr_fmt:
+            # edge_slot=True: the backward pass of the edge -> slot gather
+            # is a gather too (ops/segment.edge_gather)
+            layout = (f"layout: neighbor_format=True "
+                      f"K={train_loader.neighbor_k} "
+                      f"edge_slot={init_batch.edge_slot is not None}")
+            if getattr(model, "derives_pair_space", False):
+                pad = train_loader.padding_stats(pair_space=True) or {}
+                share = pad.get("pad_pair_share")
+                layout += ("; the stack derives its [N, K, K] pair space on "
+                           "the device" + ("" if share is None else
+                                           f", pad_pair_share={share:.3f}"))
+            log(layout)
 
     # resume / transfer: Training.continue + startfrom name the run whose
     # checkpoint seeds this one (reference: load_existing_model_config,

@@ -1201,7 +1201,9 @@ class InferenceEngine:
 
     def _empty_shard(self, bucket: PackBudget) -> GraphBatch:
         """All-padding shard batch (the loader's proto-sample trick): a
-        zeroed proto collate whose masks are all False."""
+        zeroed proto collate whose masks are all False. Every slot of its
+        tables is a padding slot at edge E - 1, the last one too, which is
+        what `edge_slot` asks of them (GraphBatch.edge_slot)."""
         b = self._collate_bucket([self._proto], bucket)
         zero = lambda a: None if a is None else np.zeros_like(a)
 
@@ -1219,7 +1221,7 @@ class InferenceEngine:
             triplet_mask=zero(b.triplet_mask),
             nbr=pad_full(b.nbr, bucket.n_node - 1),
             nbr_edge=pad_full(b.nbr_edge, b.num_edges - 1),
-            nbr_mask=zero(b.nbr_mask))
+            nbr_mask=zero(b.nbr_mask), edge_slot=zero(b.edge_slot))
 
     def _stack_shards(self, shards: List[Optional[GraphBatch]],
                       bucket: PackBudget) -> GraphBatch:
@@ -1228,10 +1230,13 @@ class InferenceEngine:
                   for s in shards]
         return _stack_batches(filled)
 
-    def _store_key(self, bucket: PackBudget) -> str:
+    def _store_key(self, bucket: PackBudget, batch: GraphBatch) -> str:
         """Compile-store fingerprint for one bucket's program: model
         config + bucket shape + everything else that changes the
-        compiled artifact (shard count, schema layout). The store
+        compiled artifact (shard count, schema layout, and WHICH fields
+        `batch` carries: they are the executable's arguments, so a store
+        written by a tree whose batches had one field fewer is a miss,
+        not an executable that is handed an argument too many). The store
         itself folds in the jax version and backend platform; the
         precision MODE — compute dtype plus the int8 calibration-scale
         digest — rides the store's labeled `precision` field, so an
@@ -1244,11 +1249,13 @@ class InferenceEngine:
             (name, None if getattr(p, name) is None
              else tuple(np.asarray(getattr(p, name)).shape[1:]))
             for name in ("x", "pos", "edge_attr", "edge_shifts", "cell"))
+        fields = tuple(f.name for f in dataclasses.fields(batch)
+                       if getattr(batch, f.name) is not None)
         from ..utils.devices import CompileStore
         return CompileStore.fingerprint(
             self.mcfg, (bucket.n_node, bucket.n_edge, bucket.n_graph),
             self.num_shards, self.neighbor_k,
-            self.ef_forward, schema,
+            self.ef_forward, schema, fields,
             precision=(self.compute_dtype, self._quant_digest))
 
     def _get_compiled(self, bucket: PackBudget, proto_batch: GraphBatch):
@@ -1263,14 +1270,14 @@ class InferenceEngine:
         compiled = None
         from_store = False
         if self._compile_store is not None:
-            compiled = self._compile_store.load(
-                self._store_key(bucket), self.devices)
+            store_key = self._store_key(bucket, proto_batch)
+            compiled = self._compile_store.load(store_key, self.devices)
             from_store = compiled is not None
         if compiled is None:
             compiled = self._jit_forward.lower(variables,
                                                proto_batch).compile()
             if self._compile_store is not None:
-                self._compile_store.save(self._store_key(bucket), compiled)
+                self._compile_store.save(store_key, compiled)
         depth = (self._resolve_depth(compiled)
                  if bucket == self.buckets[-1] else None)
         with self._lock:

@@ -214,7 +214,7 @@ def test_neighbor_format_tables():
     send = rng.randint(0, n_node - 1, n_edge).astype(np.int32)
     recv = rng.randint(0, n_node - 1, n_edge).astype(np.int32)
     mask = rng.rand(n_edge) < 0.9
-    nbr, nbr_edge, nbr_mask = build_neighbor_tables(
+    nbr, nbr_edge, nbr_mask, _ = build_neighbor_tables(
         send, recv, mask, n_node, n_edge)
     assert int(nbr_mask.sum()) == int(mask.sum())
     covered = sorted(nbr_edge[nbr_mask].tolist())
@@ -222,6 +222,102 @@ def test_neighbor_format_tables():
     rows, slots = np.nonzero(nbr_mask)
     assert np.all(recv[nbr_edge[rows, slots]] == rows)
     assert np.all(send[nbr_edge[rows, slots]] == nbr[rows, slots])
+
+
+def _slot_case(case):
+    """A numpy batch (or a stack of two) with neighbour tables, by case:
+    what `edge_slot` has to invert (ops/segment.edge_gather)."""
+    from hydragnn_tpu.graphs.batch import with_neighbor_format
+
+    rng = np.random.RandomState(3)
+    samples = [_rand_sample(rng, n) for n in (9, 14, 6)]
+    tot_e = sum(s.num_edges for s in samples)
+    if case == "padded":
+        return with_neighbor_format(collate(samples, np_out=True))
+    if case == "last_edge_real":  # collate allows tot_e == n_edge
+        b = with_neighbor_format(collate(
+            samples, n_node=40, n_edge=tot_e, n_graph=4, np_out=True))
+        assert bool(b.edge_mask[-1])
+        return b
+    from hydragnn_tpu.models.create import (build_model_config, create_model,
+                                            init_params)
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.serving.engine import InferenceEngine
+    from tests.utils import make_config
+
+    cfg = update_config(make_config("PNA", heads=("graph", "node")), samples)
+    mcfg = build_model_config(cfg)
+    model = create_model(mcfg)
+    eng = InferenceEngine(model, init_params(model, collate(samples)), mcfg,
+                          reference_samples=samples, max_batch_size=4,
+                          neighbor_format=True, num_shards=1)
+    try:
+        bucket = eng.buckets[-1]
+        empty = eng._empty_shard(bucket)
+        if case == "empty_shard":
+            return empty
+        assert case == "two_shards"
+        return eng._stack_shards(
+            [eng._collate_bucket(samples[:2], bucket), None], bucket)
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("case", ["padded", "last_edge_real", "empty_shard",
+                                  "two_shards"])
+def test_edge_slot_inverts_the_neighbor_table(case):
+    """Every real edge sits in exactly one slot of the dense table, and
+    `edge_slot` names it: flat slot receiver * K + rank. A padding edge
+    reads 0 and is masked."""
+    b = _slot_case(case)
+    assert b.edge_slot is not None and b.edge_slot.dtype == np.int32
+    shards = ([b] if b.nbr_edge.ndim == 2 else
+              [type(b)(**{k: None if v is None else v[i]
+                          for k, v in vars(b).items()})
+               for i in range(b.nbr_edge.shape[0])])
+    assert len(shards) == (2 if case == "two_shards" else 1)
+    for sh in shards:
+        assert sh.edge_slot.shape == sh.edge_mask.shape
+        real = np.nonzero(sh.edge_mask)[0]
+        k = sh.nbr_edge.shape[1]
+        slots = sh.edge_slot[real]
+        assert np.all(sh.nbr_edge.reshape(-1)[slots] == real)
+        assert np.all(sh.nbr_mask.reshape(-1)[slots])
+        assert np.all(slots // k == sh.receivers[real])
+        assert len(set(slots.tolist())) == real.size == int(sh.nbr_mask.sum())
+        assert np.all(sh.edge_slot[~sh.edge_mask] == 0)
+    if case == "empty_shard":
+        assert not b.edge_mask.any() and not b.nbr_mask.any()
+        assert np.all(b.nbr_edge == b.num_edges - 1)
+        assert np.all(b.nbr == b.num_nodes - 1)
+    if case == "two_shards":
+        assert shards[0].edge_mask.any() and not shards[1].edge_mask.any()
+
+
+def test_no_edge_slot_when_the_last_slot_is_real():
+    """`edge_gather`'s fast path reads the padding value off the last slot;
+    a hand-built batch whose last node is real and full carries no
+    `edge_slot` and so indexes plainly. `build_neighbor_tables`, the one
+    producer, holds the rule for every caller."""
+    from hydragnn_tpu.graphs.batch import (GraphBatch, build_neighbor_tables,
+                                           with_neighbor_format)
+
+    n, k = 4, 8
+    recv = np.repeat(np.arange(n), k).astype(np.int32)
+    send = np.tile(np.arange(k) % n, n).astype(np.int32)
+    full = GraphBatch(
+        x=np.zeros((n, 1), np.float32), pos=np.zeros((n, 3), np.float32),
+        senders=send, receivers=recv, node_graph=np.zeros(n, np.int32),
+        node_mask=np.ones(n, bool), edge_mask=np.ones(n * k, bool),
+        graph_mask=np.ones(1, bool))
+    assert build_neighbor_tables(send, recv, full.edge_mask, n, n * k,
+                                 k=k)[3] is None
+    b = with_neighbor_format(full, k=k)
+    assert b.nbr_mask.all() and b.edge_slot is None
+    ev = jnp.arange(n * k * 2, dtype=jnp.float32).reshape(n * k, 2)
+    assert np.array_equal(seg.edge_gather(ev, b), ev[b.nbr_edge])
+    one_less = full.replace(edge_mask=np.arange(n * k) < n * k - 1)
+    assert with_neighbor_format(one_less, k=k).edge_slot is not None
 
 
 def test_neighbor_aggregate_matches_segment():
@@ -238,7 +334,7 @@ def test_neighbor_aggregate_matches_segment():
     h = rng.randn(n_edge, f).astype(np.float32)
     ref = seg.pna_aggregate(jnp.asarray(h), jnp.asarray(recv), n_node,
                             jnp.asarray(mask))
-    nbr, nbr_edge, nbr_mask = build_neighbor_tables(
+    nbr, nbr_edge, nbr_mask, _ = build_neighbor_tables(
         send, recv, mask, n_node, n_edge)
     hk = jnp.asarray(h)[jnp.asarray(nbr_edge)]
     out = seg.neighbor_aggregate(hk, jnp.asarray(nbr_mask))

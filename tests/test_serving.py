@@ -1155,3 +1155,41 @@ def test_resolve_serving_structure_knobs(monkeypatch):
     assert s.structure is False and s.md_skin == 0.3
     monkeypatch.setenv("HYDRAGNN_MD_SKIN", "0.75")
     assert resolve_serving(None).md_skin == 0.75
+
+
+def test_compile_store_key_folds_the_batch_fields(served, tmp_path):
+    """The executable's arguments are the batch's present fields: a store
+    written by a tree whose batches lacked one (`edge_slot`, before PR 29)
+    must be a miss, not an executable handed one argument too many."""
+    from hydragnn_tpu.utils.devices import CompileStore
+    samples, _, mcfg, model, variables = served
+    store = CompileStore(str(tmp_path / "store"))
+    mk = lambda: InferenceEngine(model, variables, mcfg,
+                                 reference_samples=samples,
+                                 max_batch_size=2, neighbor_format=True,
+                                 compile_store=store)
+    old, new, again = mk(), mk(), mk()
+    try:
+        # the older tree: the same engine, its batches without the field
+        collate_bucket = old._collate_bucket
+        old._collate_bucket = lambda *a: collate_bucket(*a).replace(
+            edge_slot=None)
+        bucket = new.buckets[0]
+        proto = new._collate_bucket([new._proto], bucket)
+        assert proto.edge_slot is not None
+        assert new._store_key(bucket, proto) != new._store_key(
+            bucket, proto.replace(edge_slot=None))
+        assert new._store_key(bucket, proto) == again._store_key(
+            bucket, again._collate_bucket([again._proto], bucket))
+        n = old.warmup()
+        assert old.stats()["compile_fresh"] == n > 0
+        assert new.warmup() == n
+        assert new.stats()["compile_fresh"] == n, "the old entries miss"
+        assert again.warmup() == n
+        assert again.stats()["compile_fresh"] == 0
+        assert again.stats()["compile_store_hits"] == n
+        assert new.submit(samples[0]).result(timeout=60) is not None
+        assert again.submit(samples[0]).result(timeout=60) is not None
+    finally:
+        for eng in (old, new, again):
+            eng.shutdown()
