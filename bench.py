@@ -26,7 +26,7 @@ Env knobs:
                        On CPU the scan still wins (BENCH_SWEEP.json
                        cpu_clean_rerun: spc 1/4/10 ->
                        41.8/47.9/49.6 g/s, dispatch-bound).
-  BENCH_SWEEP          =1: sweep NBR x PALLAS x STEPS_PER_CALL in
+  BENCH_SWEEP          =1: sweep NBR x STEPS_PER_CALL in
                        subprocesses, print the winner (full grid written
                        to BENCH_SWEEP_OUT, default BENCH_SWEEP.json)
   BENCH_BATCH / BENCH_NODES / BENCH_HIDDEN
@@ -42,9 +42,6 @@ Env knobs:
                        host time blocked on the input stream vs step
                        dispatch when the same compiled step is fed from a
                        real GraphDataLoader
-  HYDRAGNN_USE_PALLAS  Pallas segment-sum kernel on/off (ops/segment.py)
-  HYDRAGNN_PALLAS_NBR  fused neighbor-gather->MXU kernel on/off
-                       (kernels/nbr_pallas.py; watcher A/Bs it on-chip)
   BENCH_PEAK_FLOPS     override chip peak FLOP/s for MFU
   HYDRAGNN_PACKING     budget-packed batching on/off (docs/packing.md);
                        the emitted `packing`/`padding_frac_nodes`/
@@ -291,26 +288,23 @@ Env knobs:
   BENCH_PREPROC_OUT    also write the preprocessing JSON to this path
                        (the nightly preproc-bench emits
                        BENCH_PREPROC.json)
-  BENCH_KERNELS        =1: kernel/mixed-precision mode
-                       (docs/kernels_mixed_precision.md) — adjudicate the
-                       fused Pallas message-passing kernels
-                       (HYDRAGNN_FUSED_MP, kernels/fused_mp_pallas.py)
-                       and the bf16 policy: padding-aware graphs/s of
-                       the SchNet and PNA train steps over
-                       {unfused, fused} x {float32, bfloat16} on
-                       identical batches, forward-parity max-abs-diff
-                       per point vs the unfused fp32 path, and a serving
-                       leg comparing a bf16 engine against the fp32
-                       engine on identical buckets vs the documented
-                       tolerance bound (serving/engine.py
-                       SERVE_REDUCED_RTOL/ATOL)
+  BENCH_KERNELS        =1: mixed-precision mode
+                       (docs/mixed_precision.md) — adjudicate the bf16
+                       policy and the int8 serving tier: padding-aware
+                       graphs/s of the SchNet and PNA train steps in
+                       {float32, bfloat16} on identical batches,
+                       forward max-abs-diff per point vs the fp32 path,
+                       the int8 PTQ forward against the fp32 one, and a
+                       serving leg comparing bf16 and int8 engines
+                       against the fp32 engine on identical buckets vs
+                       the documented tolerance bounds (serving/engine.py
+                       SERVE_REDUCED_RTOL/ATOL, SERVE_INT8_RTOL/ATOL)
   BENCH_KERNELS_BATCH / BENCH_KERNELS_NODES / BENCH_KERNELS_DEG /
   BENCH_KERNELS_HIDDEN / BENCH_KERNELS_STEPS
-                       kernel-mode scale (default 8/40/8/64/3 — CPU
-                       interpret-mode Pallas is orders slower than the
-                       compiled TPU kernel, so the CPU smoke stays
-                       small; crank these up on-chip)
-  BENCH_KERNELS_OUT    also write the kernel JSON to this path (the
+                       the mode's scale (default 8/40/8/64/3: small
+                       enough for the CPU smoke; crank these up
+                       on-chip)
+  BENCH_KERNELS_OUT    also write the mode's JSON to this path (the
                        nightly kernel-bench emits BENCH_KERNELS.json)
   BENCH_MFU            =1: device-utilization mode (docs/pipeline.md,
                        docs/MFU_ANALYSIS.md, ROADMAP item 1) — the
@@ -655,8 +649,6 @@ def run_bench():
         "backend": backend,
         "nbr_layout": use_nbr,
         "steps_per_call": spc if spc > 1 else 1,
-        "pallas": os.environ.get("HYDRAGNN_USE_PALLAS", "default"),
-        "nbr_pallas": os.environ.get("HYDRAGNN_PALLAS_NBR", "default"),
         "dtype": compute_dtype,
         "input_bound_frac": input_bound,
         "loader_async_workers": async_workers,
@@ -4010,17 +4002,14 @@ def run_bench_preproc(backend=None):
 
 
 def run_bench_kernels(backend=None):
-    """BENCH_KERNELS: fused message-passing + mixed-precision
-    adjudication (docs/kernels_mixed_precision.md).
+    """BENCH_KERNELS: mixed-precision adjudication
+    (docs/mixed_precision.md).
 
-    For SchNet and PNA (the two conv families the fused kernels cover),
-    time the full train step over {unfused, fused} x {float32, bfloat16}
+    For SchNet and PNA, time the full train step in {float32, bfloat16}
     on IDENTICAL edge-list batches. graphs/s counts real graphs only
     (padding-aware — the fixed pad slots are excluded from the numerator
-    exactly like the sized mode), every point reports the forward
-    max-abs-diff against the unfused fp32 reference, and the fused fp32
-    point's parity against the unfused path is the tier-1 kernel
-    contract re-checked at bench scale. An int8 leg times the PTQ
+    exactly like the sized mode), and every point reports the forward
+    max-abs-diff against the fp32 reference. An int8 leg times the PTQ
     serving forward (quant/ptq.py — calibrated per-channel int8
     conv-stack matmuls, forward-only because int8 is serving-only)
     against the fp32 forward per model. A serving leg then runs fp32,
@@ -4029,15 +4018,12 @@ def run_bench_kernels(backend=None):
     tolerance bound (serving/engine.py SERVE_REDUCED_RTOL/ATOL;
     SERVE_INT8_RTOL/ATOL).
 
-    The fused and int8 points are honest about the backend: on CPU the
-    Pallas kernels run in interpret mode, and XLA CPU emulates int8
+    The int8 points are honest about the backend: XLA CPU emulates int8
     matmuls rather than accelerating them — the CPU numbers guard
-    correctness and wiring; the speedup question is answered on-chip
-    (the r3 HYDRAGNN_USE_PALLAS lesson, the PR 6 bf16 precedent)."""
+    correctness and wiring; the speedup question is answered on-chip."""
     import jax
     from hydragnn_tpu.config import build_model_config, update_config
     from hydragnn_tpu.graphs.batch import collate
-    from hydragnn_tpu.kernels.fused_mp_pallas import resolve_fused_mp_flag
     from hydragnn_tpu.models.create import create_model, init_params
     from hydragnn_tpu.train.optimizer import select_optimizer
     from hydragnn_tpu.train.train_step import (TrainState, make_forward_fn,
@@ -4069,8 +4055,7 @@ def run_bench_kernels(backend=None):
     real_graphs = int(np.asarray(batch.graph_mask).sum())
 
     saved_env = {k: os.environ.pop(k, None)
-                 for k in ("HYDRAGNN_FUSED_MP", "HYDRAGNN_PRECISION",
-                           "BENCH_DTYPE")}
+                 for k in ("HYDRAGNN_PRECISION", "BENCH_DTYPE")}
     grid = []
     try:
         for model_type in ("SchNet", "PNA"):
@@ -4084,58 +4069,48 @@ def run_bench_kernels(backend=None):
             variables = init_params(model, batch)
             ref_out = None
             for dtype in ("float32", "bfloat16"):
-                for fused in (False, True):
-                    os.environ["HYDRAGNN_FUSED_MP"] = "1" if fused else "0"
-                    # the step factory re-resolves the flag at
-                    # construction (the contract this mode relies on)
-                    step = make_train_step(model, mcfg, tx,
-                                           loss_name="mae", donate=False,
-                                           compute_dtype=dtype)
-                    forward = make_forward_fn(model, mcfg,
-                                              compute_dtype=dtype)
-                    state = TrainState.create(variables, tx)
-                    flops = _step_flops(step, state, batch)
-                    state, metrics = step(state, batch)   # warmup/compile
-                    _sync_loss(metrics)
+                step = make_train_step(model, mcfg, tx, loss_name="mae",
+                                       donate=False, compute_dtype=dtype)
+                forward = make_forward_fn(model, mcfg, compute_dtype=dtype)
+                state = TrainState.create(variables, tx)
+                flops = _step_flops(step, state, batch)
+                state, metrics = step(state, batch)   # warmup/compile
+                _sync_loss(metrics)
 
-                    def reps():
-                        nonlocal state
-                        m = None
-                        for _ in range(steps):
-                            state, m = step(state, batch)
-                        _sync_loss(m)
-                    dt = _best_of(2, reps)
-                    outs, _ = forward(variables, batch)
-                    if ref_out is None:       # unfused fp32 = reference
-                        ref_out = outs
-                    diff = max(float(np.abs(np.asarray(a, np.float32)
-                                            - np.asarray(b, np.float32)
-                                            ).max())
-                               for a, b in zip(outs, ref_out))
-                    point = {
-                        "model": model_type,
-                        "fused": fused,
-                        "dtype": dtype,
-                        "graphs_per_s": round(real_graphs * steps / dt, 2),
-                        "fwd_max_abs_diff_vs_unfused_fp32": diff,
-                    }
-                    if flops is not None:
-                        point["flops_per_step"] = flops
-                        point["achieved_flops_per_s"] = round(
-                            flops * steps / dt, 1)
-                    grid.append(point)
+                def reps():
+                    nonlocal state
+                    m = None
+                    for _ in range(steps):
+                        state, m = step(state, batch)
+                    _sync_loss(m)
+                dt = _best_of(2, reps)
+                outs, _ = forward(variables, batch)
+                if ref_out is None:           # fp32 = reference
+                    ref_out = outs
+                diff = max(float(np.abs(np.asarray(a, np.float32)
+                                        - np.asarray(b, np.float32)).max())
+                           for a, b in zip(outs, ref_out))
+                point = {
+                    "model": model_type,
+                    "dtype": dtype,
+                    "graphs_per_s": round(real_graphs * steps / dt, 2),
+                    "fwd_max_abs_diff_vs_fp32": diff,
+                }
+                if flops is not None:
+                    point["flops_per_step"] = flops
+                    point["achieved_flops_per_s"] = round(
+                        flops * steps / dt, 1)
+                grid.append(point)
     finally:
         for k, v in saved_env.items():
             if v is None:
                 os.environ.pop(k, None)
             else:
                 os.environ[k] = v
-        resolve_fused_mp_flag(refresh=True)
 
-    def _gps(model, fused, dtype):
+    def _gps(model, dtype):
         return next(p["graphs_per_s"] for p in grid
-                    if (p["model"], p["fused"], p["dtype"])
-                    == (model, fused, dtype))
+                    if (p["model"], p["dtype"]) == (model, dtype))
 
     # int8 leg: the calibrated PTQ forward (quant/ptq.py) vs the fp32
     # forward on the same batch, per model — forward-only rows (int8 is
@@ -4265,12 +4240,9 @@ def run_bench_kernels(backend=None):
             eng.shutdown()
 
     out = {
-        "metric": "kernels_bf16_speedup_unfused_pna_train",
-        # the headline is the deployable-today win: bf16 over fp32 on the
-        # default (unfused) PNA path; the fused-kernel points are the
-        # on-chip A/B candidates and stay in the grid
-        "value": round(_gps("PNA", False, "bfloat16")
-                       / _gps("PNA", False, "float32"), 3),
+        "metric": "bf16_speedup_pna_train",
+        "value": round(_gps("PNA", "bfloat16") / _gps("PNA", "float32"),
+                       3),
         "unit": "x",
         "vs_baseline": None,
         "backend": backend,
@@ -4281,12 +4253,8 @@ def run_bench_kernels(backend=None):
             1.0 - int(np.asarray(batch.node_mask).sum()) / n_node, 4),
         "padding_frac_edges": round(
             1.0 - int(np.asarray(batch.edge_mask).sum()) / n_edge, 4),
-        "fused_speedup_fp32": {
-            m: round(_gps(m, True, "float32") / _gps(m, False, "float32"),
-                     3) for m in ("SchNet", "PNA")},
-        "bf16_speedup_unfused": {
-            m: round(_gps(m, False, "bfloat16")
-                     / _gps(m, False, "float32"), 3)
+        "bf16_speedup": {
+            m: round(_gps(m, "bfloat16") / _gps(m, "float32"), 3)
             for m in ("SchNet", "PNA")},
         "int8_fwd_speedup": {row["model"]: row["int8_speedup_vs_fp32"]
                              for row in int8_rows},
@@ -4607,21 +4575,18 @@ def run_bench_mfu(backend=None):
 
 
 def sweep():
-    """Run the (nbr-layout x pallas x steps-per-call) grid, each point in a
+    """Run the (nbr-layout x steps-per-call) grid, each point in a
     fresh subprocess (the flags are read once per process), and report the
     winner. Full grid lands in BENCH_SWEEP.json. This parent never touches
     JAX — a chip belongs to one process at a time and every point needs
     it. A point that fails (non-zero exit, timeout, no JSON line) fails
     the run once the grid has been written."""
-    grid = list(itertools.product(["0", "1"], ["0", "1"], ["1", "4", "10"]))
+    grid = list(itertools.product(["0", "1"], ["1", "4", "10"]))
     results = []
-    for nbr, pallas, spc in grid:
-        if nbr == "1" and pallas == "1":
-            continue  # dense layout bypasses the scatter the kernel replaces
-        env = dict(os.environ,
-                   BENCH_NBR=nbr, HYDRAGNN_USE_PALLAS=pallas,
-                   BENCH_STEPS_PER_CALL=spc, BENCH_SWEEP="0")
-        point = {"nbr_layout": nbr, "pallas": pallas, "steps_per_call": spc}
+    for nbr, spc in grid:
+        env = dict(os.environ, BENCH_NBR=nbr, BENCH_STEPS_PER_CALL=spc,
+                   BENCH_SWEEP="0")
+        point = {"nbr_layout": nbr, "steps_per_call": spc}
         try:
             r = subprocess.run([sys.executable, __file__], env=env,
                                capture_output=True, text=True, timeout=1200)

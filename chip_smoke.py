@@ -20,8 +20,6 @@ batch 32, dense neighbour layout, float32):
             InferenceEngine.submit_structure
   sync      one timed step ended by block_until_ready vs one ended by a
             value fetch; first-step loss from the seed
-  kernels   every Pallas kernel in hydragnn_tpu/kernels compiled
-            NON-interpret, forward and backward, against its XLA reference
   devices   (--devices N > 1) every device holds a batch shard and live
             memory; sharded predictions equal single-device ones; a
             two-replica fleet warms replica 1 from the compile store
@@ -299,105 +297,6 @@ def phase_sync(completed, datasets, model, num_shards, sz, watch, report):
           f"step timed to a fetched value ({tb:.5f}s vs {tf:.5f}s)")
 
 
-def phase_kernels(sz, report):
-    """Each Pallas kernel in the tree, compiled for the device (interpret
-    only where there is no TPU), forward and backward, at the smoke's
-    shapes, against its XLA reference at tests/test_kernels.py's
-    tolerances. Outside the train loop: all are default OFF."""
-    import jax
-    import jax.numpy as jnp
-    from hydragnn_tpu.kernels import fused_mp_pallas as fmp
-    from hydragnn_tpu.kernels import (interpret_mode, nbr_pallas,
-                                      segment_pallas)
-    from hydragnn_tpu.ops import segment as seg
-
-    interpret = interpret_mode()
-    on_tpu = jax.default_backend() == "tpu"
-    check(interpret == (not on_tpu),
-          "Pallas interpret switch resolves to compiled on the chip")
-    n = sz["batch_size"] * sz["atoms_per_dim"] ** 3
-    f, k = sz["hidden_dim"], 32
-    e = n * 29
-    rng = np.random.RandomState(0)
-    pi = jnp.asarray(rng.randn(n, f), jnp.float32)
-    pj = jnp.asarray(rng.randn(n, f), jnp.float32)
-    send = jnp.asarray(rng.randint(0, n, e), jnp.int32)
-    recv = jnp.asarray(np.sort(rng.randint(0, n, e)), jnp.int32)
-    emask = jnp.asarray(rng.rand(e) > 0.03)
-    nbr = jnp.asarray(rng.randint(0, n, (n, k)), jnp.int32)
-    nmask = jnp.asarray(rng.rand(n, k) > 0.1)
-    data = jnp.asarray(rng.randn(e, f), jnp.float32)
-    w = jnp.asarray(rng.randn(e, f), jnp.float32)
-    names = ("mean", "min", "max", "std", "deg")
-
-    def close(a, b, rtol, atol, what):
-        a, b = np.asarray(a), np.asarray(b)
-        check(np.isfinite(a).all(), f"{what}: finite")
-        check(np.allclose(a, b, rtol=rtol, atol=atol),
-              f"{what}: max|diff|={np.max(np.abs(a - b)):.3g} beyond "
-              f"rtol={rtol} atol={atol}")
-
-    def scalar(outs):  # a loss touching every output, for the backward
-        return sum(jnp.sum(o * o) for o in outs) * 1e-3
-
-    out = {}
-
-    # 1. segment_pallas: [E, F] -> [N, F] scatter-sum as one-hot matmuls
-    t0 = time.perf_counter()
-    fwd = jax.jit(lambda d: segment_pallas.segment_sum_pallas(
-        d, recv, n, interpret))
-    ref = jax.jit(lambda d: jax.ops.segment_sum(d, recv, n))
-    close(fwd(data), ref(data), 2e-5, 2e-5, "segment_sum_pallas fwd")
-    g = jax.jit(jax.grad(lambda d: scalar([fwd(d)])))(data)
-    gr = jax.jit(jax.grad(lambda d: scalar([ref(d)])))(data)
-    close(g, gr, 2e-5, 2e-5, "segment_sum_pallas bwd")
-    out["segment_pallas"] = round(time.perf_counter() - t0, 2)
-
-    # 2. nbr_pallas: fused neighbour-gather -> PNA statistics
-    t0 = time.perf_counter()
-    fwd = jax.jit(lambda a, b: nbr_pallas.fused_neighbor_aggregate(
-        a, b, nbr, nmask, 128, interpret))
-    ref = jax.jit(lambda a, b: nbr_pallas._reference(a, b, nbr, nmask, 1e-5))
-    for name, a, b in zip(names, fwd(pi, pj), ref(pi, pj)):
-        close(a, b, 1e-5, 1e-5, f"fused_neighbor_aggregate fwd {name}")
-    g = jax.jit(jax.grad(lambda a, b: scalar(fwd(a, b)), (0, 1)))(pi, pj)
-    gr = jax.jit(jax.grad(lambda a, b: scalar(ref(a, b)), (0, 1)))(pi, pj)
-    for a, b in zip(g, gr):
-        close(a, b, 1e-4, 1e-5, "fused_neighbor_aggregate bwd")
-    out["nbr_pallas"] = round(time.perf_counter() - t0, 2)
-
-    # 3. fused_mp_pallas: gather -> edge op -> scatter, both kernels
-    t0 = time.perf_counter()
-    fwd = jax.jit(lambda a, b: fmp.fused_pna_edge_aggregate(
-        a, b, send, recv, emask, n, 1e-5, interpret))
-    ref = jax.jit(lambda a, b: seg.pna_aggregate(
-        a[recv] + b[send], recv, n, emask))
-    for name, a, b in zip(names, fwd(pi, pj), ref(pi, pj)):
-        close(a, b, 5e-3 if name == "std" else 2e-5, 2e-5,
-              f"fused_pna_edge_aggregate fwd {name}")
-    g = jax.jit(jax.grad(lambda a, b: scalar(fwd(a, b)), (0, 1)))(pi, pj)
-    gr = jax.jit(jax.grad(lambda a, b: scalar(ref(a, b)), (0, 1)))(pi, pj)
-    for a, b in zip(g, gr):
-        close(a, b, 1e-4, 1e-5, "fused_pna_edge_aggregate bwd")
-    fwd = jax.jit(lambda h, ww: fmp.fused_filter_scatter(
-        h, ww, send, recv, emask, n, interpret))
-    ref = jax.jit(lambda h, ww: seg.segment_sum(h[send] * ww, recv, n,
-                                                emask))
-    close(fwd(pi, w), ref(pi, w), 2e-5, 2e-5, "fused_filter_scatter fwd")
-    g = jax.jit(jax.grad(lambda h, ww: scalar([fwd(h, ww)]), (0, 1)))(pi, w)
-    gr = jax.jit(jax.grad(lambda h, ww: scalar([ref(h, ww)]), (0, 1)))(pi, w)
-    for a, b in zip(g, gr):
-        close(a, b, 1e-4, 1e-5, "fused_filter_scatter bwd")
-    out["fused_mp_pallas"] = round(time.perf_counter() - t0, 2)
-
-    report["kernels"] = {"interpret": interpret,
-                         "shape": {"N": n, "F": f, "K": k, "E": e},
-                         "seconds_compile_and_check": out}
-    say(f"kernels: interpret={interpret} (compiled for the device: "
-        f"{not interpret}); N={n} F={f} K={k} E={e}; fwd+bwd match the "
-        f"XLA references: {out}")
-
-
 def phase_devices(completed, datasets, state, model, p_loop, num_shards,
                   sz, report):
     """Multi-chip facts: shard residency, live memory on every device,
@@ -504,7 +403,6 @@ def run(sz: dict, num_shards: int) -> dict:
         phase_sync(completed, datasets, model, num_shards, sz, watch,
                    report)
         main_path = (watch.count, watch.seconds, watch.cache_hits)
-        phase_kernels(sz, report)
         if num_shards > 1:
             phase_devices(completed, datasets, state, model, p_loop,
                           num_shards, sz, report)

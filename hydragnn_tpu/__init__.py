@@ -1,4 +1,4 @@
-"""hydragnn_tpu — a TPU-native (JAX/XLA/pjit/Pallas) re-design of HydraGNN.
+"""hydragnn_tpu — a TPU-native (JAX/XLA/pjit) re-design of HydraGNN.
 
 Multi-headed graph convolutional networks for atomistic materials data, built
 TPU-first: static-shape padded graph batches, masked segment ops, functional

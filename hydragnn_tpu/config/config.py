@@ -373,7 +373,7 @@ class ModelConfig:
     # compute dtype ("bfloat16" on the TPU hot path). Lowest-precedence
     # input to the mixed-precision policy — HYDRAGNN_PRECISION and
     # explicit per-construction overrides win (train/precision.py,
-    # docs/kernels_mixed_precision.md)
+    # docs/mixed_precision.md)
     dtype: str = "float32"
 
 

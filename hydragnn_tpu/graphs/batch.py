@@ -454,8 +454,7 @@ def with_neighbor_format(batch: GraphBatch, k: Optional[int] = None,
     Default-on (run_training): the r3 CPU sweep measured the dense
     layout ahead of the segment pipeline at every steps-per-call
     setting (41.5/47.6/51.4 vs 39.5/26.7/43.6 g/s at spc 1/4/10,
-    BENCH_SWEEP.json) — it removes the forward pass's scatter, which also
-    sidesteps the Pallas-vs-XLA-scatter question wherever it applies."""
+    BENCH_SWEEP.json) — it removes the forward pass's scatter."""
     nbr, nbr_edge, nbr_mask, edge_slot = build_neighbor_tables(
         np.asarray(batch.senders), np.asarray(batch.receivers),
         np.asarray(batch.edge_mask), batch.num_nodes, batch.num_edges,

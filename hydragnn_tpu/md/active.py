@@ -182,14 +182,10 @@ class EnsembleScorer:
         import jax
         import jax.numpy as jnp
 
-        from ..kernels.fused_mp_pallas import resolve_fused_mp_flag
-        from ..kernels.nbr_pallas import resolve_nbr_pallas_flag
         from ..ops.activations import activation_function_selection
         from ..ops.segment import global_sum_pool
         from ..train.train_step import _cast_floats, _resolve_compute_dtype
 
-        resolve_nbr_pallas_flag(refresh=True)  # pinned at construction
-        resolve_fused_mp_flag(refresh=True)
         cdtype = _resolve_compute_dtype(self.mcfg, self.compute_dtype)
         mixed = cdtype != jnp.float32
         model = self.model

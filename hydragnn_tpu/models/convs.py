@@ -220,47 +220,19 @@ class PNAConv(nn.Module):
                                         name="rbf_proj")(enc))
             return h
 
-        from ..kernels import interpret_mode
-        has_edge_terms = bool(self.edge_dim or self.rbf)
         if batch.nbr is not None:
-            from ..kernels.nbr_pallas import (fused_neighbor_aggregate,
-                                              nbr_pallas_enabled)
-            if nbr_pallas_enabled(proj_j.shape, proj_j.dtype,
-                                  edge_terms=has_edge_terms):
-                # fused gather->stats Pallas kernel: no [N, K, F] in HBM
-                # (HYDRAGNN_PALLAS_NBR=1, resolved once at step
-                # construction — kernels/nbr_pallas.py decision record)
-                mean, mn, mx, sd, deg = fused_neighbor_aggregate(
-                    proj_i, proj_j, batch.nbr, batch.nbr_mask, 128,
-                    interpret_mode())
-            else:
-                # dense neighbor-list layout: [N, K, F] messages, axis-1
-                # reductions, no scatter in the forward pass
-                # (with_neighbor_format)
-                h = proj_i[:, None, :] + seg.neighbor_gather(proj_j,
-                                                             batch.nbr)
-                h = edge_terms(h, lambda ev: seg.edge_gather(ev, batch))
-                mean, mn, mx, sd, deg = seg.neighbor_aggregate(
-                    h, batch.nbr_mask)
+            # dense neighbor-list layout: [N, K, F] messages, axis-1
+            # reductions, no scatter in the forward pass
+            # (with_neighbor_format)
+            h = proj_i[:, None, :] + seg.neighbor_gather(proj_j, batch.nbr)
+            h = edge_terms(h, lambda ev: seg.edge_gather(ev, batch))
+            mean, mn, mx, sd, deg = seg.neighbor_aggregate(h, batch.nbr_mask)
         else:
-            from ..kernels.fused_mp_pallas import (fused_mp_enabled,
-                                                   fused_pna_edge_aggregate)
-            if fused_mp_enabled(proj_j.shape, proj_j.dtype,
-                                edge_terms=has_edge_terms,
-                                has_edge_mask=batch.edge_mask is not None):
-                # fused gather->edge-add->stats Pallas kernel: no [E, F]
-                # edge tensor in HBM (HYDRAGNN_FUSED_MP=1, resolved once
-                # at step construction — kernels/fused_mp_pallas.py
-                # decision record; A/B via bench BENCH_KERNELS)
-                mean, mn, mx, sd, deg = fused_pna_edge_aggregate(
-                    proj_i, proj_j, batch.senders, batch.receivers,
-                    batch.edge_mask, n, 1e-5, interpret_mode())
-            else:
-                h = (seg.neighbor_gather(proj_i, batch.receivers)
-                     + seg.neighbor_gather(proj_j, batch.senders))
-                h = edge_terms(h, lambda ev: ev)
-                mean, mn, mx, sd, deg = seg.pna_aggregate(
-                    h, batch.receivers, n, batch.edge_mask)
+            h = (seg.neighbor_gather(proj_i, batch.receivers)
+                 + seg.neighbor_gather(proj_j, batch.senders))
+            h = edge_terms(h, lambda ev: ev)
+            mean, mn, mx, sd, deg = seg.pna_aggregate(
+                h, batch.receivers, n, batch.edge_mask)
         aggs = jnp.concatenate([mean, mn, mx, sd], axis=-1)      # [N, 4F]
 
         avg_lin, avg_log = pna_degree_stats(self.deg_hist)

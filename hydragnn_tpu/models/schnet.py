@@ -60,9 +60,7 @@ class CFConv(nn.Module):
             pos = pos + seg.edge_aggregate_mean(trans, batch)
 
         # filter-weighted aggregation: dense layout -> masked K-axis
-        # reduction; edge list -> fused Pallas gather->mult->scatter when
-        # HYDRAGNN_FUSED_MP is on (kernels/fused_mp_pallas.py), else the
-        # unfused gather + segment scatter
+        # reduction; edge list -> gather + segment scatter
         h = seg.filter_weighted_aggregate(h, W, batch)
         h = nn.Dense(self.num_filters, name="lin2")(h)
         h = shifted_softplus(h)

@@ -24,9 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-_PALLAS_STATE = {"checked": False, "on": False}
-
-
 def _aggregate(fn):
     """Run `fn` under the "aggregate" scope. A fresh context manager a
     call: a shared `jax.named_scope` object keeps ONE saved name stack, so
@@ -138,37 +135,9 @@ def row_gather(slot_values, nbr):
         return slot_values[nbr]
 
 
-def _use_pallas() -> bool:
-    """Route 2-D segment sums through the Pallas MXU kernel.
-
-    Default: OFF everywhere — adjudicated by the r3 on-chip integration
-    sweep (BENCH_SWEEP_TPU.json): end-to-end PNA energy-force training on
-    the v5e is slower with the kernel at every measured point (spc 1/4/10:
-    1106 vs 1135, 807 vs 1059, 865 vs 1017 g/s), despite the kernel-level
-    microbench win at OC20-like shapes (kernels/segment_pallas.py) — the
-    one-hot-matmul formulation adds FLOPs that XLA's fused scatter doesn't
-    pay, and the winning dense neighbor layout (graphs/batch.py
-    with_neighbor_format) bypasses the scatter entirely. On CPU pallas is
-    interpret-mode only and pathologically slow (r3 CPU sweep: every
-    HYDRAGNN_USE_PALLAS=1 grid point timed out at 20 min, BENCH_SWEEP.json).
-    The kernel stays available behind HYDRAGNN_USE_PALLAS=1 for shapes
-    where a future sweep shows an end-to-end win. Parsed STRICTLY
-    (utils/envflags.env_strict_flag, the HYDRAGNN_PALLAS_NBR lesson): a
-    typo value warns and leaves the kernel off instead of silently
-    enabling it.
-    """
-    if not _PALLAS_STATE["checked"]:
-        from ..utils.envflags import env_strict_flag
-        from ..kernels import interpret_mode
-        _PALLAS_STATE["on"] = env_strict_flag("HYDRAGNN_USE_PALLAS", False)
-        _PALLAS_STATE["interpret"] = interpret_mode()
-        _PALLAS_STATE["checked"] = True
-    return _PALLAS_STATE["on"]
-
-
 def _accum_f32(data):
     """Mixed-precision accumulation policy
-    (docs/kernels_mixed_precision.md): reduced-precision segment
+    (docs/mixed_precision.md): reduced-precision segment
     reductions accumulate in f32 and store back reduced — a bf16
     pairwise sum over a 30-neighbor radius-graph segment loses low bits
     at every add otherwise. Returns (upcast data, dtype to cast the
@@ -190,14 +159,8 @@ def segment_sum(data, segment_ids, num_segments, mask=None,
     if mask is not None:
         data = jnp.where(_bcast(mask, data), data, 0.0)
     data, store_dtype = _accum_f32(data)
-    if (data.ndim == 2 and jnp.issubdtype(data.dtype, jnp.floating)
-            and _use_pallas()):
-        from ..kernels.segment_pallas import segment_sum_pallas
-        out = segment_sum_pallas(data, segment_ids, num_segments,
-                                 _PALLAS_STATE["interpret"])
-    else:
-        out = jax.ops.segment_sum(data, segment_ids, num_segments,
-                                  indices_are_sorted=indices_are_sorted)
+    out = jax.ops.segment_sum(data, segment_ids, num_segments,
+                              indices_are_sorted=indices_are_sorted)
     return out if store_dtype is None else out.astype(store_dtype)
 
 
@@ -249,12 +212,7 @@ def segment_std(data, segment_ids, num_segments, mask=None, eps=1e-5):
 
 def pna_stats_epilogue(s, sq, cnt, mn, mx, eps=1e-5):
     """(mean, min, max, std, degree) from the raw additive accumulators
-    and extrema. The SHARED epilogue of `pna_aggregate` and the fused
-    Pallas kernel (kernels/fused_mp_pallas.py): one traced subgraph, so
-    a composite loss reading several statistics accumulates its
-    cotangents through the mean/std interdependence identically on both
-    paths — splitting this math across the kernel's custom-VJP boundary
-    measurably reorders the last-ulp gradient accumulation."""
+    and extrema of `pna_aggregate`."""
     cnt_safe = jnp.maximum(cnt, 1.0)
     mean = s / cnt_safe
     var = jnp.maximum(sq / cnt_safe - mean * mean, 0.0)
@@ -345,27 +303,12 @@ def filter_weighted_aggregate(h, w, batch):
     """SchNet CFConv aggregation: sum_{e: recv[e]=n} h[send[e]] * w[e]
     (models/schnet.py; reference: SCFStack.py:143-223 CFConv propagate).
 
-    Routing: the dense neighbor layout keeps its masked K-axis
-    reduction; the edge-list layout goes through the fused
-    gather->multiply->scatter Pallas kernel when HYDRAGNN_FUSED_MP is on
-    and the node array fits VMEM (kernels/fused_mp_pallas.py — parity
-    contract pinned in tests/test_kernels.py), else the unfused
-    gather + masked segment scatter."""
+    The dense neighbor layout takes a masked K-axis reduction, the edge
+    list a gather + masked segment scatter."""
     if batch.nbr_edge is not None:
         return neighbor_sum(
             edge_gather(neighbor_gather(h, batch.senders) * w, batch),
             batch.nbr_mask)
-    from ..kernels import interpret_mode
-    from ..kernels.fused_mp_pallas import (fused_filter_scatter,
-                                           fused_mp_enabled)
-    # VMEM bound against the PROMOTED dtype: a bf16 h multiplied by
-    # an f32 filter runs the kernel in f32 (fused_mp_pallas mirrors
-    # the unfused promotion)
-    if fused_mp_enabled(h.shape, jnp.promote_types(h.dtype, w.dtype),
-                        has_edge_mask=batch.edge_mask is not None):
-        return fused_filter_scatter(h, w, batch.senders,
-                                    batch.receivers, batch.edge_mask,
-                                    batch.num_nodes, interpret_mode())
     return segment_sum(neighbor_gather(h, batch.senders) * w,
                        batch.receivers, batch.num_nodes, batch.edge_mask)
 

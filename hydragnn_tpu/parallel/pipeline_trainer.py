@@ -273,8 +273,6 @@ def make_pipeline_forward(cfg: ModelConfig, mesh: Mesh, num_stages: int,
     (embed, decode, losses) stays on the flat axis, and only the
     pipelined conv stack reshapes to [D, M, ...] so each data shard of
     the (pipe x data) mesh rings its own microbatches."""
-    from ..kernels.nbr_pallas import resolve_nbr_pallas_flag
-    resolve_nbr_pallas_flag(refresh=True)  # pinned at construction time
     conv_fn = PIPELINE_CONV_TYPES[cfg.model_type]
     hidden = cfg.hidden_dim
     act = activation_function_selection(cfg.activation)
@@ -525,7 +523,7 @@ def make_pipeline_train_step(cfg: ModelConfig, mesh: Mesh, num_stages: int,
     def train_step(state: TrainState, stacked: GraphBatch):
         grads, metrics = grads_and_metrics(state.params, stacked)
         # bf16/overflow watchdog parity with the main trainer path
-        # (docs/kernels_mixed_precision.md): count this step if the loss
+        # (docs/mixed_precision.md): count this step if the loss
         # or ANY gradient leaf went non-finite
         metrics = {**metrics,
                    "nonfinite_steps": _nonfinite_watchdog(metrics["loss"],

@@ -1,5 +1,5 @@
 """Calibrated int8 post-training quantization for the serving tier
-(docs/kernels_mixed_precision.md "int8").
+(docs/mixed_precision.md "int8").
 
 Three pieces, composed by the serving engine's ``compute_dtype="int8"``
 mode (serving/engine.py) and the fleet's tier routing

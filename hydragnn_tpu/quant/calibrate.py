@@ -1,5 +1,5 @@
 """Deterministic per-channel activation calibration for int8 PTQ
-(docs/kernels_mixed_precision.md "int8").
+(docs/mixed_precision.md "int8").
 
 The calibration pass runs the fp32 model over a calibration set and
 records, for every conv-stack ``nn.Dense`` matmul, the per-INPUT-channel
@@ -192,7 +192,7 @@ def calibrate(model, variables, mcfg, samples: Sequence[GraphSample], *,
         raise ValueError(
             "calibrate needs at least one calibration sample — int8 "
             "activation scales cannot be invented "
-            "(docs/kernels_mixed_precision.md)")
+            "(docs/mixed_precision.md)")
     n_node, n_edge, n_graph = _calibration_shape(subset)
     num_conv = int(mcfg.num_conv_layers)
     amax: Dict[str, np.ndarray] = {}
@@ -244,7 +244,7 @@ def calibrate(model, variables, mcfg, samples: Sequence[GraphSample], *,
             "calibration recorded no conv-stack Dense activations — "
             f"model {type(model).__name__} exposes no encoder "
             "``conv_<i>`` matmuls to quantize "
-            "(docs/kernels_mixed_precision.md \"int8\")")
+            "(docs/mixed_precision.md \"int8\")")
     result = CalibrationScales.from_amax(amax, len(subset))
     dur = _spans.now() - t0
     rec = _spans.current_recorder()

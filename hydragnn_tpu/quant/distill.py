@@ -1,5 +1,5 @@
 """Per-head student distillation for the int8 serving tier
-(docs/kernels_mixed_precision.md "int8"; the FlashSchNet motivation in
+(docs/mixed_precision.md "int8"; the FlashSchNet motivation in
 PAPERS.md — a small distilled student preserves accuracy at a fraction
 of the cost).
 

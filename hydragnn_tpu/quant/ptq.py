@@ -1,5 +1,5 @@
 """Symmetric per-channel int8 matmuls for the serving tier
-(docs/kernels_mixed_precision.md "int8").
+(docs/mixed_precision.md "int8").
 
 The quantization math, per in-scope ``nn.Dense`` (kernel ``w`` of shape
 [in, out], calibrated per-input-channel activation scales ``s_x``):

@@ -248,7 +248,7 @@ def _predict_with_engine(model, state, mcfg, testset, serving, num_shards,
             num_shards=num_shards if num_shards and num_shards > 1 else 1,
             neighbor_format=neighbor_format, neighbor_k=neighbor_k,
             # serve-side precision override (Serving.precision /
-            # HYDRAGNN_SERVE_PRECISION, docs/kernels_mixed_precision.md);
+            # HYDRAGNN_SERVE_PRECISION, docs/mixed_precision.md);
             # None inherits the train-side policy
             compute_dtype=serving.precision,
             quant_calibration=quant_calibration,

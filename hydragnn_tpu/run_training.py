@@ -229,7 +229,7 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
     # The loader's device-stacked output doubles as the microbatch axis.
     # Schedule/remat/microbatch knobs resolve ONCE here, strictly, at
     # step-construction time (utils/envflags.resolve_pipeline — typo env
-    # values warn and fall back, the HYDRAGNN_PALLAS_NBR lesson).
+    # values warn and fall back).
     pipeline_stages = int(train_cfg.get("pipeline_stages", 1) or 1)
     from .utils.envflags import resolve_pipeline
     (microbatches, pipe_schedule, pipe_remat,

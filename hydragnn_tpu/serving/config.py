@@ -4,8 +4,7 @@ Precedence per knob: env var over config block over default — the same
 contract as Training.batch_packing / HYDRAGNN_PACKING. All env values are
 parsed STRICTLY (utils/envflags.env_strict_*): serving switches the whole
 prediction path, so a typo value must warn and fall back to the config
-default, never silently flip the engine on (the HYDRAGNN_PALLAS_NBR
-lesson).
+default, never silently flip the engine on.
 
 Config schema (top-level block, alongside "Dataset"/"NeuralNetwork"):
 
@@ -89,7 +88,7 @@ DeadlineExceededError expiry, and the dispatcher circuit breaker.
 
 `precision` (env: HYDRAGNN_SERVE_PRECISION; "float32" | "bfloat16" |
 "int8") is the serve-side compute-dtype override
-(docs/kernels_mixed_precision.md): unset, the engine inherits the
+(docs/mixed_precision.md): unset, the engine inherits the
 train-side policy (HYDRAGNN_PRECISION / Architecture.dtype). A
 reduced-precision engine relaxes the PR 3 bitwise-parity adjudication
 to the documented tolerance bound — each resolved future carries the

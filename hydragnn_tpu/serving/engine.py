@@ -132,7 +132,7 @@ from .config import Structure
 _SHUTDOWN = object()
 
 # Reduced-precision serving parity contract
-# (docs/kernels_mixed_precision.md). A float32 engine keeps the PR 3
+# (docs/mixed_precision.md). A float32 engine keeps the PR 3
 # adjudication: batched outputs are BITWISE-equal to the single-request
 # forward on the same bucket. A reduced-precision engine (compute_dtype
 # "bfloat16", the serve-side precision override) keeps that same-bucket
@@ -153,7 +153,7 @@ _SHUTDOWN = object()
 SERVE_REDUCED_RTOL = 2.0 ** -5
 SERVE_REDUCED_ATOL = 2.0 ** -5
 
-# int8 serving parity contract (docs/kernels_mixed_precision.md
+# int8 serving parity contract (docs/mixed_precision.md
 # "int8"). An int8 engine (compute_dtype "int8": calibrated per-channel
 # PTQ over the conv-stack matmuls, quant/ptq.py) keeps the same-bucket
 # batched-vs-single BITWISE guarantee — identical compiled program,
@@ -349,7 +349,7 @@ class InferenceEngine:
             getattr(mcfg, "dtype", None), compute_dtype)
         compute_dtype = self.compute_dtype
         # three rungs of the precision ladder
-        # (docs/kernels_mixed_precision.md): fp32 = bitwise parity, bf16
+        # (docs/mixed_precision.md): fp32 = bitwise parity, bf16
         # = the reduced tolerance bound, int8 = calibrated PTQ
         # (quant/ptq.py) under its own documented bound
         self.quantized = self.compute_dtype == "int8"
@@ -500,7 +500,7 @@ class InferenceEngine:
                         "int8 serving needs calibration: pass "
                         "quant_calibration (quant.calibrate) or "
                         "reference_samples for the engine to calibrate "
-                        "from (docs/kernels_mixed_precision.md)")
+                        "from (docs/mixed_precision.md)")
                 from ..quant.calibrate import calibrate
                 quant_calibration = calibrate(
                     model, self._variables, mcfg, reference_samples,

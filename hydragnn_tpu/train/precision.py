@@ -1,4 +1,4 @@
-"""Mixed-precision policy resolution (docs/kernels_mixed_precision.md).
+"""Mixed-precision policy resolution (docs/mixed_precision.md).
 
 ONE place decides the compute dtype for a step/engine, resolved at
 CONSTRUCTION time and baked into the compiled program — never read
@@ -17,8 +17,7 @@ Precedence, most specific wins:
    override `Serving.precision`/HYDRAGNN_SERVE_PRECISION resolved by
    serving/config.py, or bench.py's BENCH_DTYPE),
 2. the HYDRAGNN_PRECISION env knob (STRICT parsing via
-   envflags.env_strict_choice — a typo warns and falls through, the
-   HYDRAGNN_PALLAS_NBR lesson),
+   envflags.env_strict_choice — a typo warns and falls through),
 3. Architecture.dtype from the model config,
 4. float32.
 """
@@ -31,7 +30,7 @@ import jax.numpy as jnp
 # accepted spellings -> canonical dtype name. bf16 and f32 are the
 # dtypes the policy layer supports end to end (f32 accumulation, serving
 # tolerance bound); int8 is the SERVING-ONLY post-training-quantization
-# mode (docs/kernels_mixed_precision.md "int8") — the serving engine
+# mode (docs/mixed_precision.md "int8") — the serving engine
 # handles it via quant/ptq.py and the train-side step factories reject
 # it with an actionable error (train_step._resolve_compute_dtype). Other
 # valid jnp dtype strings in Architecture.dtype pass through unchanged
@@ -79,7 +78,7 @@ def canonical_or_f32(name, what: str = "Architecture.dtype") -> str:
         import logging
         logging.getLogger("hydragnn_tpu").warning(
             "%s 'int8' is serving-only (post-training quantization, "
-            "docs/kernels_mixed_precision.md) — the train-side policy "
+            "docs/mixed_precision.md) — the train-side policy "
             "uses float32; serve with Serving.precision='int8' / "
             "HYDRAGNN_SERVE_PRECISION=int8 instead", what)
         return "float32"

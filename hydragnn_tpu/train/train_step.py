@@ -88,7 +88,7 @@ def _resolve_compute_dtype(cfg: ModelConfig, compute_dtype):
     if name == "int8":
         raise ValueError(
             "int8 is a serving-only precision (post-training "
-            "quantization, docs/kernels_mixed_precision.md): casting "
+            "quantization, docs/mixed_precision.md): casting "
             "float params/activations to int8 in a train/eval step "
             "would destroy them. Train in float32/bfloat16 and serve "
             "int8 via Serving.precision='int8' / "
@@ -104,12 +104,6 @@ def make_loss_fn(model, cfg: ModelConfig, loss_name: str = "mse",
     metrics)) with the mixed-precision casting policy — the ONE training
     loss body, shared by the single-device step factories here and the
     SPMD factories in parallel/spmd.py so the two paths cannot drift."""
-    # pin env-dependent kernel choices NOW: the traced body must not read
-    # os.environ (a post-compile toggle would silently no-op — r5 advisor)
-    from ..kernels.fused_mp_pallas import resolve_fused_mp_flag
-    from ..kernels.nbr_pallas import resolve_nbr_pallas_flag
-    resolve_nbr_pallas_flag(refresh=True)
-    resolve_fused_mp_flag(refresh=True)
     cdtype = _resolve_compute_dtype(cfg, compute_dtype)
     mixed = cdtype != jnp.float32
 
@@ -197,7 +191,7 @@ def _make_step_body(model, cfg: ModelConfig, tx: optax.GradientTransformation,
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         (total, (new_bs, metrics)), grads = grad_fn(
             state.params, state.batch_stats, batch)
-        # NaN/overflow watchdog (docs/kernels_mixed_precision.md): bf16's
+        # NaN/overflow watchdog (docs/mixed_precision.md): bf16's
         # 8-bit significand and 8-bit exponent overflow/flush far earlier
         # than f32, and a silently-NaN'd optimizer poisons every later
         # step — count the bad steps where they happen. Computed BEFORE
@@ -236,10 +230,6 @@ def make_sampled_loss_fn(model, cfg: ModelConfig, loss_name: str = "ce",
     seed-masked loss plus (when `num_hist_layers` > 0) the encoder's
     fresh post-layer states, sown by BaseStack.encode and returned
     [L-1, N, H] for the historical-cache refresh."""
-    from ..kernels.fused_mp_pallas import resolve_fused_mp_flag
-    from ..kernels.nbr_pallas import resolve_nbr_pallas_flag
-    resolve_nbr_pallas_flag(refresh=True)  # pinned at construction time
-    resolve_fused_mp_flag(refresh=True)
     cdtype = _resolve_compute_dtype(cfg, compute_dtype)
     mixed = cdtype != jnp.float32
 
@@ -476,10 +466,6 @@ def make_forward_fn(model, cfg: Optional[ModelConfig] = None,
     outputs out, model compute in Architecture.dtype (or `compute_dtype`).
     The ONE eval-side casting policy, shared by the single-device eval
     body here and the SPMD eval/predict factories in parallel/spmd.py."""
-    from ..kernels.fused_mp_pallas import resolve_fused_mp_flag
-    from ..kernels.nbr_pallas import resolve_nbr_pallas_flag
-    resolve_nbr_pallas_flag(refresh=True)  # pinned at construction time
-    resolve_fused_mp_flag(refresh=True)
     cdtype = _resolve_compute_dtype(cfg, compute_dtype)
     mixed = cdtype != jnp.float32
 

@@ -451,7 +451,7 @@ def train_validate_test(
         train_loss = acc_train.pop("loss", 0.0) / max(nb, 1)
         # NaN/overflow watchdog (train_step._nonfinite_watchdog): COUNT of
         # steps this epoch whose loss or gradients went non-finite — the
-        # bf16 mixed-precision canary (docs/kernels_mixed_precision.md),
+        # bf16 mixed-precision canary (docs/mixed_precision.md),
         # a sum not a mean, surfaced next to input_bound_frac
         nonfinite_steps = acc_train.pop("nonfinite_steps", 0.0)
         history.setdefault("nonfinite_steps", []).append(nonfinite_steps)

@@ -49,8 +49,7 @@ def env_strict_flag(name: str, default: bool = False) -> bool:
     ('1'/'true'/'on', any case) as True. Unlike `env_flag`, an
     unrecognized value (a typo like 'ture') does NOT silently enable the
     feature — it logs a warning and returns the default. Use for flags
-    that switch in experimental code paths (r5 advisor: any non-empty
-    HYDRAGNN_PALLAS_NBR value used to enable the Pallas kernel)."""
+    that switch in experimental code paths."""
     val = os.getenv(name)
     if val is None:
         return default
@@ -70,10 +69,9 @@ def env_strict_choice(name: str, choices, default=None):
     """String env knob restricted to a canonical choice set. `choices`
     maps accepted (lowercased) spellings to canonical values (e.g.
     {"bf16": "bfloat16", "bfloat16": "bfloat16"}). An unrecognized value
-    warns and returns `default` instead of taking effect — the
-    HYDRAGNN_PALLAS_NBR lesson, applied to the mixed-precision knobs
-    (HYDRAGNN_PRECISION / HYDRAGNN_SERVE_PRECISION) where a typo must
-    never silently change the compute dtype."""
+    warns and returns `default` instead of taking effect: for the
+    mixed-precision knobs (HYDRAGNN_PRECISION / HYDRAGNN_SERVE_PRECISION)
+    a typo must never silently change the compute dtype."""
     val = os.getenv(name)
     if val is None or not val.strip():
         return default
@@ -125,8 +123,8 @@ def resolve_packing(train_cfg) -> bool:
     env overrides Training.batch_packing (default off). Strict parsing —
     packing switches batch composition and (multi-process) the data
     distribution contract, so a typo value must warn and fall back, not
-    silently enable it (the HYDRAGNN_PALLAS_NBR lesson). Shared by
-    run_training and bench.py so the precedence can't drift."""
+    silently enable it. Shared by run_training and bench.py so the
+    precedence can't drift."""
     default = bool(train_cfg.get("batch_packing", False))
     if os.getenv("HYDRAGNN_PACKING") is not None:
         return env_strict_flag("HYDRAGNN_PACKING", default)
@@ -240,9 +238,9 @@ def resolve_pipeline(train_cfg, num_stages: int):
     Precedence per knob: HYDRAGNN_* env over the Training.* config keys
     over defaults. STRICT parsing throughout — the schedule/remat knobs
     switch the compiled program's structure, so a typo value must warn
-    and fall back, never silently take effect (the HYDRAGNN_PALLAS_NBR
-    lesson). Resolved ONCE here at step-construction time; the
-    parallel/ modules take plain values and never read the environment
+    and fall back, never silently take effect. Resolved ONCE here at
+    step-construction time; the parallel/ modules take plain values and
+    never read the environment
     (tools/check_traced_env_reads.py enforces it).
 
     Knobs:
@@ -321,7 +319,7 @@ def resolve_hpo_supervisor(hpo_cfg=None) -> "tuple[int, float, float, int]":
     (keys max_retries/heartbeat_s/backoff_s/concurrency) over defaults.
     STRICT parsing — these knobs bound how hard the supervisor fights for
     a dying trial, so a typo value must warn and fall back, never
-    silently disable recovery (the HYDRAGNN_PALLAS_NBR lesson).
+    silently disable recovery.
 
     Knobs:
       HYDRAGNN_HPO_MAX_RETRIES  relaunches per trial after preemption/
@@ -356,7 +354,7 @@ def resolve_elastic(cfg=None) -> "tuple[float, float, float]":
     dict (keys max_restarts/heartbeat_s/backoff_s) over defaults. STRICT
     parsing — these knobs bound how hard the supervisor fights for a
     dying job, so a typo value must warn and fall back, never silently
-    disable recovery (the HYDRAGNN_PALLAS_NBR lesson).
+    disable recovery.
 
     Knobs:
       HYDRAGNN_ELASTIC_MAX_RESTARTS  coordinated restarts after a rank
@@ -421,10 +419,10 @@ def resolve_sampling(train_cfg=None) -> "tuple[tuple, int, int, str]":
     Training.Sampling config block over defaults. STRICT parsing
     throughout — fanouts change every compiled shape in the run and
     staleness_k changes the training mathematics, so a typo value must
-    warn and fall back, never silently take effect (the
-    HYDRAGNN_PALLAS_NBR lesson). Resolved ONCE at loader construction;
-    preprocess/sampling.py takes plain values and never reads the
-    environment (tools/check_traced_env_reads.py enforces it).
+    warn and fall back, never silently take effect. Resolved ONCE at
+    loader construction; preprocess/sampling.py takes plain values and
+    never reads the environment (tools/check_traced_env_reads.py
+    enforces it).
 
     Knobs:
       HYDRAGNN_SAMPLE_FANOUTS      comma-separated per-hop fanouts,
@@ -472,9 +470,9 @@ def resolve_gfm(train_cfg=None) -> "tuple":
     sampling, cfg.task_weights head combine). STRICT parsing — the
     mixture weights change the epoch's global pack plan and the head
     weights change the training mathematics, so a typo value must warn
-    naming the variable and fall back, never silently take effect (the
-    HYDRAGNN_PALLAS_NBR lesson). Resolved ONCE at loader/step
-    construction; parallel/multidataset.py and train/gfm.py take plain
+    naming the variable and fall back, never silently take effect.
+    Resolved ONCE at loader/step construction;
+    parallel/multidataset.py and train/gfm.py take plain
     values and never read the environment (the traced-env-read
     discipline, tools/hydralint).
 

@@ -30,7 +30,7 @@ Faults raise ``InjectedFault``; the ``loader-fetch`` site raises
 loader's transient-I/O retry path — a single listed index is recovered by
 the retry, while ``attempts`` consecutive indices exhaust it and surface.
 
-Parsing is STRICT in the envflags sense (the HYDRAGNN_PALLAS_NBR lesson):
+Parsing is STRICT in the envflags sense:
 a malformed plan or unknown site warns and installs NOTHING — a typo must
 degrade to "no faults injected", never to a surprise injection.
 """

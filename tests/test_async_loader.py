@@ -1,6 +1,6 @@
 """Asynchronous input pipeline (datasets/async_loader.py) + the three r5
 advisor regressions riding the same PR: the multihost checkpoint gate, the
-empty `slice_by_process` slice, and the trace-time HYDRAGNN_PALLAS_NBR read.
+empty `slice_by_process` slice, and loose parsing of an on/off flag.
 """
 import dataclasses
 import threading
@@ -289,28 +289,19 @@ def test_slice_by_process_logs_dropped_tail(caplog):
     assert any("dropping 2 tail" in r.message for r in caplog.records)
 
 
-def test_pallas_nbr_flag_strict_and_pinned(monkeypatch):
-    """Regression (convs.py:218): HYDRAGNN_PALLAS_NBR is resolved once at
-    step-construction time and only explicit truthy values enable it."""
-    from hydragnn_tpu.kernels import nbr_pallas as knp
+def test_env_strict_flag_only_explicit_truthy_values_enable(monkeypatch):
+    """Only explicit truthy values turn an on/off flag on; a typo does
+    not."""
     from hydragnn_tpu.utils.envflags import env_strict_flag
 
-    monkeypatch.setenv("HYDRAGNN_PALLAS_NBR", "ture")  # typo: NOT truthy
-    assert env_strict_flag("HYDRAGNN_PALLAS_NBR", False) is False
+    monkeypatch.setenv("HYDRAGNN_PACKING", "ture")  # typo: NOT truthy
+    assert env_strict_flag("HYDRAGNN_PACKING", False) is False
     for v in ("1", "true", "on", "TRUE", "On"):
-        monkeypatch.setenv("HYDRAGNN_PALLAS_NBR", v)
-        assert env_strict_flag("HYDRAGNN_PALLAS_NBR", False) is True
+        monkeypatch.setenv("HYDRAGNN_PACKING", v)
+        assert env_strict_flag("HYDRAGNN_PACKING", False) is True
     for v in ("0", "false", "off", ""):
-        monkeypatch.setenv("HYDRAGNN_PALLAS_NBR", v)
-        assert env_strict_flag("HYDRAGNN_PALLAS_NBR", False) is False
-
-    # pinning: the resolved value is frozen until the next refresh (i.e. a
-    # post-step-construction env toggle is a no-op, not a trace-time read)
-    monkeypatch.setenv("HYDRAGNN_PALLAS_NBR", "1")
-    assert knp.resolve_nbr_pallas_flag(refresh=True) is True
-    monkeypatch.setenv("HYDRAGNN_PALLAS_NBR", "0")
-    assert knp.resolve_nbr_pallas_flag() is True  # still the pinned value
-    assert knp.resolve_nbr_pallas_flag(refresh=True) is False
+        monkeypatch.setenv("HYDRAGNN_PACKING", v)
+        assert env_strict_flag("HYDRAGNN_PACKING", False) is False
 
 
 # --------------------------------------------------- CI smoke benchmark

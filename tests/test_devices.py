@@ -145,7 +145,7 @@ def test_bench_rate_modes_require_the_chip(monkeypatch, capsys):
 @pytest.mark.slow
 def test_chip_smoke_phases_run_at_tiny_size_on_the_cpu_mesh():
     """The smoke's phases — train, predict (loop vs engine), structure
-    serving, sync, kernels (interpret), multi-device facts incl. the
+    serving, sync, multi-device facts incl. the
     compile-store-warmed fleet — at a tiny size on 4 virtual CPU devices,
     so the script cannot rot between chip runs. Only `main()` holds the
     TPU gate; `run()` is the same code the chip executes."""
@@ -169,4 +169,3 @@ def test_chip_smoke_phases_run_at_tiny_size_on_the_cpu_mesh():
     assert rep["predict"]["engine_equals_loop_bitwise"] is True
     assert rep["train"]["jit_recompiles_per_epoch"][1:] == [0]
     assert rep["devices"]["fleet_warmup"][1]["fresh"] == 0
-    assert rep["kernels"]["interpret"] is True

@@ -1,7 +1,5 @@
 """tools/check_traced_env_reads.py — structural guard against env reads
-inside traced model/step/ops modules (the twice-shipped trace-time-read
-bug class: HYDRAGNN_PALLAS_NBR in convs.py, HYDRAGNN_USE_PALLAS in
-ops/segment.py)."""
+inside traced model/step/ops modules (the trace-time-read bug class)."""
 import importlib.util
 import os
 
@@ -61,13 +59,10 @@ def test_lint_covers_the_known_offender_modules():
     paths = [os.path.relpath(p, REPO) for p in lint.traced_module_paths(REPO)]
     assert os.path.join("hydragnn_tpu", "ops", "segment.py") in paths
     assert os.path.join("hydragnn_tpu", "models", "convs.py") in paths
-    assert os.path.join("hydragnn_tpu", "kernels", "nbr_pallas.py") in paths
+    assert os.path.join("hydragnn_tpu", "models", "schnet.py") in paths
     assert os.path.join("hydragnn_tpu", "train", "train_step.py") in paths
-    # PR 6 additions: the fused message-passing kernels and the
-    # mixed-precision policy module resolve their flags at construction
-    # (HYDRAGNN_FUSED_MP / HYDRAGNN_PRECISION) — keep them linted
-    assert os.path.join("hydragnn_tpu", "kernels",
-                        "fused_mp_pallas.py") in paths
+    # the mixed-precision policy module resolves its knob at construction
+    # (HYDRAGNN_PRECISION) — keep it linted
     assert os.path.join("hydragnn_tpu", "train", "precision.py") in paths
     # PR 7: the telemetry subsystem resolves every knob via
     # utils/envflags.resolve_telemetry — no direct env reads inside

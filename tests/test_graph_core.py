@@ -320,6 +320,33 @@ def test_no_edge_slot_when_the_last_slot_is_real():
     assert with_neighbor_format(one_less, k=k).edge_slot is not None
 
 
+def test_pna_aggregate_fused_matches_separate():
+    """Fused PNA aggregation must equal the separate segment ops."""
+    import numpy as np
+    import jax.numpy as jnp
+    from hydragnn_tpu.ops import segment as seg
+    rng = np.random.RandomState(0)
+    E, N, F = 200, 40, 16
+    data = jnp.asarray(rng.randn(E, F).astype(np.float32))
+    ids = jnp.asarray(rng.randint(0, N, E).astype(np.int32))
+    mask = jnp.asarray(rng.rand(E) > 0.2)
+    mean, mn, mx, sd, deg = seg.pna_aggregate(data, ids, N, mask)
+    np.testing.assert_allclose(
+        np.asarray(mean),
+        np.asarray(seg.segment_mean(data, ids, N, mask)), atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(mn), np.asarray(seg.segment_min(data, ids, N, mask)),
+        atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(mx), np.asarray(seg.segment_max(data, ids, N, mask)),
+        atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(sd), np.asarray(seg.segment_std(data, ids, N, mask)),
+        atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(deg), np.asarray(seg.degree(ids, N, mask)), atol=1e-6)
+
+
 def test_neighbor_aggregate_matches_segment():
     import numpy as np
     import jax.numpy as jnp

@@ -208,8 +208,7 @@ def test_resolve_hpo_supervisor_strict_and_precedence(monkeypatch, caplog):
     monkeypatch.setenv("HYDRAGNN_HPO_BACKOFF_S", "0")
     monkeypatch.setenv("HYDRAGNN_HPO_CONCURRENCY", "8")
     assert resolve_hpo_supervisor({"max_retries": 5}) == (1, 3.5, 0.0, 8)
-    # a typo value warns and falls back instead of taking effect (the
-    # HYDRAGNN_PALLAS_NBR lesson)
+    # a typo value warns and falls back instead of taking effect
     monkeypatch.setenv("HYDRAGNN_HPO_MAX_RETRIES", "threeish")
     with caplog.at_level(logging.WARNING, logger="hydragnn_tpu"):
         retries, _, _, conc = resolve_hpo_supervisor({"max_retries": 5})

@@ -59,7 +59,7 @@ def test_cli_list_rules():
 
 def test_traced_env_rule_scope():
     rule = r_traced.TracedEnvReadRule()
-    assert rule.applies("hydragnn_tpu/kernels/nbr_pallas.py")
+    assert rule.applies("hydragnn_tpu/ops/segment.py")
     assert rule.applies("hydragnn_tpu/telemetry/registry.py")
     assert rule.applies("hydragnn_tpu/train/precision.py")
     assert rule.applies("hydragnn_tpu/md/farm.py")  # PR 11 farm scan body

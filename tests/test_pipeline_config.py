@@ -328,7 +328,7 @@ def _state(params, tx):
 def test_pipeline_knob_resolution(monkeypatch, caplog):
     """resolve_pipeline (utils/envflags): env over config over defaults,
     STRICT parsing — a typo value warns and falls back instead of taking
-    effect (the HYDRAGNN_PALLAS_NBR lesson applied to schedule knobs)."""
+    effect."""
     import logging
     from hydragnn_tpu.utils.envflags import resolve_pipeline
 

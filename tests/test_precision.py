@@ -1,4 +1,4 @@
-"""Mixed-precision policy layer (docs/kernels_mixed_precision.md):
+"""Mixed-precision policy layer (docs/mixed_precision.md):
 resolver precedence + strict parsing, f32 segment accumulation, the
 NaN/overflow watchdog, and the reduced-precision serving parity bound.
 """
@@ -27,8 +27,7 @@ def test_resolve_precision_precedence(monkeypatch):
 
 
 def test_resolve_precision_strict_typo(monkeypatch):
-    """A typo value warns and falls through instead of taking effect —
-    the HYDRAGNN_PALLAS_NBR lesson applied to the precision knobs."""
+    """A typo value warns and falls through instead of taking effect."""
     from hydragnn_tpu.train.precision import resolve_precision
     monkeypatch.setenv("HYDRAGNN_PRECISION", "bfloat")
     assert resolve_precision() == "float32"
@@ -195,3 +194,39 @@ def test_bf16_training_smoke_finite():
     for leaf in jax.tree_util.tree_leaves(state.params):
         if jnp.issubdtype(leaf.dtype, jnp.floating):
             assert leaf.dtype == jnp.float32  # f32 master copies
+
+
+@pytest.mark.slow
+def test_bench_kernels_smoke(tmp_path):
+    """Slow-lane BENCH_KERNELS smoke (the nightly kernel-bench job): the
+    mode must emit its JSON with the fp32/bf16 grid, the int8 forward rows,
+    and the bf16 and int8 serving legs inside their documented tolerance
+    bounds."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    out_path = tmp_path / "BENCH_KERNELS.json"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_KERNELS="1",
+               BENCH_KERNELS_OUT=str(out_path),
+               BENCH_KERNELS_BATCH="4", BENCH_KERNELS_NODES="24",
+               BENCH_KERNELS_DEG="6", BENCH_KERNELS_HIDDEN="32",
+               BENCH_KERNELS_STEPS="2")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, os.path.join(repo, "bench.py")],
+                       env=env, capture_output=True, text=True,
+                       timeout=1500, cwd=repo)
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(out_path.read_text())
+    points = {(p["model"], p["dtype"]): p for p in out["grid"]}
+    assert len(points) == 4
+    for m in ("SchNet", "PNA"):
+        assert points[(m, "float32")]["fwd_max_abs_diff_vs_fp32"] == 0.0
+        assert all(points[(m, dt)]["graphs_per_s"] > 0
+                   for dt in ("float32", "bfloat16"))
+    assert {row["model"] for row in out["int8_forward"]} == {"SchNet", "PNA"}
+    assert out["serving"]["bf16_within_bound"] is True
+    assert out["serving"]["int8_within_bound"] is True
+    assert out["serving"]["fp32_parity"] == "bitwise"
+    assert out["serving"]["bf16_parity"] == "tolerance"

@@ -1,5 +1,5 @@
 """int8 PTQ serving tier (hydragnn_tpu/quant/,
-docs/kernels_mixed_precision.md "int8", docs/serving.md "Tiered
+docs/mixed_precision.md "int8", docs/serving.md "Tiered
 fleets").
 
 Contract under test:
@@ -27,8 +27,7 @@ Contract under test:
   downgraded (counted), and a dead preferred tier falls back cross-tier
   (counted) — zero lost futures,
 * the HYDRAGNN_QUANT_CALIB_SAMPLES / HYDRAGNN_FLEET_TIER_* knobs parse
-  strictly (typo warns and falls back — the HYDRAGNN_PALLAS_NBR
-  lesson).
+  strictly (typo warns and falls back).
 """
 import numpy as np
 import pytest

@@ -549,8 +549,7 @@ def test_resolve_serving_precedence(monkeypatch):
 
 
 def test_resolve_serving_strict_parsing(monkeypatch, caplog):
-    """Typo values warn and fall back — never silently enable (the
-    HYDRAGNN_PALLAS_NBR lesson)."""
+    """Typo values warn and fall back — never silently enable."""
     import logging
     monkeypatch.setenv("HYDRAGNN_SERVE", "ture")  # typo
     monkeypatch.setenv("HYDRAGNN_SERVE_MAX_BATCH", "thirty-two")

@@ -390,7 +390,7 @@ def test_span_table_matches_call_sites(name):
 def _scopes():
     """(documented, called): the device-side scope table of
     docs/observability.md and the literal `jax.named_scope("...")` names
-    of the package (kernels aside: no program of the table runs one)."""
+    of the package."""
     import os
     import re
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -401,8 +401,6 @@ def _scopes():
                                 re.MULTILINE))
     called = set()
     for folder, _, files in os.walk(os.path.join(repo, "hydragnn_tpu")):
-        if os.path.basename(folder) == "kernels":
-            continue
         for name in files:
             if name.endswith(".py"):
                 called.update(re.findall(
@@ -484,7 +482,7 @@ def test_resolve_telemetry_precedence(monkeypatch):
     cfg = resolve_telemetry({})
     assert cfg.enabled is False and cfg.device_trace is False
     # config block enables; env overrides both ways; strict parsing on
-    # typos (warn + keep default, the HYDRAGNN_PALLAS_NBR lesson)
+    # typos (warn + keep default)
     block = {"Telemetry": {"enabled": True, "dir": "/tmp/t",
                            "device_trace_epoch": 2}}
     cfg = resolve_telemetry(block)

@@ -16,10 +16,11 @@ python -m tools.hydralint
 
 case "$shard" in
   core)
-    # ops, model zoo construction, kernels, symmetry, neighbor
+    # ops, model zoo construction, symmetry, neighbor
     # construction (vectorized radius/PBC oracle suite)
     python -m pytest -q tests/test_graph_core.py tests/test_models.py \
-      tests/test_registries.py tests/test_irreps.py tests/test_kernels.py \
+      tests/test_registries.py tests/test_irreps.py \
+      tests/test_layout_parity.py \
       tests/test_equivariance.py tests/test_radius_fast.py
     ;;
   data)

@@ -1,7 +1,7 @@
 """loose-env-read: every env read goes through utils/envflags helpers.
 
-The HYDRAGNN_PALLAS_NBR lesson, generalized from the traced surface to
-the whole library: a raw ``os.environ``/``os.getenv`` read means ad-hoc
+The strict-parsing rule ("a typo warns and keeps the default"),
+generalized from the traced surface to the whole library: a raw ``os.environ``/``os.getenv`` read means ad-hoc
 parsing, and ad-hoc parsing is how a typo value silently enables an
 experimental path (`bool(int(env))` crashing on "true", any-non-empty
 truthiness enabling a kernel). utils/envflags.py is the one place that

@@ -1,13 +1,11 @@
 """traced-env-read: no os.environ/os.getenv inside the traced surface.
 
 An env read inside code that jax traces (model forward, loss/step bodies,
-ops/kernels) is resolved once at trace time and frozen into the compiled
-program — toggling the variable afterwards silently does nothing, and a
-loosely-parsed value can flip an experimental kernel on from a typo. This
-class of bug shipped twice (HYDRAGNN_PALLAS_NBR read at trace time in
-convs.py, r5 advisor; HYDRAGNN_USE_PALLAS loose-truthy in ops/segment.py,
-PR 3), so the rule is structural: env reads belong in utils/envflags.py
-helpers, resolved at construction time and passed in as plain values.
+ops) is resolved once at trace time and frozen into the compiled program —
+toggling the variable afterwards silently does nothing, and a
+loosely-parsed value can flip an experimental path on from a typo. So the
+rule is structural: env reads belong in utils/envflags.py helpers,
+resolved at construction time and passed in as plain values.
 
 Checked (AST, so comments/strings never trip it):
 * any `os.environ` attribute use (covers .get, [], `in`),
@@ -34,13 +32,12 @@ from ..engine import Finding, Rule
 TRACED_DIRS = (
     os.path.join("hydragnn_tpu", "models"),
     os.path.join("hydragnn_tpu", "ops"),
-    os.path.join("hydragnn_tpu", "kernels"),
     # the telemetry layer is host-side, but its knobs gate producer call
     # sites that run adjacent to (and inside wrappers around) traced
     # code — every telemetry knob must resolve through
     # utils/envflags.resolve_telemetry at construction time, never via a
     # direct env read inside the subsystem (PR 7; same rule that keeps
-    # the kernels/precision modules honest)
+    # the precision module honest)
     os.path.join("hydragnn_tpu", "telemetry"),
     # the parallel step/forward factories (pipeline, spmd, composite,
     # graph_parallel) build traced bodies — the schedule/remat/shard
@@ -52,7 +49,7 @@ TRACED_DIRS = (
     # the MD farm's scan body + batched re-filter are compiled programs
     # whose knobs (steps-per-dispatch, candidate headroom) must resolve
     # via serving/config.resolve_md_farm at construction — an env read
-    # here would be trace-time-frozen exactly like the kernels' (PR 11)
+    # here would be trace-time-frozen (PR 11)
     os.path.join("hydragnn_tpu", "md"),
     # the HPO supervision layer is host-side, but its knobs (retry/
     # heartbeat/backoff/concurrency) must resolve through
@@ -96,7 +93,7 @@ TRACED_FILES = (
     # the mixed-precision policy module: resolve_precision is called by
     # step/engine factories whose results are baked into compiled
     # programs — an env read here would be the same trace-time-frozen
-    # bug class, so it must go through utils/envflags like the kernels
+    # bug class, so it must go through utils/envflags
     os.path.join("hydragnn_tpu", "train", "precision.py"),
     # the sampled-training pipeline: its knobs (fanouts, staleness_k,
     # partitions) determine every compiled shape of the run and the
