@@ -98,6 +98,10 @@ class BaseStack(nn.Module):
         # an explicit scope; each conv is already named `conv_<i>` by flax
         with jax.named_scope("geometry"):
             cargs = self.conv_args(batch)
+        # a conv that can trade memory for recomputation does so where its
+        # program is differentiated once (evaluation, serving), not in the
+        # EF train step, which differentiates twice (models/schnet.CFConv)
+        cargs["train"] = train
         x, pos = self.encode(batch, cargs, act, train)
         with jax.named_scope("heads"):
             return self.decode(x, pos, batch, cargs, act, train)

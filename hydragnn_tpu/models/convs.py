@@ -206,17 +206,23 @@ class PNAConv(nn.Module):
         proj_j = nn.Dense(fin, use_bias=False, name="pre_j")(x)
         ea = cargs.get("edge_attr", batch.edge_attr)
 
-        def edge_terms(h, gather):
-            """Add per-edge encoder terms; `gather` maps [E, F] edge values
-            into the target layout (identity for the edge list, nbr_edge
-            gather for the dense layout)."""
+        def edge_terms(h):
+            """Add the per-edge encoder terms to the messages `h`. Each is
+            encoded in the order its input arrives in: one already laid out
+            like `h` (the edge list; slot order `[N, K, .]` from
+            `PNAPlusStack.conv_args` on the dense layout) is added as it
+            stands, an edge-order one on the dense layout (`edge_attr`)
+            goes through the `nbr_edge` gather."""
+            def like_h(ev):
+                return ev if ev.ndim == h.ndim else seg.edge_gather(ev, batch)
+
             if self.edge_dim:
                 enc = nn.Dense(fin, name="edge_encoder")(ea)
-                h = h + gather(nn.Dense(fin, use_bias=False,
+                h = h + like_h(nn.Dense(fin, use_bias=False,
                                         name="edge_proj")(enc))
             if self.rbf:
                 enc = nn.Dense(fin, name="rbf_encoder")(cargs["rbf"])
-                h = h + gather(nn.Dense(fin, use_bias=False,
+                h = h + like_h(nn.Dense(fin, use_bias=False,
                                         name="rbf_proj")(enc))
             return h
 
@@ -225,12 +231,12 @@ class PNAConv(nn.Module):
             # reductions, no scatter in the forward pass
             # (with_neighbor_format)
             h = proj_i[:, None, :] + seg.neighbor_gather(proj_j, batch.nbr)
-            h = edge_terms(h, lambda ev: seg.edge_gather(ev, batch))
+            h = edge_terms(h)
             mean, mn, mx, sd, deg = seg.neighbor_aggregate(h, batch.nbr_mask)
         else:
             h = (seg.neighbor_gather(proj_i, batch.receivers)
                  + seg.neighbor_gather(proj_j, batch.senders))
-            h = edge_terms(h, lambda ev: ev)
+            h = edge_terms(h)
             mean, mn, mx, sd, deg = seg.pna_aggregate(
                 h, batch.receivers, n, batch.edge_mask)
         aggs = jnp.concatenate([mean, mn, mx, sd], axis=-1)      # [N, 4F]

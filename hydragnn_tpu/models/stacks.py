@@ -9,7 +9,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.basis import bessel_basis
-from ..ops.geometry import edge_vectors
+from ..ops.geometry import edge_lengths
 from .base import BaseStack
 from .convs import CGConv, GATv2Conv, GINConv, MFConv, PNAConv, SAGEConv
 
@@ -84,9 +84,10 @@ class PNAPlusStack(BaseStack):
                        name=f"conv_{idx}")
 
     def conv_args(self, batch):
-        _, length = edge_vectors(batch.pos, batch.senders, batch.receivers,
-                                 batch.edge_shifts)
-        rbf = bessel_basis(length, float(self.cfg.radius),
+        """The radial basis of every edge, made once a step in the order
+        of the batch's layout ([N, K, R] with the neighbour tables, [E, R]
+        without): `PNAConv` encodes it where it stands."""
+        rbf = bessel_basis(edge_lengths(batch), float(self.cfg.radius),
                            int(self.cfg.num_radial or 6),
                            int(self.cfg.envelope_exponent or 5))
         return {"rbf": rbf, "edge_attr": batch.edge_attr}

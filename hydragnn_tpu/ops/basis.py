@@ -9,9 +9,11 @@ Covers the reference's radial machinery:
   Agnesi and Soft distance transforms
   (reference: hydragnn/models/mace_utils/modules/radial.py:23,66,94,118,151,204)
 
-All are pure jnp functions of distance arrays — shape-polymorphic, mask-free
-(padding edges have distance 0 which stays finite in every basis here; masking
-happens at aggregation time).
+All are pure jnp functions of distance arrays — shape-polymorphic ([E] in
+edge order, [N, K] in slot order: `ops/geometry.edge_lengths`), mask-free: a
+padding edge has the distance sqrt(1e-9) and a padding slot the length 1
+(`ops/geometry.slot_vectors`), both finite in every basis here and in its
+derivative; masking happens at aggregation time.
 """
 from __future__ import annotations
 

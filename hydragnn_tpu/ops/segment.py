@@ -11,7 +11,9 @@ Trace vocabulary (PERF.md section 3; metadata only, the lowered program is
 the same): the reductions here run under `jax.named_scope("aggregate")`,
 node -> neighbour gathers under "neighbor_gather" (`neighbor_gather`), the
 edge -> dense-slot layout conversion `ev[batch.nbr_edge]` under
-"edge_gather" (`edge_gather`), the slot -> pair row gather of directional
+"edge_gather" (`edge_gather`: within `geometry` the 3-wide gather of the
+shifts, within a `conv_<i>` a conversion that conv makes itself), the
+slot -> pair row gather of directional
 message passing under "pair_gather" (`row_gather`). Convs call the gather
 helpers instead of indexing, so a reduction of a device trace finds the same
 names after a refactor.
@@ -45,9 +47,14 @@ def neighbor_gather(x, index):
 
 def edge_gather(edge_values, batch):
     """Per-edge values [E, ...] into the dense neighbour layout [N, K, ...]
-    (`edge_values[batch.nbr_edge]`), named for the trace: on the v5e this
-    layout conversion and its transpose are the largest single term of the
-    PNAPlus step and of the SchNet forward (PERF.md section 5).
+    (`edge_values[batch.nbr_edge]`), named for the trace. A row gather
+    costs 12-20 ns a row on the v5e whatever its width or index pattern
+    (PERF.md section 6), so what CAN be made in slot order is: lengths and
+    every basis of them come from `ops/geometry.slot_vectors`, whose 3-wide
+    gather of `edge_shifts` is the one call a step that PNAPlus, SchNet and
+    DimeNet make. What still converts here: a true per-edge feature
+    (`edge_attr`), a conv's own per-edge product (`edge_aggregate_*`), and
+    edge-order inputs handed to a conv on a batch with tables.
 
     A batch that carries `edge_slot`, the inverse of the table
     (graphs/batch.build_neighbor_tables), gets the same values under a
@@ -303,8 +310,15 @@ def filter_weighted_aggregate(h, w, batch):
     """SchNet CFConv aggregation: sum_{e: recv[e]=n} h[send[e]] * w[e]
     (models/schnet.py; reference: SCFStack.py:143-223 CFConv propagate).
 
-    The dense neighbor layout takes a masked K-axis reduction, the edge
-    list a gather + masked segment scatter."""
+    The layout is read from the filters: `w` in slot order ([N, K, F], made
+    from slot-order lengths) meets `h[nbr]` where it stands and a masked
+    K-axis reduction follows, no layout converted; `w` in edge order
+    ([E, F]) meets `h[senders]`, and the product takes the `nbr_edge`
+    gather on a batch with the tables, the masked segment scatter on the
+    edge list."""
+    if w.ndim == h.ndim + 1:
+        return neighbor_sum(neighbor_gather(h, batch.nbr) * w,
+                            batch.nbr_mask)
     if batch.nbr_edge is not None:
         return neighbor_sum(
             edge_gather(neighbor_gather(h, batch.senders) * w, batch),

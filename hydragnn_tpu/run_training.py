@@ -419,6 +419,16 @@ def run_training(config_or_path, datasets: Optional[Tuple] = None,
             layout = (f"layout: neighbor_format=True "
                       f"K={train_loader.neighbor_k} "
                       f"edge_slot={init_batch.edge_slot is not None}")
+            # the order the stack's per-edge inputs are made in, read off
+            # the shapes `conv_args` gives on this layout: [N, K, ...] is
+            # slot order, once a step, and no conv converts a layout
+            made = jax.tree_util.tree_leaves(
+                jax.eval_shape(model.conv_args, init_batch))
+            if made:
+                in_slots = any(a.shape[:2] == init_batch.nbr.shape
+                               for a in made)
+                layout += ("; per-edge inputs made in "
+                           f"{'slot' if in_slots else 'edge'} order")
             if getattr(model, "derives_pair_space", False):
                 pad = train_loader.padding_stats(pair_space=True) or {}
                 share = pad.get("pad_pair_share")

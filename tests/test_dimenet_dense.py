@@ -240,6 +240,7 @@ def test_run_training_packs_dimenet(trained, capsys):
     # the stack says it derives a pair space; the loader counts its padding
     layout = [m for m in messages if m.startswith("layout: ")]
     assert len(layout) == 1 and "pad_pair_share=0." in layout[0]
+    assert "per-edge inputs made in slot order" in layout[0]
 
 
 def test_run_prediction_serves_dimenet_through_the_engine(trained, caplog):
