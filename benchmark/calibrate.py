@@ -7,6 +7,8 @@ runs in a check; a later `benchmark` PR repeats it the same way
         --rates 50,100,150,200,300 --seconds 10          (on the chip)
     python3 -m benchmark.calibrate tolerance --workload <cell> --seeds 64 \\
         [--also 2071849904 --diagnose 2071849904]        (on the chip)
+    python3 -m benchmark.calibrate loss_fell --workload <train cell> \\
+        --seeds 16 [--fault state_unchanged]             (on the chip)
     python3 -m benchmark.calibrate verdict benchmark/calibration.json
                       (here: the kept sweeps against the present tolerances)
     python3 -m benchmark.calibrate memory --workload <cell> --sizes 16,32,64
@@ -18,9 +20,12 @@ runs in a check; a later `benchmark` PR repeats it the same way
 from __future__ import annotations
 
 import argparse
+import contextlib
 import copy
+import dataclasses
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -129,6 +134,8 @@ def _distribution(rows: List[Dict], keys: Dict[str, str] = None
     return out
 
 
+SEEDS_FLOOR = 12    # seeds a kept `loss_fell` sweep holds, unless its file
+#                     names fewer and says why (`seeds_floor`)
 SEPARATES = 3.0     # a control's narrowest reading over the sound program's
 #                     widest, from which a number is held against it
 
@@ -179,6 +186,10 @@ def verdict(args) -> Dict:
     here, before and after it moves a tolerance."""
     with open(args.path) as f:
         doc = json.load(f)
+    if "loss_fell" in doc:      # a file of `loss_fell` sweeps
+        return {cell: _loss_fell_verdict(
+            kept, int(cells.load_cell(cell).traffic["trace_steps"]))
+            for cell, kept in doc["loss_fell"]["cells"].items()}
     sweeps = doc.get("tolerance", {}).get("cells") or {
         doc["workload"]: doc}
 
@@ -208,6 +219,60 @@ def verdict(args) -> Dict:
                     kept["seeds"] = max(kept["seeds"], dist["seeds"])
         out[cell] = _verdict(sound, controls)
     return out
+
+
+READING = re.compile(r"ratio(_one_state)?(?:_at_(\d+)_steps)?")
+
+
+def _loss_fell_verdict(kept: Dict, trace_steps: int) -> Dict:
+    """The present `checks.LOSS_FELL` and the mix's present `trace_steps`
+    against the `loss_fell` sweeps kept for one train cell: `sound` (a
+    list of outputs of `calibrate loss_fell`), `controls` (the same with
+    each of `LOSS_FELL_FAULTS` planted) and, where there are any,
+    `one_state`: sweeps of the number the check judged first, one state's
+    reading, which bounds the judged one from above seed by seed. The
+    lower reading is the widest of the sound program in EVERY reading of
+    the judged number from `trace_steps` steps on, at whatever count a
+    sweep's window closed; the one-state sweeps stand in for it only where
+    a cell has no other (`stands_in`), and their widest is reported beside
+    it. The upper is the narrowest of the controls. The limit holds when
+    the upper reading separates (3 x the lower or more) and the limit lies
+    1.5 x or more above the lower and under the upper."""
+    from .jobs import checks
+
+    def widest(sweeps: List[Dict], one_state: bool) -> float:
+        found = [dist["max"] for sweep in sweeps
+                 for name, dist in sweep["distribution"].items()
+                 for m in [READING.fullmatch(name)]
+                 if m and bool(m.group(1)) == one_state
+                 and (m.group(2) is None or int(m.group(2)) >= trace_steps)]
+        return max(found) if found else None
+    earlier = kept.get("one_state", [])
+    sound = kept["sound"] or earlier
+    lower = widest(sound, False)
+    controls = {fault: min(dist["min"]
+                           for name, dist in sweep["distribution"].items()
+                           if READING.fullmatch(name))
+                for fault, sweep in kept["controls"].items()}
+    upper = min(controls.values())
+    limit = checks.LOSS_FELL
+    at_trace = f"ratio_at_{trace_steps}_steps"
+    return {"limit": limit, "trace_steps": trace_steps, "sound_max": lower,
+            "stands_in": not kept["sound"],
+            "one_state_max": max(
+                [v for v in (widest(kept["sound"], True),
+                             widest(earlier, False)) if v is not None],
+                default=None),
+            "controls": controls,
+            # a sound sweep without the reading where a traced run closes
+            # is a KeyError, not a pass
+            "seeds": sum(sweep["distribution"][at_trace]["seeds"]
+                         for sweep in sound),
+            "seeds_floor": kept.get("seeds_floor", SEEDS_FLOOR),
+            "room_above_sound": limit / lower,
+            "room_below_controls": upper / limit,
+            "holds": bool(upper >= SEPARATES * lower
+                          and 1.5 * lower <= limit < upper)}
 
 
 def _readings(compared, highest_only: bool = False) -> Dict[str, float]:
@@ -441,6 +506,173 @@ def _tolerance_serving(ctx, args, seeds):
             {"structures": len(check)})
 
 
+# the two controls of `loss_fell` (jobs/checks.LOSS_FELL): each leaves
+# the weights where they were, and each has to FAIL the check
+LOSS_FELL_FAULTS = ("state_unchanged", "zero_learning_rate")
+
+
+@contextlib.contextmanager
+def planted(fault: str, cell):
+    """The train job of `cell` with `fault` planted under it, the way the
+    tests break a step (tests/benchmark/test_bench_checks.py).
+    `state_unchanged`: every composition's train step computes its
+    metrics and hands back the state it was given. `zero_learning_rate`:
+    the configuration's optimizer at learning rate 0, through the mix's
+    `training` keys: only BatchNorm's running statistics move."""
+    from . import system
+    from .jobs import train
+    built, traffic = system.Training.__init__, cell.traffic
+    if fault == "state_unchanged":
+        def broken(self, *args, **kwargs):
+            built(self, *args, **kwargs)
+            step = self.train_step
+
+            def train_step(state, batch):
+                _, metrics = step(train.copy_state(state), batch)
+                return state, metrics
+            self.train_step = train_step
+        system.Training.__init__ = broken
+    elif fault == "zero_learning_rate":
+        optimizer = cell.config_doc["hydragnn"]["NeuralNetwork"][
+            "Training"]["Optimizer"]
+
+        def stilled(block: Dict) -> Dict:
+            return dict(block, training=dict(
+                block.get("training") or {},
+                Optimizer=dict(optimizer, learning_rate=0.0)))
+        # the rehearsal's block too: a `training` of its own would
+        # override the mix's
+        cell.traffic = stilled(dict(traffic,
+                                    tiny=stilled(traffic.get("tiny", {}))))
+    elif fault:
+        raise ValueError(f"no fault {fault!r}: {LOSS_FELL_FAULTS}")
+    try:
+        yield
+    finally:
+        system.Training.__init__, cell.traffic = built, traffic
+
+
+_COMPOSED: Dict = {}    # `swept`: completed configuration -> composition
+
+
+@contextlib.contextmanager
+def swept(counts: List[int], seen: Dict):
+    """The train job as a sweep over seeds runs it, by the same kind of
+    wrapping as `planted`; the timed job itself knows nothing of it.
+    (1) Inside the window, the loss of the trainer's first batches is also
+    read with the state that each of `counts` steps returned, and each of
+    the `LATE_STEPS` steps after it, as a run reads them past its close:
+    into `seen`, {steps: loss}; `seen["step"]` is the job's `WindowedStep`.
+    A reading waits for the device, so such a window is no measurement.
+    (2) The comparisons with the plain reference are left out (`judge`
+    gives an empty record): they read the same at every count of steps,
+    and at `dimenetpp-s2ef.train` they are 190 s of the 270 s a seed cost.
+    (3) Every composition of one configuration takes the model, the
+    optimizer and the jitted steps of the first one this process built
+    (`_COMPOSED`): they hang on the configuration and only the loaders'
+    order on the seed, so a sweep traces and compiles once, not once a
+    seed, and the controls after it not at all."""
+    from . import system
+    from .jobs import checks, train
+    stepped, judge = train.WindowedStep.__call__, train.Checks.judge
+    built, first = system.Training.__init__, _COMPOSED
+    wanted = {n + k for n in counts for k in range(train.LATE_STEPS + 1)}
+
+    def call(self, state, batch):
+        state, metrics = stepped(self, state, batch)
+        seen["step"] = self
+        # (not before the trainer has fed all the batches a run reads)
+        if (self.t1 is None and self.work["steps"] in wanted
+                and self.calls >= self.keep):
+            seen[self.work["steps"]] = self.read(state, self.first_batches)
+        return state, metrics
+
+    def rebuilt(self, config, *args, **kwargs):
+        built(self, config, *args, **kwargs)
+        key = json.dumps(config, sort_keys=True,
+                         default=lambda a: a.tolist())
+        if key in first:
+            for name in ("model", "tx", "mesh", "train_step", "eval_step",
+                         "place"):
+                setattr(self, name, getattr(first[key], name))
+            self.__dict__["_initialiser"] = first[key]._initialiser
+        first.setdefault(key, self)
+    train.WindowedStep.__call__ = call
+    train.Checks.judge = lambda self: checks.Compared(say)
+    system.Training.__init__ = rebuilt
+    try:
+        yield
+    finally:
+        train.WindowedStep.__call__, train.Checks.judge = stepped, judge
+        system.Training.__init__ = built
+
+
+def loss_fell(args) -> Dict:
+    """`loss_fell` over seeds: the train job itself (`swept`), once a seed
+    in one process, untraced at `--seconds`. A row holds the ratio the run
+    was judged on (`ratio`: the least of the state that closed the window
+    and the `LATE_STEPS` after it), the same read inside the window from
+    the mix's `trace_steps` steps on, where a traced run closes
+    (`ratio_at_<n>_steps`), and from each count of `--probe-at`; beside
+    each the one state's own reading (`ratio_one_state...`), which is what
+    the least of five is steadier than. With `--fault` the job runs with
+    that control planted and has to read over `checks.LOSS_FELL` at every
+    seed. `jobs/checks.LOSS_FELL` is set from what this prints (README.md,
+    "Tolerance of `correct`")."""
+    import jax
+    from .jobs import checks, train
+    ctx = _context(args.workload, args.seed, args.seconds, True)
+    steps = int(ctx.param("trace_steps"))
+    counts = sorted({steps} | {int(n) for n in args.probe_at.split(",")
+                               if n})
+    rows, seen = [], {}
+    with swept(counts, seen), planted(args.fault, ctx.cell):
+        for i, seed in enumerate(_seeds(args)):
+            if i and _out_of_time(args):
+                break
+            say(f"--- seed {seed} ({i + 1} of {args.seeds})")
+            seen.clear()
+            got = train.run(dataclasses.replace(ctx, seed=seed))
+            judged, after = got["checks"], seen["step"].after
+            ratio = judged.numbers["loss_fell"][0]
+            fresh = float(np.min(after)) / ratio
+            row = {"seed": seed, "steps": got["work"]["steps"],
+                   "ratio": ratio, "ratio_one_state": after[0] / fresh}
+            late = range(train.LATE_STEPS + 1)
+            for n in counts:
+                if all(n + k in seen for k in late):
+                    row[f"ratio_at_{n}_steps"] = min(
+                        seen[n + k] for k in late) / fresh
+                    row[f"ratio_one_state_at_{n}_steps"] = seen[n] / fresh
+            row["other_checks_failed"] = sorted(
+                k for k, ok in judged.ok.items()
+                if not ok and k != "loss_fell")
+            say(json.dumps(row))
+            rows.append(row)
+    # what a run is judged on: the ratio where an untraced window closes
+    # and where a traced one does
+    ratios = [r[k] for r in rows
+              for k in ("ratio", f"ratio_at_{steps}_steps") if k in r]
+    return {"workload": args.workload, "fault": args.fault or None,
+            "device": jax.devices()[0].device_kind,
+            "devices": ctx.cell.chips, "seconds": args.seconds,
+            "trace_steps": steps, "probe_at": counts,
+            "late_steps": train.LATE_STEPS,
+            "batches": len(seen["step"].first_batches),
+            "limit": checks.LOSS_FELL, "rows": rows,
+            "steps": [r["steps"] for r in rows],
+            # of the readings that every seed's window reached
+            "distribution": _distribution(
+                [{k: r[k] for k in rows[0] if k == "seed" or "ratio" in k
+                  and all(k in other for other in rows)} for r in rows]),
+            "other_checks_failed": {str(r["seed"]): r["other_checks_failed"]
+                                    for r in rows
+                                    if r["other_checks_failed"]},
+            "every_run_as_it_should_be": bool(
+                min(ratios) > checks.LOSS_FELL if args.fault
+                else max(ratios) <= checks.LOSS_FELL)}
+
+
 def memory(args) -> Dict:
     """Bytes the main program of a cell needs on one described v5e chip,
     by batch size, from the TPU compiler (no chip: section 2 of the
@@ -567,26 +799,38 @@ def record(args) -> Dict:
 def main(argv: List[str] = None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     sub = parser.add_subparsers(dest="command", required=True)
-    for name in ("knee", "tolerance", "memory"):
+    for name in ("knee", "tolerance", "loss_fell", "memory"):
         p = sub.add_parser(name)
         p.add_argument("--workload", required=True)
         p.add_argument("--seed", type=int, default=0)
         p.add_argument("--out")
     tol = sub.choices["tolerance"]
-    tol.add_argument("--seeds", type=int, default=64,
-                     help="how many seeds to judge (drawn from --seed)")
-    tol.add_argument("--also", default="",
-                     help="seeds to judge first, by name, comma-separated")
+    for p in (tol, sub.choices["loss_fell"]):
+        p.add_argument("--seeds", type=int, default=64,
+                       help="how many seeds to judge (drawn from --seed)")
+        p.add_argument("--also", default="",
+                       help="seeds to judge first, by name, "
+                            "comma-separated")
+        p.add_argument("--max-seconds", type=float, default=0.0,
+                       help="seconds of the process after which no "
+                            "further seed is judged (0: all of them)")
     tol.add_argument("--control-seeds", type=int, default=8,
                      help="how many of the seeds also run the controls")
-    tol.add_argument("--max-seconds", type=float, default=0.0,
-                     help="seconds of the process after which no further "
-                          "seed is judged (0: all of them)")
     tol.add_argument("--diagnose", default="",
                      help="seeds to take apart shard by shard, with the "
                           "reference in float64 on the CPU backend")
     sub.choices["knee"].add_argument("--rates", required=True)
     sub.choices["knee"].add_argument("--seconds", type=float, default=10.0)
+    sub.choices["loss_fell"].add_argument(
+        "--seconds", type=float, default=20.0,
+        help="the untraced window (BENCHMARK.json's run_seconds)")
+    sub.choices["loss_fell"].add_argument(
+        "--probe-at", default="",
+        help="further counts of the window's steps to read the probe "
+             "after, comma-separated (a candidate for `trace_steps`)")
+    sub.choices["loss_fell"].add_argument(
+        "--fault", default="", choices=("",) + LOSS_FELL_FAULTS,
+        help="plant this control under the job: it has to fail")
     sub.choices["memory"].add_argument("--sizes", required=True)
     sub.choices["memory"].add_argument("--cache")
     for name in ("spread", "xplane", "record", "verdict"):

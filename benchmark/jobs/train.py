@@ -7,14 +7,19 @@ validation and test passes, best-state copy — with the step callable
 wrapped by a counter. After the warm-up steps the counter syncs and opens
 the window; it counts the optimizer steps and their REAL graphs, atoms and
 edges (read on the host from each batch's masks as it is placed); when the
-window's time has passed it syncs on the last step's output, closes the
-window and asks the trainer to stop (``trainer.request_preemption``).
+window's time has passed it syncs on the last step's output and closes the
+window. The trainer takes `LATE_STEPS` more steps, outside the window, the
+counter reads the loss of the trainer's first batches with the state each
+of them returned (`loss_fell`) and then asks the trainer to stop
+(``trainer.request_preemption``); after that the job compares the step
+programs with the plain reference (`Checks`).
 
 Not as a user has it: no TensorBoard writer (HYDRAGNN_DISABLE_TB, saves
 importing torch in every run), no checkpoint, weights from ``--seed``.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Dict, List
@@ -29,16 +34,27 @@ from . import checks
 
 WARMUP_STEPS = 2   # the trainer's first steps: both compiled variants of a
 #                    data-parallel step (PERF.md, PR 21) and the prefetch
+# `loss_fell` (`jobs/checks.LOSS_FELL`): the trainer's first batches, as
+# many as hold this many structures, are read with the state that closed
+# the window and with the states of this many further steps
+PROBE_STRUCTURES = 32
+LATE_STEPS = 4
 
 
 class WindowedStep:
     """`train_step` with the window round it. Not jitted itself: the
     trainer's recompile counter skips it and reads the eval step."""
 
-    def __init__(self, step, ctx, max_steps=None):
+    def __init__(self, step, ctx, max_steps=None, keep=1, read=None):
         self.step, self.ctx, self.max_steps = step, ctx, max_steps
         self.calls = 0
         self.t0 = self.t1 = None
+        # for `loss_fell`: the first `keep` batches the trainer feeds before
+        # the window closes, as the loader collated them, and what
+        # `read(state, batches)` says of them with the state the window's
+        # last step returned and with each of the `LATE_STEPS` after it
+        self.keep, self.read = keep, read
+        self.first_batches, self.after = [], []
         self.losses: List = []
         self.nonfinite: List = []
         self.work = {"graphs": 0, "atoms": 0, "edges": 0, "steps": 0,
@@ -47,27 +63,34 @@ class WindowedStep:
 
     def placing(self, place):
         """Wrap the placement: count a batch's real content on the host,
-        keyed by the placed batch the step will receive."""
+        keyed by the placed batch the step will receive; the host batch
+        beside it until the trainer's first `keep` steps have taken theirs
+        (`loss_fell` reads those batches again after the window, and
+        nothing more lies on the device meanwhile)."""
         def placed(batch):
             counts = (int(np.sum(batch.graph_mask)),
                       int(np.sum(batch.node_mask)),
                       int(np.sum(batch.edge_mask)),
                       int(np.size(batch.node_mask)))
             out = place(batch)
-            self._placed[id(out)] = counts
+            self._placed[id(out)] = (
+                counts, batch if self.calls < self.keep else None)
             return out
         return placed
 
     def __call__(self, state, batch):
-        from hydragnn_tpu.train import trainer
-        counts = self._placed.pop(id(batch), None)
+        counts, host_batch = self._placed.pop(id(batch), (None, None))
         if self.calls == WARMUP_STEPS and self.t0 is None:
             jax.block_until_ready(state)
             self.ctx.open_window()
             self.t0 = time.perf_counter()
         state, metrics = self.step(state, batch)
+        if self.calls < self.keep and self.t1 is None:
+            self.first_batches.append(host_batch)
         self.calls += 1
-        if self.t0 is not None and self.t1 is None:
+        if self.t1 is not None:
+            self.late(state)
+        elif self.t0 is not None:
             self.losses.append(metrics["loss"])
             self.nonfinite.append(metrics["nonfinite_steps"])
             self.work["steps"] += 1
@@ -80,8 +103,17 @@ class WindowedStep:
                 jax.block_until_ready((state, metrics))
                 self.t1 = time.perf_counter()
                 self.ctx.close_window()
-                trainer.request_preemption()
+                self.late(state)
         return state, metrics
+
+    def late(self, state):
+        """Past the window's close: one more reading of the first batches,
+        before the trainer's next step donates `state`; after the last of
+        them the trainer is asked to stop."""
+        from hydragnn_tpu.train import trainer
+        self.after.append(self.read(state, self.first_batches))
+        if len(self.after) > LATE_STEPS:
+            trainer.request_preemption()
 
 
 @jax.jit
@@ -91,6 +123,17 @@ def copy_state(state):
     the same kind of array (the trainer's would otherwise compile the step
     a third time)."""
     return jax.tree_util.tree_map(jnp.copy, state)
+
+
+def loss_of(comp, state, batches) -> float:
+    """What the train step says of the host batches `batches` with `state`,
+    their mean: a step's loss precedes its update, and the update is thrown
+    away here. Train mode: BatchNorm takes the batch's own statistics, so
+    the reading hangs on the weights alone."""
+    return float(np.mean([
+        jax.device_get(comp.train_step(copy_state(state),
+                                       comp.place(batch))[1]["loss"])
+        for batch in batches]))
 
 
 class Checks:
@@ -276,7 +319,9 @@ def run(ctx) -> Dict:
     trainer.clear_preemption()
     step = WindowedStep(comp.train_step, ctx,
                         max_steps=ctx.param("trace_steps")
-                        if ctx.trace is not None else None)
+                        if ctx.trace is not None else None,
+                        keep=-(-PROBE_STRUCTURES // batch_size),
+                        read=functools.partial(loss_of, comp))
     tcfg = comp.train_cfg
     try:
         trainer.train_validate_test(
@@ -303,10 +348,29 @@ def run(ctx) -> Dict:
         f"graphs, {work['atoms'] / seconds:.1f} real atoms/s, "
         f"{work['edges'] / seconds:.1f} real edges/s; loss {first:.5f} "
         f"(first tenth) -> {last:.5f} (last tenth)")
+    # `loss_fell`: the trainer's first batches (as many as hold 32
+    # structures, of those it fed before the window closed) through the
+    # train step again: with the state the window's last step returned and
+    # with the states of the `LATE_STEPS` steps after it (`step.after`:
+    # read as the trainer went on, past the close), the LEAST of them over
+    # the reading with the fresh weights. The same structures and the same
+    # compiled program on both sides of the window; everything after the
+    # close and before the result line, so inside neither
+    # `train_graphs_per_s` nor `setup_s`. A step that returns its state
+    # unchanged, or moves only BatchNorm's running statistics, reads 1 at
+    # every state (`jobs/checks.LOSS_FELL`)
+    fresh = loss_of(comp, state, step.first_batches)
+    ratio = float(np.min(step.after)) / fresh   # a NaN among them fails
+    say(f"loss_fell: the trainer's first {len(step.first_batches)} "
+        f"batch(es) read {fresh:.6f} with the fresh weights; with the state "
+        f"the window's last step returned and the {len(step.after) - 1} "
+        "after it "
+        + ", ".join(f"{loss:.6f}" for loss in step.after)
+        + f": least ratio {ratio:.4f} (limit {checks.LOSS_FELL:g})")
     results = against_reference.judge()
     results.flag("every_loss_finite", bool(np.isfinite(losses).all()
                                            and bad == 0))
-    results.flag("loss_fell", bool(last < first))
+    results.record("loss_fell", ratio, checks.LOSS_FELL)
     return {
         "end_to_end": {"train_graphs_per_s": work["graphs"] / seconds},
         "attempted": work["steps"], "failed": bad, "checks": results,

@@ -239,39 +239,47 @@ def output(p, e, rbf, struct, num_atoms: int):
     return common.dense(p["lin_out"], x)
 
 
-def node_energies(arch):
-    """arch: the completed Architecture dict (radius, num_radial,
-    num_spherical, envelope_exponent, num_before_skip, num_after_skip,
-    num_conv_layers, max_neighbours, output_heads)."""
+def block_on(arch, struct, pos):
+    """block(p, x): one DimeNet++ block (lin -> embedding -> interaction ->
+    output) on the structures at `pos`, node features in, node features
+    out. The edge vectors, the pair space and both bases are made here,
+    once, and every block of the model reads them. arch: the completed
+    Architecture dict (radius, num_radial, num_spherical,
+    envelope_exponent, num_before_skip, num_after_skip, max_neighbours)."""
     cutoff = float(arch["radius"])
     radial, spherical = int(arch["num_radial"]), int(arch["num_spherical"])
     exponent = int(arch["envelope_exponent"])
+    send, recv = struct["senders"], struct["receivers"]
+    vec = pos[send] - pos[recv] + struct["shifts"]
+    d = jnp.sqrt(jnp.sum(vec * vec, axis=-1))
+    pairs = edge_pairs(struct, pos.shape[0], int(arch["max_neighbours"]))
+    kj, ji, real = pairs
+    # angle at j between (pos_i - pos_j) = -vec[ji], (pos_k - pos_j)
+    cos = -jnp.sum(vec[ji] * vec[kj], axis=-1) / (d[ji] * d[kj])
+    rbf = radial_basis(d, cutoff, radial, exponent)
+    sbf = spherical_basis(d[kj], cos, cutoff, spherical, radial, exponent)
 
+    def block(p, x):
+        x = common.dense(p["lin"], x)
+        e = embedding(p["emb"], x, rbf, struct)
+        e = interaction(p["interaction"], e, rbf, sbf, pairs,
+                        int(arch["num_before_skip"]),
+                        int(arch["num_after_skip"]))
+        return output(p["output"], e, rbf, struct, pos.shape[0])
+    return block
+
+
+def node_energies(arch):
+    """arch: the completed Architecture dict (`block_on`'s keys,
+    num_conv_layers, output_heads)."""
     def fn(variables, struct, pos, train):
         params = variables["params"]
-        send, recv = struct["senders"], struct["receivers"]
-        vec = pos[send] - pos[recv] + struct["shifts"]
-        d = jnp.sqrt(jnp.sum(vec * vec, axis=-1))
-        pairs = edge_pairs(struct, pos.shape[0], int(arch["max_neighbours"]))
-        kj, ji, real = pairs
-        # angle at j between (pos_i - pos_j) = -vec[ji], (pos_k - pos_j)
-        cos = -jnp.sum(vec[ji] * vec[kj], axis=-1) / (d[ji] * d[kj])
-        rbf = radial_basis(d, cutoff, radial, exponent)
-        sbf = spherical_basis(d[kj], cos, cutoff, spherical, radial,
-                              exponent)
-        def block(p, x):
-            x = common.dense(p["lin"], x)
-            e = embedding(p["emb"], x, rbf, struct)
-            e = interaction(p["interaction"], e, rbf, sbf, pairs,
-                            int(arch["num_before_skip"]),
-                            int(arch["num_after_skip"]))
-            return output(p["output"], e, rbf, struct, pos.shape[0])
-
+        stats = variables.get("batch_stats", {})
+        block = block_on(arch, struct, pos)
         x = jnp.asarray(struct["x"])
         depth = int(arch["num_conv_layers"])
         for i in range(depth):
             x = jax.nn.relu(block(params[f"conv_{i}"], x))
-        stats = variables["batch_stats"]
         for i in range(len(arch["output_heads"]["node"]["dim_headlayers"])):
             x = jax.nn.relu(common.batch_norm(
                 params[f"head_0_norm_{i}"], stats[f"head_0_norm_{i}"],

@@ -70,7 +70,7 @@ def node_energies(arch):
     """arch: the completed Architecture dict (radius, num_radial,
     envelope_exponent, num_conv_layers, pna_deg)."""
     def fn(variables, struct, pos, train):
-        params, stats = variables["params"], variables["batch_stats"]
+        params, stats = variables["params"], variables.get("batch_stats", {})
         rbf = bessel_rbf(common.edge_lengths(pos, struct),
                          float(arch["radius"]), int(arch["num_radial"]),
                          int(arch["envelope_exponent"]))

@@ -43,7 +43,7 @@ def node_energies(arch):
     """arch: the completed Architecture dict (radius, num_gaussians,
     num_conv_layers)."""
     def fn(variables, struct, pos, train):
-        params, stats = variables["params"], variables["batch_stats"]
+        params, stats = variables["params"], variables.get("batch_stats", {})
         d = common.edge_lengths(pos, struct)
         x = jnp.asarray(struct["x"])
         for i in range(int(arch["num_conv_layers"])):

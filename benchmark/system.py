@@ -121,15 +121,18 @@ BATCHNORM_PASSES = 64
 
 
 def initialiser(model, calibration: Sequence):
-    """key -> weights, made on the device in one jitted call, with
-    the BatchNorm running statistics a trained model would carry: 64
-    train-mode passes over the `calibration` structures move them (momentum
-    0.9) onto those structures' own statistics. At flax's initial values
-    (mean 0, variance 1) nothing is normalised in eval mode, SchNet's
-    activations shrink to 1e-6 layer by layer, and ``softplus(x) - log 2``
-    there cancels to a few digits: two float32 evaluations of the same
-    mathematics then differ by percents (PERF.md, PR 22). Whoever keeps
-    the function (a sweep over seeds) compiles it once."""
+    """key -> weights, made on the device in one jitted call. Where the
+    model has BatchNorm layers, with the running statistics a trained
+    model would carry: 64 train-mode passes over the `calibration`
+    structures move them (momentum 0.9) onto those structures' own
+    statistics. At flax's initial values (mean 0, variance 1) nothing is
+    normalised in eval mode, SchNet's activations shrink to 1e-6 layer by
+    layer, and ``softplus(x) - log 2`` there cancels to a few digits: two
+    float32 evaluations of the same mathematics then differ by percents
+    (PERF.md, PR 22). Where `model.init` makes no `batch_stats` collection
+    there is nothing to calibrate: the collection comes back empty and no
+    pass runs. Whoever keeps the function (a sweep over seeds) compiles it
+    once."""
     import jax
     from hydragnn_tpu.graphs.batch import collate, with_neighbor_format
     n = 64 * (sum(s.num_nodes for s in calibration) // 64 + 1)
@@ -141,6 +144,8 @@ def initialiser(model, calibration: Sequence):
     @jax.jit
     def init(key):
         variables = model.init(key, batch, train=False)
+        if "batch_stats" not in variables:
+            return {"params": variables["params"], "batch_stats": {}}
 
         def one_pass(_, stats):
             _, mutated = model.apply(

@@ -3,13 +3,13 @@ to the files it stands for — without JAX."""
 import json
 import os
 import re
-import shutil
 
 import pytest
 
 from benchmark import cells
 
-from bench_testlib import DEVICE_KEYS, LINE_KEYS, REPO, run_cell
+from bench_testlib import (DEVICE_KEYS, LINE_KEYS, REPO, config_doc, run_cell,
+                           throwaway_root as throwaway)
 
 BENCH = cells.load_benchmark()
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -106,16 +106,9 @@ def throwaway_root(tmp_path):
     """A copy of BENCHMARK.json and the data files with one more
     configuration, traffic mix, per-layer metric and cell — new files and
     new entries only, nothing that was there is edited."""
-    root = tmp_path / "bench"
-    for sub in ("configs", "traffic", "metrics"):
-        shutil.copytree(os.path.join(REPO, "benchmark", sub),
-                        root / "benchmark" / sub)
-    bench = json.loads(json.dumps(BENCH))
-    with open(root / "benchmark" / "configs" / "schnet-s2ef.json") as f:
-        doc = json.load(f)
+    doc = config_doc("schnet-s2ef")
     doc["tiny"]["hidden_dim"] = 8
-    (root / "benchmark" / "configs" / "schnet-wide.json").write_text(
-        json.dumps(doc))
+    root, bench, _ = throwaway(tmp_path, "schnet-wide", doc, "predict-few")
     with open(root / "benchmark" / "traffic" / "predict.json") as f:
         mix = json.load(f)
     mix["tiny"]["structures"] = 12
@@ -123,16 +116,6 @@ def throwaway_root(tmp_path):
         json.dumps(mix))
     (root / "benchmark" / "metrics" / "predict_batches.json").write_text(
         json.dumps({"reader": "counters.value", "args": {"key": "batches"}}))
-    bench["configs"].append({
-        "name": "schnet-wide", "source": "https://example.org/paper",
-        "file": "benchmark/configs/schnet-wide.json", "reduced": [],
-        "why": "throw-away"})
-    bench["workloads"].append({
-        "name": "schnet-wide.predict-few", "config": "schnet-wide",
-        "traffic": "predict-few", "chips": 1, "why": "throw-away"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "schnet-s2ef.predict" in metric.get("workloads", []):
-            metric["workloads"].append("schnet-wide.predict-few")
     bench["per_layer"].append({
         "name": "predict_batches", "unit": "batches", "better": "lower",
         "source": "program_counter", "layer": "serving",

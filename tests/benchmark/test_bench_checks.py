@@ -18,7 +18,7 @@ import pytest
 from benchmark import cells, run, system
 from benchmark.jobs import checks, train
 
-from bench_testlib import REPO
+from bench_testlib import REPO, kept_sweeps
 
 SEEDS = [int(s) for s in np.random.RandomState(25).randint(
     1, 2 ** 31 - 1, size=15)] + [2071849904]
@@ -327,15 +327,20 @@ def test_calibrate_tolerance_sweeps_seeds_and_writes_the_distribution(
 
 
 def test_the_kept_sweeps_hold_the_tolerances_as_they_stand(capsys):
-    """calibration.json's sweeps (TPU v5 lite, PR 25) judged by the present
-    HIGHEST_TOL: every limit 3 x above the widest sound reading of every
-    sweep kept and below every control that separates from the sound
-    program, every control 3 x over the limit of some number. A PR that
-    moves a tolerance without a sweep beside it fails here."""
+    """The sweeps kept in every `benchmark/calibration*.json` (TPU v5
+    lite; PR 25's in calibration.json, a later cell's in the file its PR
+    adds) judged by the present HIGHEST_TOL: every limit 3 x above the
+    widest sound reading of every sweep kept and below every control that
+    separates from the sound program, every control 3 x over the limit of
+    some number; every cell of the benchmark has a sweep in one of the
+    files. A PR that moves a tolerance, or adds a cell, without a sweep
+    beside it fails here."""
     from benchmark import calibrate
-    assert calibrate.main(["verdict", os.path.join(
-        REPO, "benchmark", "calibration.json")]) == 0
-    verdicts = json.loads(capsys.readouterr().out)
+    verdicts, kept = {}, {}
+    for path, sweeps in kept_sweeps("tolerance").items():
+        kept.update(sweeps)
+        assert calibrate.main(["verdict", path]) == 0
+        verdicts.update(json.loads(capsys.readouterr().out))
     assert set(verdicts) == {w["name"] for w in
                              cells.load_benchmark()["workloads"]}
     for cell, verdict in verdicts.items():
@@ -346,8 +351,6 @@ def test_the_kept_sweeps_hold_the_tolerances_as_they_stand(capsys):
             # no limit sits above a control that separates
             assert all(reading > held["limit"]
                        for reading in held["held_against"].values())
-    with open(os.path.join(REPO, "benchmark", "calibration.json")) as f:
-        kept = json.load(f)["tolerance"]["cells"]
     assert {dist["judged_by"] for sweep in kept.values()
             for dist in sweep["at_highest"].values()
             if dist["judged_by"]} == set(checks.HIGHEST_TOL)

@@ -7,18 +7,16 @@ up by themselves (`test_bench_reference.py`, `test_bench_rehearsal.py`,
 hand count, the kept sweeps of EVERY cell against the tolerances as they
 stand, and the 12 GiB rule. Nothing runs on a chip.
 
-Two tests of the harness pin the benchmark to its extent at PR 26 and fail
-on any appended cell or per-layer metric (they are under `paths`, so not a
-`model_config` PR's to edit): `test_bench_checks.py::
-test_the_kept_sweeps_hold_the_tolerances_as_they_stand` (its set equality
-reads `calibration.json` alone) and `test_bench_scopes.py::
-test_problems_empty_and_new_entries_resolve` (the LAST 15 `per_layer`
-entries are PR 26's). `test_every_cells_sweeps_hold_the_tolerances_as_they_
-stand` and `test_entries_are_appended_and_resolve` below assert all that
-those two assert, over every calibration file and with PR 26's entries
-contiguous instead of last.
+Two tests of the harness pinned the benchmark to its extent at PR 26 and
+failed on any appended cell or per-layer metric from PR 28 to PR 33, which
+repaired them: `test_bench_checks.py::
+test_the_kept_sweeps_hold_the_tolerances_as_they_stand` and
+`test_bench_scopes.py::test_problems_empty_and_new_entries_resolve`.
+`test_every_cells_sweeps_hold_the_tolerances_as_they_stand` and
+`test_entries_are_appended_and_resolve` below assert what those two
+assert, and since PR 33 find PR 28's own entries by name as well: no test
+here counts entries from the end of a list that later PRs append to.
 """
-import glob
 import json
 import os
 
@@ -29,7 +27,7 @@ from benchmark.data import s2ef_like
 from benchmark.jobs import checks
 from benchmark.roofline import common, dimenetconv
 
-from bench_testlib import REPO
+from bench_testlib import REPO, kept_sweeps
 
 GIB = 2 ** 30
 STEP_LIMIT_GIB = 12.0
@@ -46,13 +44,19 @@ def test_entries_are_appended_and_resolve():
     from test_bench_scopes import NEW_METRICS as PR26
     bench = cells.load_benchmark()
     assert cells.problems() == []
-    assert bench["configs"][-1]["name"] == "dimenetpp-s2ef"
-    assert [w["name"] for w in bench["workloads"]][-2:] == NEW_CELLS
+    # appended after what PR 26 left and kept together, wherever later
+    # PRs' entries leave them (PR 33: by name, not counted from the end)
+    configs = [c["name"] for c in bench["configs"]]
+    assert configs.index("dimenetpp-s2ef") == 2
+    workloads = [w["name"] for w in bench["workloads"]]
+    at = workloads.index(NEW_CELLS[0])
+    assert at == 4 and workloads[at:at + 2] == NEW_CELLS
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-4:] == NEW_METRICS
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + 4] == NEW_METRICS
     # PR 26's fifteen stay together, right before them
-    assert set(names[-19:-4]) == PR26
-    for entry in bench["configs"][-1:] + bench["workloads"][-2:]:
+    assert set(names[first - 15:first]) == PR26
+    for entry in bench["configs"][2:3] + bench["workloads"][at:at + 2]:
         assert len(entry["why"]) <= 200 and "\n" not in entry["why"]
     cell = cells.load_cell(CELL)
     assert cell.chips == 1 and cell.traffic["job"] == "train"
@@ -134,12 +138,9 @@ def test_every_cells_sweeps_hold_the_tolerances_as_they_stand(capsys):
     adds a cell, without a sweep beside it fails here."""
     from benchmark import calibrate
     verdicts, judged_by = {}, set()
-    for path in sorted(glob.glob(os.path.join(REPO, "benchmark",
-                                              "calibration*.json"))):
+    for path, kept in kept_sweeps("tolerance").items():
         assert calibrate.main(["verdict", path]) == 0
         verdicts.update(json.loads(capsys.readouterr().out))
-        with open(path) as f:
-            kept = json.load(f)["tolerance"]["cells"]
         judged_by |= {dist["judged_by"] for sweep in kept.values()
                       for dist in sweep["at_highest"].values()
                       if dist["judged_by"]}

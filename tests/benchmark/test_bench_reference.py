@@ -13,7 +13,7 @@ from benchmark.data import s2ef_like
 from benchmark.jobs import checks
 from benchmark.reference import common
 
-from bench_testlib import REPO
+from bench_testlib import NO_BATCHNORM, config_doc
 
 CONFIGS = [c["name"] for c in cells.load_benchmark()["configs"]]
 # float32 on the CPU: the two sides differ by summation order only
@@ -21,11 +21,12 @@ TIGHT = 2e-5
 
 
 def tiny_doc(name):
-    with open(os.path.join(REPO, "benchmark", "configs", f"{name}.json")) as f:
-        return system.apply_tiny(json.load(f))
+    return system.apply_tiny(config_doc(name))
 
 
-@pytest.fixture(scope="module", params=CONFIGS)
+# every configuration of the benchmark has a BatchNorm; beside them one
+# stack that has none, which the harness takes as well (PR 33)
+@pytest.fixture(scope="module", params=CONFIGS + [NO_BATCHNORM])
 def composed(request, tmp_path_factory):
     doc = tiny_doc(request.param)
     pools = system.load_pools(doc, str(tmp_path_factory.mktemp("pools")))
@@ -57,8 +58,14 @@ def test_reference_matches_the_system_forward_forces_and_first_loss(composed):
     copied = jax.tree_util.tree_map(np.array, state)
     _, metrics = comp.train_step(copied, comp.place(batch))
     assert float(metrics["loss"]) == pytest.approx(want, rel=TIGHT)
-    # train-mode statistics are the batch's own: another number than eval
-    assert system.relative_error(e, ref_e) > 1e-3
+    if jax.tree_util.tree_leaves(state.batch_stats):
+        # train-mode statistics are the batch's own: another number than
+        # eval
+        assert system.relative_error(e, ref_e) > 1e-3
+    else:
+        # no BatchNorm, so nothing tells the two modes apart
+        assert system.relative_error(e, ref_e) < TIGHT
+        assert state.batch_stats == {}
 
 
 def test_the_tolerance_of_correct_would_catch_bfloat16_compute(composed):
