@@ -393,10 +393,15 @@ class GraphDataLoader:
 
 
 def prefetch_to_device(iterator, size: int = 2, place_fn=None):
-    """Double-buffered device prefetch: enqueue `size` batches ahead so the
-    host->device copy of batch k+1 overlaps the compute of batch k (the
-    DataLoader worker/pin-memory overlap of the reference's HydraDataLoader,
-    preprocess/load_data.py:93-203, expressed as async dispatch).
+    """Device prefetch: keep `size` placed batches ahead of the consumer
+    (the DataLoader worker/pin-memory overlap of the reference's
+    HydraDataLoader, preprocess/load_data.py:93-203, expressed as async
+    dispatch). A generator on the consumer's thread, not a thread: each
+    `next()` places the batch `size` ahead, then yields. That copy overlaps
+    device compute only if the consumer has work queued when it calls
+    `next()`: the trainer keeps one step owed (dispatches step k+1 before
+    fetching step k's metrics), so the copy runs under step k+1; a loop
+    that fetches each step's result first leaves the device idle for it.
 
     `place_fn` customizes placement (e.g. mesh-sharded via
     parallel.mesh.shard_batch); default = jax.device_put to the default

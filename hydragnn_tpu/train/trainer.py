@@ -314,8 +314,9 @@ def train_validate_test(
     stall.step = start_epoch * len(train_loader)
     prev_compiled = 0  # jit-recompile counter baseline (utils/profiling)
     # span taxonomy (docs/observability.md): the placement callables are
-    # wrapped so host->device staging shows up as `h2d` spans on the
-    # prefetch thread; no-op cost when no recorder is installed
+    # wrapped so host->device staging shows up as `h2d` spans on the train
+    # thread, inside its `dataload_wait`; no-op cost when no recorder is
+    # installed
     place_fn = _traced_place(place_fn)
     place_group_fn = _traced_place(place_group_fn)
     # the MFU probe batch: one single-step batch reference (not a copy)
@@ -353,6 +354,15 @@ def train_validate_test(
         acc_train: Dict[str, float] = {}
         nb = 0
         preempted = False
+        # ONE step is kept owed on the device: its metrics are fetched
+        # after the NEXT step is dispatched, so the `next(stream)` that
+        # follows (collation wait, H2D copy of a later batch) runs while
+        # that next step is queued behind the owed one, not on an idle
+        # chip. (metrics, span args, summed) of the owed step, or None
+        owed = None
+        # fetches that found their step already finished: the host was the
+        # slower side and the device ran dry
+        host_bound = 0
         with tr.timer("train_epoch"), profiler:
             # double-buffered device prefetch only when the caller supplies
             # a placement (meshes need mesh-aware sharding; committing to a
@@ -374,8 +384,9 @@ def train_validate_test(
                   else place_fn)
             stream = (prefetch_to_device(source, size=depth, place_fn=pf)
                       if pf is not None else source)
-            # every next() on the stream is host time the device waits on
-            # (collation, cache lookup, staging) — accounted per epoch
+            # every next() on the stream is host time spent on the input
+            # (collation, cache lookup, staging), under the owed step —
+            # accounted per epoch
             stream = stall.wrap(stream)
             n_items = len(train_loader)
             if group:
@@ -405,8 +416,8 @@ def train_validate_test(
                         stall.step_timer():
                     if full_group:
                         state, metrics = multi_train_step(state, batch)
-                        _accumulate_metrics(acc_train, metrics, summed=True,
-                                            span_args=stall.span_args())
+                        host_bound += _fetch_owed(acc_train, owed)
+                        owed = (metrics, stall.span_args(), True)
                         nb += steps_per_call
                     elif group:
                         # remainder group, or a max_num_batch cap inside
@@ -419,17 +430,24 @@ def train_validate_test(
                             b_i = jax.tree_util.tree_map(
                                 lambda a, i=i: a[i], batch)
                             state, m = train_step(state, b_i)
-                            _accumulate_metrics(
-                                acc_train, m, span_args=stall.span_args())
+                            host_bound += _fetch_owed(acc_train, owed)
+                            owed = (m, {"step": stall.step + nb - nb_before},
+                                    False)
                             nb += 1
                     else:
                         state, metrics = train_step(state, batch)
-                        _accumulate_metrics(acc_train, metrics,
-                                            span_args=stall.span_args())
+                        host_bound += _fetch_owed(acc_train, owed)
+                        owed = (metrics, stall.span_args(), False)
                         nb += 1
                 stall.step += nb - nb_before
                 if max_num_batch is not None and nb >= max_num_batch:
                     break
+            # every exit from the pass (its end, the max_num_batch cap, a
+            # preemption) fetches the step still owed; its wait is step
+            # time, as every other fetch's is
+            t0 = time.perf_counter()
+            host_bound += _fetch_owed(acc_train, owed)
+            stall.step_s += time.perf_counter() - t0
         if preempted:
             # mid-epoch preemption: save the EPOCH-START state with
             # next_epoch = THIS epoch, so the resumed run replays the
@@ -455,10 +473,12 @@ def train_validate_test(
         # a sum not a mean, surfaced next to input_bound_frac
         nonfinite_steps = acc_train.pop("nonfinite_steps", 0.0)
         history.setdefault("nonfinite_steps", []).append(nonfinite_steps)
+        history.setdefault("host_bound_steps", []).append(host_bound)
         task_tot = acc_train
-        # host-stall report: fraction of the train pass the host (and so
-        # the device) was blocked on the input pipeline rather than
-        # dispatching/executing steps
+        # host-stall report: fraction of the train pass the host was
+        # blocked on the input pipeline rather than dispatching steps and
+        # fetching their metrics (the device idles only where
+        # `host_bound_steps` counts)
         input_bound = stall.input_bound_frac()
         history.setdefault("input_bound_frac", []).append(input_bound)
         # padding-waste report: fraction of the epoch's node/edge slots
@@ -739,8 +759,8 @@ def train_validate_test(
 
 def _traced_place(place_fn):
     """Wrap a batch-placement callable so host->device staging shows up
-    as `h2d` spans (telemetry/spans.py). No `step` on them: the prefetch
-    thread places a batch a few steps ahead of its own, and evaluation
+    as `h2d` spans (telemetry/spans.py). No `step` on them: the device
+    prefetch places a batch a few steps ahead of its own, and evaluation
     batches come through here too. With no recorder installed the
     per-batch cost is one global read + None check."""
     if place_fn is None:
@@ -784,6 +804,22 @@ def _accumulate_metrics(acc: Dict[str, float], metrics, summed=False,
                 or k.endswith("_loss")):
             acc[k] = acc.get(k, 0.0) + (float(np.sum(v)) if summed
                                         else float(v))
+
+
+def _fetch_owed(acc: Dict[str, float], owed) -> int:
+    """Fetch the metrics of the step owed on the device, `owed` = (metrics,
+    span args, summed) or None, into `acc` (`_accumulate_metrics`). Returns
+    1 when that step had finished before the fetch (the host was the slower
+    side: the device ran dry), else 0; its `device_wait` span carries the
+    same as `ready`."""
+    if owed is None:
+        return 0
+    metrics, span_args, summed = owed
+    ready = all(getattr(a, "is_ready", lambda: True)()
+                for a in jax.tree_util.tree_leaves(metrics))
+    _accumulate_metrics(acc, metrics, summed=summed,
+                        span_args={**span_args, "ready": ready})
+    return int(ready)
 
 
 def _eval_one(eval_step, state, batch, acc: Dict[str, float]):

@@ -133,13 +133,14 @@ class HostStallMonitor:
     time spent dispatching/executing steps.
 
     ``wrap(stream)`` times every ``next()`` on the batch stream (collation,
-    cache lookups, host->device staging — everything the accelerator waits
-    on); ``step_timer()`` wraps the step call. ``input_bound_frac`` is
-    wait / (wait + step): the fraction of the epoch the device sat idle
-    for the host. This turns "the input pipeline is probably the problem"
-    into a measured number (bench.py emits it as `input_bound_frac`;
-    the trainer logs it per epoch and accumulates tracer regions
-    `dataload_wait` / `step_dispatch`)."""
+    cache lookups, host->device staging); ``step_timer()`` wraps the step
+    call. ``input_bound_frac`` is wait / (wait + step): the fraction of the
+    host's epoch spent on the input. The trainer keeps one step owed on the
+    device while it waits, so this is host time under a running step, not
+    device idle time: the device idles only when the owed step finishes
+    first (the trainer's `host_bound_steps`). bench.py emits it as
+    `input_bound_frac`; the trainer logs it per epoch and accumulates
+    tracer regions `dataload_wait` / `step_dispatch`."""
 
     def __init__(self, tracer: Optional[Tracer] = None):
         self.tracer = tracer

@@ -644,3 +644,175 @@ def test_force_loss_weight_auto_matches_reference_balancing():
     f_l = float(aux["force_loss"])
     np.testing.assert_allclose(float(tot_auto), e_l + fw * f_l, rtol=1e-5)
     np.testing.assert_allclose(float(tot_unit), e_l + f_l, rtol=1e-6)
+
+
+# ------------------------------------------- one train step owed on the device
+
+class _TrainRig:
+    """A tiny GIN (graph + node heads), its jitted steps and a shuffled
+    loader of 5 batches an epoch, built once for the tests below: each run
+    takes a fresh state, so every run starts from the same weights."""
+
+    def __init__(self):
+        import jax
+        from hydragnn_tpu.config import build_model_config, update_config
+        from hydragnn_tpu.datasets.loader import GraphDataLoader
+        from hydragnn_tpu.models.create import create_model, init_params
+        from hydragnn_tpu.train.optimizer import select_optimizer
+        from hydragnn_tpu.train.train_step import (make_eval_step,
+                                                   make_multi_train_step,
+                                                   make_train_step)
+        heads = ("graph", "node")
+        samples = deterministic_graph_dataset(num_configs=20, heads=heads)
+        cfg = update_config(make_config("GIN", heads=heads), samples)
+        mcfg = build_model_config(cfg)
+        model = create_model(mcfg)
+        self.loader = GraphDataLoader(samples, batch_size=4, shuffle=True,
+                                      seed=0)
+        self.variables = init_params(model, next(iter(self.loader)))
+        self.tx = select_optimizer(cfg["NeuralNetwork"]["Training"])
+        self.train_step = make_train_step(model, mcfg, self.tx, donate=False)
+        self.multi_train_step = make_multi_train_step(model, mcfg, self.tx,
+                                                      donate=False)
+        self.eval_step = make_eval_step(model, mcfg)
+        self.place = lambda b: jax.tree_util.tree_map(
+            lambda a: None if a is None else jax.device_put(a), b)
+
+    def state(self):
+        import jax
+        import jax.numpy as jnp
+        from hydragnn_tpu.train.train_step import TrainState
+        return TrainState.create(
+            jax.tree_util.tree_map(jnp.array, self.variables), self.tx)
+
+    def run(self, tmp_path, train_step, name, **kw):
+        from hydragnn_tpu.train import trainer
+        trainer.clear_preemption()
+        try:
+            return trainer.train_validate_test(
+                train_step, self.eval_step, self.state(), self.loader, None,
+                None, num_epochs=2, log_name=name, log_dir=str(tmp_path),
+                use_early_stopping=False, keep_best=False,
+                place_fn=self.place, **kw)
+        finally:
+            trainer.clear_preemption()
+
+
+@pytest.fixture(scope="module")
+def train_rig():
+    return _TrainRig()
+
+
+_OWED_ORDERS = [
+    # steady state: step k+1 is dispatched before step k's metrics are
+    # fetched; the epoch's end fetches the step still owed
+    ("single", "d0 d1 f0 d2 f1 d3 f2 d4 f3 f4 "
+               "d5 d6 f5 d7 f6 d8 f7 d9 f8 f9"),
+    # HYDRAGNN_MAX_NUM_BATCH=3 ends each pass after its third step
+    ("single_cap3", "d0 d1 f0 d2 f1 f2 d3 d4 f3 d5 f4 f5"),
+    # steps_per_call 2: two scanned groups and the remainder's single step
+    ("group", "g0 g2 f0 d4 f2 f4 g5 g7 f5 d9 f7 f9"),
+    # ... and a cap inside the second group: its first step alone
+    ("group_cap3", "g0 d2 f0 f2 g3 d5 f3 f5"),
+    # a preemption asked for during step 6: the pass stops at the next
+    # boundary and fetches the owed step before the save
+    ("preempt", "d0 d1 f0 d2 f1 d3 f2 d4 f3 f4 d5 d6 f5 f6 save"),
+]
+
+
+@pytest.mark.parametrize("case, expected", _OWED_ORDERS,
+                         ids=[case for case, _ in _OWED_ORDERS])
+def test_train_pass_keeps_one_step_owed(train_rig, tmp_path, monkeypatch,
+                                        case, expected):
+    """The train pass dispatches step k+1 before it fetches step k's
+    metrics, in all three branches (single steps, full `steps_per_call`
+    groups, a remainder's single steps), and every exit from the pass (its
+    end, a `max_num_batch` cap, a preemption) fetches the step still owed.
+    `d<k>` / `g<k>`: a single step / a group dispatched, starting at step
+    k; `f<k>`: the metrics of the dispatch that started at k fetched."""
+    import jax
+    from hydragnn_tpu.train import trainer
+    monkeypatch.setenv("HYDRAGNN_DISABLE_TB", "1")
+    if case.endswith("cap3"):
+        monkeypatch.setenv("HYDRAGNN_MAX_NUM_BATCH", "3")
+    events, tags, keep = [], {}, []
+    taken = [0]
+
+    def tagging(step, kind, size):
+        def call(state, batch):
+            state, metrics = step(state, batch)
+            events.append(f"{kind}{taken[0]}")
+            tags[id(metrics)] = f"f{taken[0]}"
+            keep.append(metrics)  # no id is reused while the run lasts
+            taken[0] += size
+            if case == "preempt" and taken[0] == 7:
+                trainer.request_preemption()
+            return state, metrics
+        return call
+
+    real_get = jax.device_get
+
+    def get(x):
+        if id(x) in tags:
+            events.append(tags[id(x)])
+        return real_get(x)
+
+    monkeypatch.setattr(trainer.jax, "device_get", get)
+    kw = {}
+    if case.startswith("group"):
+        kw = dict(multi_train_step=tagging(train_rig.multi_train_step, "g",
+                                           2),
+                  steps_per_call=2, place_group_fn=train_rig.place)
+    if case == "preempt":
+        kw["preempt_save_fn"] = lambda s, meta: events.append("save")
+    _, hist = train_rig.run(tmp_path, tagging(train_rig.train_step, "d", 1),
+                            case, **kw)
+    assert " ".join(events) == expected
+    assert len(hist["train_loss"]) == (1 if case == "preempt" else 2)
+
+
+class _Running:
+    """A metric that reads as still running on the device."""
+
+    def __init__(self, value):
+        self.value = value
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.value, dtype=dtype)
+
+
+def test_owed_step_leaves_the_results_unchanged(train_rig, tmp_path,
+                                                monkeypatch):
+    """Keeping a step owed moves only when the host reads the metrics: the
+    final state (bit for bit), the per-epoch losses, the per-task sums and
+    `nonfinite_steps` equal those of a run whose step blocks on its own
+    output before it returns. `host_bound_steps` counts the fetches that
+    found their step finished: every one in the blocking run (5 steps, 5
+    fetches an epoch), none where every fetch has to wait."""
+    import jax
+    monkeypatch.setenv("HYDRAGNN_DISABLE_TB", "1")
+    step = train_rig.train_step
+
+    def blocking(state, batch):
+        return jax.block_until_ready(step(state, batch))
+
+    def running(state, batch):
+        state, metrics = step(state, batch)
+        return state, {k: _Running(v) for k, v in metrics.items()}
+
+    state, hist = train_rig.run(tmp_path, step, "owed")
+    state_synced, hist_synced = train_rig.run(tmp_path, blocking, "synced")
+    _, hist_running = train_rig.run(tmp_path, running, "running")
+    for a, b in zip(jax.tree_util.tree_leaves(state),
+                    jax.tree_util.tree_leaves(state_synced)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    keys = [k for k in hist if k == "train_loss" or k == "nonfinite_steps"
+            or k.startswith("task_")]
+    assert {"task_0", "task_1"} <= set(keys)
+    for k in keys:
+        assert hist[k] == hist_synced[k] == hist_running[k], k
+    assert hist_synced["host_bound_steps"] == [5, 5]
+    assert hist_running["host_bound_steps"] == [0, 0]
