@@ -3,9 +3,11 @@ happens to index (PR 33): a stack WITHOUT BatchNorm goes all the way
 through, beside one with it, as two cases of every test here: the seeded
 weights, the composition `run_training` makes, the plain reference in both
 modes, the train cell's comparisons, and a `predict` rehearsal of a
-throw-away cell in a temporary root. And the weights of the benchmark's
-own configurations, which all have BatchNorm, are bit for bit what the
-harness made before it learnt this. Tiny preset, CPU."""
+throw-away cell in a temporary root. The weights of every configuration
+of the benchmark, and of the one without BatchNorm, are bit for bit what
+the harness made before it learnt this; and that configuration joins the
+benchmark by new files and entries alone, through every check that holds
+of each configuration. Tiny preset, CPU."""
 import json
 
 import jax
@@ -15,8 +17,9 @@ import pytest
 from benchmark import cells, system
 from benchmark.jobs import train
 
-from bench_testlib import (NO_BATCHNORM, config_doc, rehearse,
-                           throwaway_root)
+from bench_testlib import (NO_BATCHNORM, REPO, add_cell,
+                           check_configuration_file, check_weights,
+                           config_doc, rehearse, throwaway_root)
 
 WITH_BATCHNORM = "dimenetpp-s2ef"
 KINDS = [WITH_BATCHNORM, NO_BATCHNORM]
@@ -105,41 +108,47 @@ def test_a_throw_away_predict_cell_of_either_kind_rehearses(
     assert line["attempted"] > 0 and line["failed"] == 0
 
 
-@pytest.mark.parametrize("name", CONFIGS)
-def test_weights_with_batchnorm_are_bit_for_bit_what_they_were(name, cache):
-    """`system.initialiser` up to PR 32, written out: the same key, the same
-    64 train-mode passes over the check structures, in one jitted call.
-    Every configuration of the benchmark has a BatchNorm, so its weights at
-    a seed may not move by a bit."""
-    from hydragnn_tpu.graphs.batch import collate, with_neighbor_format
-    doc, config, comp = composed(name, cache)
-    model = comp.model
-    chk = system.check_structures(comp.loaders[2].dataset)
-    n = 64 * (sum(s.num_nodes for s in chk) // 64 + 1)
-    e = 64 * (sum(s.num_edges for s in chk) // 64 + 1)
-    batch = with_neighbor_format(collate(
-        list(chk), n_node=n, n_edge=e, n_graph=len(chk) + 1, np_out=True))
+@pytest.mark.parametrize("name", CONFIGS + [NO_BATCHNORM])
+def test_weights_are_bit_for_bit_what_they_were(name, cache, tmp_path):
+    """`check_weights`: with a `batch_stats` collection the 64 train-mode
+    passes, without one `model.init`'s parameters, bit for bit at two
+    seeds. Each configuration of the benchmark as its entry stands; the
+    one without BatchNorm as a new file and entry in a temporary root."""
+    if name in CONFIGS:
+        root, bench = REPO, cells.load_benchmark()
+        entry = next(c for c in bench["configs"] if c["name"] == name)
+    else:
+        root, bench, _ = throwaway_root(tmp_path, "throwaway",
+                                        config_doc(name))
+        root, entry = str(root), bench["configs"][-1]
+    check_weights(root, entry, cache)
 
-    @jax.jit
-    def as_it_was(key):
-        variables = model.init(key, batch, train=False)
 
-        def one_pass(_, stats):
-            _, mutated = model.apply(
-                {"params": variables["params"], "batch_stats": stats},
-                batch, train=True, mutable=["batch_stats"])
-            return mutated["batch_stats"]
-        stats = jax.lax.fori_loop(0, 64, one_pass, variables["batch_stats"])
-        return {"params": variables["params"], "batch_stats": stats}
-
-    for seed in (3, 2071849904):
-        was = as_it_was(jax.random.PRNGKey(seed))
-        now = system.init_variables(model, chk, seed)
-        assert jax.tree_util.tree_structure(was) \
-            == jax.tree_util.tree_structure(now)
-        assert leaves(now["batch_stats"]), "every configuration has one"
-        assert all(np.array_equal(a, b)
-                   for a, b in zip(leaves(was), leaves(now)))
-        state = comp.initial_state(seed)
-        assert all(np.array_equal(a, b) for a, b in zip(
-            leaves(was["batch_stats"]), leaves(state.batch_stats)))
+def test_a_configuration_without_batchnorm_joins_by_files_and_entries(
+        tmp_path, cache):
+    """The configuration without BatchNorm, cut in depth (`reduced`: two
+    blocks of three), added as a later PR adds one: its file, its entry, a
+    `predict` cell listed by the metrics of `schnet-s2ef.predict` and a
+    `train-g4` cell listed by those of `dimenetpp-s2ef.train`, in a
+    temporary root. Every name resolves and every check that holds of each
+    configuration (`bench_testlib`: the file, the weights) holds there, as
+    the tests over the real BENCHMARK.json hold it. (The rehearsal of such
+    a cell is `test_a_throw_away_predict_cell_of_either_kind_rehearses`.)
+    The weights of the configurations copied from BENCHMARK.json are
+    `test_weights_are_bit_for_bit_what_they_were`'s own cases."""
+    root, bench, predict = throwaway_root(tmp_path, "throwaway",
+                                          config_doc(NO_BATCHNORM))
+    train = add_cell(bench, "throwaway", "train-g4", "dimenetpp-s2ef.train")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    root = str(root)
+    assert cells.problems(root) == []
+    assert bench["configs"][-1]["reduced"] == ["num_conv_layers"]
+    for config in bench["configs"]:
+        check_configuration_file(bench, root, config)
+    check_weights(root, bench["configs"][-1], cache)
+    for cell, e2e in ((predict, "infer_graphs_per_s"),
+                      (train, "train_graphs_per_s")):
+        loaded = cells.load_cell(cell, root)
+        assert {m["name"] for m in loaded.end_to_end} == {e2e, "setup_s"}
+        assert loaded.per_layer and all(m["moves"] in (e2e, "setup_s")
+                                        for m in loaded.per_layer)

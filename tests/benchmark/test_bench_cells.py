@@ -8,8 +8,9 @@ import pytest
 
 from benchmark import cells
 
-from bench_testlib import (DEVICE_KEYS, LINE_KEYS, REPO, config_doc, run_cell,
-                           throwaway_root as throwaway)
+from bench_testlib import (DEVICE_KEYS, LINE_KEYS, REPO,
+                           check_configuration_file, config_doc, depth_cuts,
+                           run_cell, throwaway_root as throwaway)
 
 BENCH = cells.load_benchmark()
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -51,17 +52,27 @@ def test_at_most_a_quarter_of_the_cells_take_four_chips():
 
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_file(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
-    with open(os.path.join(REPO, config["file"])) as f:
-        doc = json.load(f)
-    for key in ("source", "reduced", "assumed", "departures", "reference",
-                "hydragnn", "data", "tiny"):
-        assert key in doc, key
-    assert doc["reduced"] == config["reduced"] == [], "no width is cut"
-    assert os.path.exists(os.path.join(REPO, doc["reference"]))
-    arch = doc["hydragnn"]["NeuralNetwork"]["Architecture"]
-    assert "neighbor_format" not in arch, "a cell takes the default layout"
+    check_configuration_file(BENCH, REPO, config)
+
+
+ARCH = {"num_conv_layers": 2, "hidden_dim": 192}
+
+
+@pytest.mark.parametrize("reduced,keys", [
+    ([], []), (["num_conv_layers: 2 of 3"], ["num_conv_layers"]),
+    (["hidden_dim: 192 of 256"], None), (["num_conv_layers"], None),
+    (["num_conv_layers: 3 of 3"], None), (["num_conv_layers: 1 of 3"], None),
+    (["num_conv_layers: 2 of 3", "num_conv_layers: 2 of 3"], None)],
+    ids=["none", "depth", "width", "bare-key", "not-a-cut", "not-as-held",
+         "twice"])
+def test_reduced_holds_a_cut_in_depth_and_never_a_width(reduced, keys):
+    """`reduced` in a configuration file: `"<Architecture key>: <held> of
+    <published>"`, a key of depth as held, under the published count."""
+    if keys is None:
+        with pytest.raises(AssertionError):
+            depth_cuts(reduced, ARCH)
+    else:
+        assert depth_cuts(reduced, ARCH) == keys
 
 
 @pytest.mark.parametrize("metric", BENCH["end_to_end"] + BENCH["per_layer"],

@@ -24,8 +24,8 @@ def tiny_doc(name):
     return system.apply_tiny(config_doc(name))
 
 
-# every configuration of the benchmark has a BatchNorm; beside them one
-# stack that has none, which the harness takes as well (PR 33)
+# beside the benchmark's configurations, with a BatchNorm or without, the
+# tests' throw-away stack that has none
 @pytest.fixture(scope="module", params=CONFIGS + [NO_BATCHNORM])
 def composed(request, tmp_path_factory):
     doc = tiny_doc(request.param)
